@@ -23,7 +23,6 @@ from jax import lax
 
 from apex_tpu.parallel.sync_batchnorm import sync_batch_norm
 from apex_tpu.utils.convnet import conv_nhwc as _conv, he_init as _he
-from apex_tpu._compat import axis_size as _axis_size
 
 __all__ = ["Bottleneck", "SpatialBottleneck", "halo_exchange"]
 
@@ -101,7 +100,7 @@ def halo_exchange(x: jnp.ndarray, axis_name: str, halo: int = 1) -> jnp.ndarray:
     height-sharded NHWC tensor (reference: SpatialBottleneck's peer halo
     buffers, bottleneck.py:218-385).  Edge ranks get zero rows, matching
     conv zero padding at the true image border."""
-    world = _axis_size(axis_name)
+    world = jax.lax.axis_size(axis_name)
     rank = lax.axis_index(axis_name)
     down = [(i, (i + 1) % world) for i in range(world)]
     up = [(i, (i - 1) % world) for i in range(world)]

@@ -34,8 +34,8 @@ rebuilds the model-dtype tree per bucket ON USE (int8 + ``ag`` error
 feedback under ``CompressionConfig(ici_legs=True)``), gradients
 reduce-scatter straight into the shard and the update runs there in
 place — no replicated master, no tail all-gather, persistent
-per-device bytes down ~world-fold (the h≥4096 unlock,
-PROFILE_r05.md).  Entry points: :meth:`build_layout` (host-side,
+per-device bytes down ~world-fold (what a model too wide for a
+replicated copy needs).  Entry points: :meth:`build_layout` (host-side,
 once), :meth:`init_shards`, :meth:`gather_params`, :meth:`step` (same
 method, shard-aware), :meth:`unshard_params` (checkpoint → replicated
 eval).  At ``compression=None`` the step is bit-identical to the
@@ -67,13 +67,6 @@ __all__ = ["DistributedFusedAdam", "DistributedFusedLAMB",
            "reestablish_replicated"]
 
 
-def _axis_size(axis_name) -> int:
-    """Static axis extent (shared version-portable shim)."""
-    from apex_tpu._compat import axis_size
-
-    return int(axis_size(axis_name))
-
-
 def reestablish_replicated(params: Any, param_specs: Any,
                            axes: Tuple[str, ...] = ("pp", "tp")) -> Any:
     """Re-mark model-axis-replicated params invariant after a ZeRO step.
@@ -91,11 +84,7 @@ def reestablish_replicated(params: Any, param_specs: Any,
     def fix(p, s):
         names = spec_axis_names(s)
         for ax in axes:
-            try:
-                varying = ax in jax.typeof(p).vma
-            except Exception:
-                varying = True
-            if ax not in names and varying:
+            if ax not in names and ax in jax.typeof(p).vma:
                 p = lax.pmean(p, ax)
         return p
 
@@ -497,7 +486,7 @@ class _DistributedOptimizer:
         if self._mask is not None:
             local_tree = self._mask_tree(params, self._mask, True)
             params = self._mask_tree(params, self._mask, False)
-        world = _axis_size(self._shard_axis)
+        world = jax.lax.axis_size(self._shard_axis)
         rank = lax.axis_index(self._shard_axis)
         meta = _FlatMeta(params, world)
         flat = meta.flatten(params)
@@ -509,7 +498,7 @@ class _DistributedOptimizer:
             from apex_tpu.ops.quantization import init_residual
 
             state["comm"] = init_residual(
-                meta.shard, _axis_size(self._cross_axis),
+                meta.shard, jax.lax.axis_size(self._cross_axis),
                 self.compression.block_size,
             )
             if self.compression.ici_legs:
@@ -595,7 +584,7 @@ class _DistributedOptimizer:
             local_grads = self._mask_tree(grads, self._mask, True)
             params = self._mask_tree(params, self._mask, False)
             grads = self._mask_tree(grads, self._mask, False)
-        world = _axis_size(self._shard_axis)
+        world = jax.lax.axis_size(self._shard_axis)
         rank = lax.axis_index(self._shard_axis)
         meta = _FlatMeta(params, world)
         lr = f32(self.lr if lr is None else lr)
@@ -656,7 +645,7 @@ class _DistributedOptimizer:
                     new_comm["ici_push"] = new_ici_push
             else:
                 g_local = lax.psum(g_local, self._cross_axis)
-            total = world * _axis_size(self._cross_axis)
+            total = world * jax.lax.axis_size(self._cross_axis)
         g_local = g_local / total
         ids = meta.segment_ids()
         ids_local = lax.dynamic_slice(
@@ -688,7 +677,7 @@ class _DistributedOptimizer:
             lextra = {k: v for k, v in state["local"].items()
                       if k != "master"}
             lscale = (1.0 if local_grads_prenormalized
-                      else 1.0 / _axis_size(self._shard_axis))
+                      else 1.0 / jax.lax.axis_size(self._shard_axis))
             lgrads = jax.tree.map(
                 lambda g: jnp.asarray(g, jnp.float32) * lscale,
                 local_grads)
@@ -728,10 +717,10 @@ class _DistributedOptimizer:
         by this step's gather on FINITE params and must survive the
         skip)."""
         layout = self.layout
-        world = _axis_size(self._shard_axis)
+        world = jax.lax.axis_size(self._shard_axis)
         total = world
         if self._cross_axis is not None:
-            total = world * _axis_size(self._cross_axis)
+            total = world * jax.lax.axis_size(self._cross_axis)
         lr = f32(self.lr if lr is None else lr)
         comm = state.get("comm")
         g_shard, new_comm = layout.reduce_scatter_grads(

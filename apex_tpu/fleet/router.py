@@ -58,7 +58,7 @@ all deterministic consequences of the token-identity contract:
   the same migration path drains the replica's work.  With a
   ``watchdog=``, every pump beats the heartbeat file first, carrying
   the replica's name — so a wedged pump leaves the stalled replica
-  NAMED on disk for ``tools/tpu_watch.py``.
+  NAMED on disk for ``resilience.watchdog.read_heartbeat``.
 - **deadlines**: an SLO class (or a per-request override) may carry
   ``deadline_s``.  Unmeetable deadlines are rejected at admission
   (``deadline_unmeetable`` — the budget-headroom discipline); a
@@ -462,6 +462,8 @@ class FleetRouter:
             r.name: r for r in self.replicas}
         self._rr = 0
         self._steps = 0
+        #: the newest exception a replica's pump raised
+        self._last_pump_error: Optional[BaseException] = None
         #: live hedges: uid -> {"replica", "base" (stream at spawn)}
         self._hedges: Dict[Any, dict] = {}
         self._hedged_once: set = set()   # one hedge per request, ever
@@ -532,7 +534,11 @@ class FleetRouter:
         order."""
         alive = [r for r in self.replicas if r.alive]
         if not alive:
-            raise RuntimeError("no replica is alive")
+            # chained to the last pump exception (None when every
+            # replica was killed from outside), so a compile error on
+            # the device is not reported as a dead fleet
+            raise RuntimeError(
+                "no replica is alive") from self._last_pump_error
         # disaggregation: prompts go to prefill-capable replicas; a
         # pure-decode replica receives work by page handoff, never by
         # routing — unless nothing prefill-capable is left alive
@@ -707,7 +713,9 @@ class FleetRouter:
     def _beat(self, r: Replica) -> None:
         """Heartbeat BEFORE the pump, carrying the replica's serving
         fields — if the pump then wedges, the heartbeat file names
-        the stalled replica (``tools/tpu_watch.py`` reads it)."""
+        the stalled replica
+        (:func:`apex_tpu.resilience.watchdog.read_heartbeat` reads
+        it)."""
         if self.watchdog is None:
             return
         self.watchdog.beat(step=self._steps, extra={
@@ -720,6 +728,7 @@ class FleetRouter:
         r.faults += 1
         r.consecutive_faults += 1
         r.last_error = repr(err)
+        self._last_pump_error = err
         self.stats["replica_faults"] += 1
         self._event("replica_fault", replica=r.name, error=repr(err),
                     consecutive=r.consecutive_faults)

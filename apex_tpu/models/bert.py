@@ -29,7 +29,6 @@ from apex_tpu.transformer.tensor_parallel import (
     VocabParallelEmbedding,
     vocab_parallel_cross_entropy,
 )
-from apex_tpu._compat import axis_size as _axis_size
 
 __all__ = ["BertConfig", "BertModel"]
 
@@ -50,8 +49,8 @@ class BertConfig:
     # an amp.Policy drives both dtypes (one-kwarg O0..O5 switch)
     policy: Optional[Any] = None
     remat: bool = True
-    # same measured defaults as GPTConfig (PROFILE_r03.md exps 1 and 5;
-    # fused_ce None = auto by logits size, see GPTConfig)
+    # same chip-measured defaults as GPTConfig (fused_ce None = auto
+    # by logits size, see GPTConfig)
     remat_policy: Optional[str] = "dots_with_no_batch_dims_saveable"
     fused_ce: Optional[bool] = None
     fused_ce_chunk: int = 8192
@@ -224,7 +223,7 @@ class BertModel:
     # ------------------------------------------------------------- forward
     def _layer(self, lp, x, segs):
         c = self.config
-        world = _axis_size(self.axis_name)
+        world = jax.lax.axis_size(self.axis_name)
         heads_local = c.num_attention_heads // world
         b, s, h = x.shape
 
@@ -620,7 +619,7 @@ class BertModel:
             return M * loss_m
 
         fwd_bwd = get_forward_backward_func(
-            pipeline_model_parallel_size=_axis_size(
+            pipeline_model_parallel_size=jax.lax.axis_size(
                 PIPELINE_PARALLEL_AXIS
             ),
         )

@@ -33,7 +33,7 @@ from typing import Any, Dict, Optional
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import PartitionSpec as P
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from apex_tpu.ops.attention import flash_attention
 from apex_tpu.ops.layer_norm import (
@@ -48,13 +48,14 @@ from apex_tpu.transformer.tensor_parallel import (
     ColumnParallelLinear,
     RowParallelLinear,
     VocabParallelEmbedding,
+    gather_from_tensor_model_parallel_region,
+    reduce_from_tensor_model_parallel_region,
     vocab_parallel_cross_entropy,
 )
 from apex_tpu.transformer.tensor_parallel.random import (
     data_parallel_key,
     model_parallel_key,
 )
-from apex_tpu._compat import axis_size as _axis_size
 
 __all__ = ["GPTConfig", "GPTModel", "GPTDecodeFns",
            "quantize_gpt_weights", "QUANTIZED_WEIGHT_LEAVES",
@@ -118,6 +119,18 @@ class GPTDecodeFns:
     #: dp-replicated serving).  Mirrored as ``decode.tp`` so the
     #: batcher's telemetry can stamp it on decode spans.
     tp: Any = None
+    #: where the decode carry lives (replicated on the steps' mesh).
+    #: Mirrored as ``decode.carry_sharding`` so the batcher creates its
+    #: carry there (``init_carry(sharding=...)``) and the first decode
+    #: step compiles for the carry every later step sees.
+    carry_sharding: Any = None
+    #: the partition specs the steps were built from — the SAME at
+    #: every tp (size 1 included).  Place the params and the
+    #: ``init_pools`` dict under ``NamedSharding(mesh, spec)`` with
+    #: these and the first call compiles for the layout every later
+    #: call sees.
+    param_specs: Any = None
+    pool_specs: Any = None
 
 
 #: the projection weight leaves :func:`quantize_gpt_weights` converts —
@@ -254,24 +267,20 @@ def quantize_gpt_weights(
 
 def _quantized_layer_specs(lspecs: Dict[str, Any],
                            layers: Dict[str, Any],
-                           axis_name: str, tp: int) -> Dict[str, Any]:
+                           axis_name: str) -> Dict[str, Any]:
     """Partition specs for the quantized-pool leaves, mirroring the
-    pytree structure :func:`quantize_gpt_weights` built.  At tp=1
-    everything is replicated (the historical serving layout — specs
-    stay byte-identical to older builds); at tp>1 column leaves shard
-    ``q8``/``q4``/``scales`` on the stacked OUTPUT dim (axis 2 of
-    ``(L, k, ·)``) with the bias riding along, and row leaves shard on
-    the contraction dim (axis 1) with a replicated bias — so each chip
-    streams exactly 1/tp of the quantized pool."""
+    pytree structure :func:`quantize_gpt_weights` built — the same
+    specs at every tp: column leaves shard ``q8``/``q4``/``scales`` on
+    the stacked OUTPUT dim (axis 2 of ``(L, k, ·)``) with the bias
+    riding along, and row leaves shard on the contraction dim (axis 1)
+    with a replicated bias — so each chip streams exactly 1/tp of the
+    quantized pool."""
     out = dict(lspecs)
     for name in QUANTIZED_WEIGHT_LEAVES:
         if name not in out or name not in layers:
             continue
         leaf = layers[name]
         if "q8" not in leaf and "q4" not in leaf:
-            continue
-        if tp == 1:
-            out[name] = jax.tree.map(lambda _: P(), leaf)
             continue
         col = name in COLUMN_PARALLEL_LEAVES
         spec = {}
@@ -642,9 +651,9 @@ class GPTModel:
         local dot IS its output shard (bias shards with it), a
         row-parallel leaf's local dot is a partial sum over its slice
         of the contraction dim — psum exactly like
-        ``RowParallelLinear.apply``, then add the replicated bias once.
-        At tp=1 both reduce to the historical dot+bias (the collective
-        is skipped at trace time)."""
+        ``RowParallelLinear.apply``, then add the replicated bias once
+        (the psum is free at tp=1 and is what types the sum
+        replicated)."""
         if "weight" in p:
             return mod.apply(p, y)
         from apex_tpu.ops.dequant_matmul import (
@@ -654,12 +663,7 @@ class GPTModel:
         out = dequant_matmul(
             y, p["q8"] if "q8" in p else p["q4"], p["scales"],
             weight_dtype=weight_pool_dtype(p))
-        if (isinstance(mod, RowParallelLinear)
-                and _axis_size(mod.axis_name) > 1):
-            from apex_tpu.transformer.tensor_parallel.mappings import (
-                reduce_from_tensor_model_parallel_region,
-            )
-
+        if isinstance(mod, RowParallelLinear):
             out = reduce_from_tensor_model_parallel_region(
                 out, mod.axis_name)
         if "bias" in p:
@@ -714,7 +718,7 @@ class GPTModel:
         (:meth:`prefill_forward`) and decode (:meth:`decode_step`), so
         the cache can never hold a different K than training computed."""
         c = self.config
-        world = _axis_size(self.axis_name)
+        world = jax.lax.axis_size(self.axis_name)
         heads_local = c.num_attention_heads // world
         b, s, _ = y.shape
         qkv = self._apply_linear(self.qkv, lp["qkv"], y)  # (b, s, 3h/tp)
@@ -747,7 +751,7 @@ class GPTModel:
         (cos, sin) tables from :meth:`_rope_tables` (None for learned
         positions)."""
         c = self.config
-        world = _axis_size(self.axis_name)
+        world = jax.lax.axis_size(self.axis_name)
         heads_local = c.num_attention_heads // world
         b, s, h = x.shape
 
@@ -1491,7 +1495,6 @@ class GPTModel:
         )
         from apex_tpu.serving.sampling import sample, spec_accept
         from apex_tpu.transformer import parallel_state
-        from apex_tpu._compat import shard_map
 
         c = self.config
         if self.moe is not None:
@@ -1597,35 +1600,28 @@ class GPTModel:
         specs = self.param_specs()
         if wd_active in ("int8", "int4"):
             # the spec tree must mirror the quantized pytree structure:
-            # replicated at tp=1 (the historical layout), column/row
-            # sharded at tp>1 so each chip streams 1/tp of the pool
+            # column/row sharded so each chip streams 1/tp of the pool
             specs["layers"] = _quantized_layer_specs(
-                specs["layers"], params["layers"], self.axis_name,
-                tp_size)
+                specs["layers"], params["layers"], self.axis_name)
         pool_tmpl = jax.eval_shape(lambda: init_pools(cfg))
         # KV pools (L, num_pages, h, page_size, d) head-shard on axis 2
-        # at tp>1: each shard owns its head slice of every layer's
-        # pool, while page tables / write targets / the host allocator
-        # stay replicated — ONE shared free list drives every shard, so
+        # at EVERY tp (size 1 included — one layout, one set of specs):
+        # each shard owns its head slice of every layer's pool, while
+        # page tables / write targets / the host allocator stay
+        # replicated — ONE shared free list drives every shard, so
         # tables are identical across shards by construction
-        pool_sharding = (P(None, None, self.axis_name, None, None)
-                         if tp_size > 1 else P())
-        pool_specs = jax.tree.map(lambda _: pool_sharding, pool_tmpl)
+        pool_specs = jax.tree.map(
+            lambda _: P(None, None, self.axis_name, None, None),
+            pool_tmpl)
         rep = lambda tree: jax.tree.map(lambda _: P(), tree)
-        if tp_size > 1:
-            from apex_tpu.transformer.tensor_parallel.mappings import (
-                gather_from_tensor_model_parallel_region,
-            )
-
-            # the ONE sampling seam: vocab-parallel logits all-gather
-            # to the full (replicated) vocab right before the sampler,
-            # so sample / spec_accept / the per-slot key schedule see
-            # exactly the tensors the tp=1 path sees
-            _full_logits = functools.partial(
-                gather_from_tensor_model_parallel_region,
-                axis_name=self.axis_name)
-        else:
-            _full_logits = lambda l: l
+        # the ONE sampling seam: vocab-parallel logits all-gather to
+        # the full (replicated) vocab right before the sampler, so
+        # sample / spec_accept / the per-slot key schedule see the same
+        # tensors at every tp.  The gather is what types the sampled
+        # tokens replicated for the P() out_specs; at tp=1 it is free.
+        _full_logits = functools.partial(
+            gather_from_tensor_model_parallel_region,
+            axis_name=self.axis_name)
 
         def _prefill(params, pools, toks, length, page_row, key):
             hidden, ks, vs = self.prefill_forward(params, toks)
@@ -1871,12 +1867,12 @@ class GPTModel:
         from apex_tpu.serving.serve import init_carry
 
         carry_tmpl = init_carry(cfg.max_seqs)
-        pf = jax.jit(shard_map(
+        pf = jax.jit(jax.shard_map(
             _prefill, mesh=mesh,
             in_specs=(specs, pool_specs, P(), P(), P(), P()),
             out_specs=(pool_specs, P()),
         ))
-        df = jax.jit(shard_map(
+        df = jax.jit(jax.shard_map(
             _decode, mesh=mesh,
             in_specs=(specs, pool_specs, rep(carry_tmpl), P()),
             out_specs=(pool_specs, rep(carry_tmpl)),
@@ -1887,6 +1883,11 @@ class GPTModel:
         # the batcher only sees the callables; stamp the freeze id so
         # it can reject a host truncation id the device disagrees with
         decode.eos_id = eos_id
+        # every step returns the carry on the mesh; a carry created
+        # there (``init_carry(sharding=...)``) types the first step like
+        # every later one — one trace, one compile
+        carry_sharding = NamedSharding(mesh, P())
+        decode.carry_sharding = carry_sharding
         # ONE decode step streams this chip's OWN slice of the pool:
         # sharded projections (at the active width, + their fp32
         # scales) and the vocab-sharded embedding at 1/tp, replicated
@@ -1917,7 +1918,7 @@ class GPTModel:
                     f"(FMHA_DECODE_MAX_ROWS={FMHA_DECODE_MAX_ROWS}); "
                     "use a smaller chunk — serving stalls shrink with "
                     "it anyway (docs/serving.md)")
-            cj = jax.jit(shard_map(
+            cj = jax.jit(jax.shard_map(
                 _chunk, mesh=mesh,
                 in_specs=(specs, pool_specs, P(), P(), P(), P(), P(),
                           P()),
@@ -1978,7 +1979,7 @@ class GPTModel:
                         f"kernel's per-program row budget "
                         f"(FMHA_DECODE_MAX_ROWS="
                         f"{FMHA_DECODE_MAX_ROWS}); prune the tree")
-                sj = jax.jit(shard_map(
+                sj = jax.jit(jax.shard_map(
                     _spec_tree, mesh=mesh,
                     in_specs=(specs, pool_specs, rep(carry_tmpl), P(),
                               P(), P()),
@@ -1995,7 +1996,7 @@ class GPTModel:
                     return _sj(params, pools, carry, pt, drafts,
                                draft_len)
             else:
-                sj = jax.jit(shard_map(
+                sj = jax.jit(jax.shard_map(
                     _spec, mesh=mesh,
                     in_specs=(specs, pool_specs, rep(carry_tmpl), P(),
                               P(), P()),
@@ -2039,6 +2040,9 @@ class GPTModel:
             weight_dtype=wd_active,
             weight_stream_bytes=wbytes,
             tp=tp_size,
+            carry_sharding=carry_sharding,
+            param_specs=specs,
+            pool_specs=pool_specs,
         )
 
     def generate(
@@ -2156,8 +2160,6 @@ class GPTModel:
         max_position_embeddings``."""
         import numpy as np
 
-        from apex_tpu._compat import shard_map
-
         c = self.config
         prompts = np.asarray(prompts)
         prompt_lengths = np.asarray(prompt_lengths)
@@ -2175,11 +2177,16 @@ class GPTModel:
             idx = jnp.clip(lens - 1, 0, total - 1)
             last = jnp.take_along_axis(
                 logits, idx[:, None, None], axis=1)[:, 0]  # (b, V/tp)
+            # full vocab before the argmax: a local argmax over V/tp is
+            # a different token at tp>1, and the gather types the result
+            # replicated for the P() out_specs
+            last = gather_from_tensor_model_parallel_region(
+                last, axis_name=self.axis_name)
             nxt = jnp.argmax(last, axis=-1).astype(jnp.int32)
             buf = buf.at[jnp.arange(b), lens].set(nxt)
             return buf, lens + 1, nxt
 
-        fn = jax.jit(shard_map(
+        fn = jax.jit(jax.shard_map(
             step, mesh=mesh, in_specs=(specs, P(), P()),
             out_specs=(P(), P(), P()),
         ))
@@ -2414,7 +2421,7 @@ class GPTModel:
 
         fwd_bwd = get_forward_backward_func(
             virtual_pipeline_model_parallel_size=num_model_chunks,
-            pipeline_model_parallel_size=_axis_size(
+            pipeline_model_parallel_size=jax.lax.axis_size(
                 PIPELINE_PARALLEL_AXIS
             ),
         )
@@ -2439,7 +2446,7 @@ class GPTModel:
             # (MoE experts ride "dp" as the ep axis): the all_to_all
             # transpose already accumulated every shard's contribution
             # into the owner, so the mean is just the 1/n scale.
-            n = _axis_size(axis)
+            n = jax.lax.axis_size(axis)
             if axis in spec_axis_names(s):
                 return g / n
             return jax.lax.pmean(g, axis)

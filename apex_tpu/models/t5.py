@@ -54,7 +54,6 @@ from apex_tpu.transformer.tensor_parallel import (
     VocabParallelEmbedding,
     vocab_parallel_cross_entropy,
 )
-from apex_tpu._compat import axis_size as _axis_size
 
 __all__ = ["T5Config", "T5Model"]
 
@@ -75,8 +74,8 @@ class T5Config:
     # an amp.Policy drives the dtypes, as in GPTConfig/BertConfig
     policy: Optional[Any] = None
     remat: bool = True
-    # same measured defaults as GPTConfig (PROFILE_r03.md exps 1 and 5;
-    # fused_ce None = auto by logits size, see GPTConfig)
+    # same chip-measured defaults as GPTConfig (fused_ce None = auto
+    # by logits size, see GPTConfig)
     remat_policy: Optional[str] = "dots_with_no_batch_dims_saveable"
     fused_ce: Optional[bool] = None
     fused_ce_chunk: int = 8192
@@ -255,7 +254,7 @@ class T5Model:
         """(b, s, n*heads_local*d) → n arrays of (b, heads_local, s, d),
         head-grouped layout as in GPT (tp-invariant slices)."""
         c = self.config
-        world = _axis_size(self.axis_name)
+        world = jax.lax.axis_size(self.axis_name)
         heads_local = c.num_attention_heads // world
         b, s, _ = x.shape
         x = x.reshape(b, s, heads_local, n, c.head_dim)
@@ -742,7 +741,7 @@ class T5Model:
         )
 
         c = self.config
-        pp = _axis_size(PIPELINE_PARALLEL_AXIS)
+        pp = jax.lax.axis_size(PIPELINE_PARALLEL_AXIS)
         if parallel_state.get_pipeline_model_parallel_split_rank() is not None:
             fwd_bwd = get_forward_backward_func(
                 pipeline_model_parallel_size=pp,

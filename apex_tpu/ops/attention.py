@@ -49,12 +49,8 @@ import numpy as np
 from apex_tpu.ops.common import shape_struct
 from apex_tpu.utils.platform import default_implementation, is_tpu
 
-try:
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-except Exception:  # pragma: no cover
-    pl = None
-    pltpu = None
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["flash_attention", "mha_reference"]
 
@@ -354,9 +350,7 @@ def _fwd_in_specs(cfg, d, psq, psk, has_bias, has_segs, has_dropout,
 
 
 def _compiler_params():
-    from apex_tpu.ops.common import tpu_compiler_params
-
-    return tpu_compiler_params(
+    return pltpu.CompilerParams(
         dimension_semantics=("parallel", "parallel", "arbitrary")
     )
 
@@ -864,13 +858,7 @@ def flash_attention(
         raise ValueError("dropout_rate > 0 requires dropout_seed")
     if bias is not None and bias.ndim < 4:
         bias = bias.reshape((1,) * (4 - bias.ndim) + bias.shape)
-    from apex_tpu.ops.common import KernelLoweringError, run_kernel
-
-    if pl is None and implementation in ("pallas", "short", "mid"):
-        raise KernelLoweringError(
-            f"implementation={implementation!r} requested but Pallas "
-            "failed to import"
-        )
+    from apex_tpu.ops.common import run_kernel
 
     def _short_path(forced: bool):
         from apex_tpu.ops.attention_short import fmha_short
@@ -958,8 +946,6 @@ def flash_attention(
             # APEX_TPU_FMHA_MID_MAX_SEQ=0 pins this window back to
             # the flash kernel bit-identically)
             return _mid_path(forced=False)
-    if pl is None:
-        impl = "xla"
 
     def _xla_path():
         return mha_reference(
@@ -976,7 +962,7 @@ def flash_attention(
         )
 
     return run_kernel(
-        "flash_attention", _pallas_path, _xla_path, implementation, impl
+        "flash_attention", _pallas_path, _xla_path, impl
     )
 
 

@@ -70,12 +70,8 @@ import jax.numpy as jnp
 from apex_tpu.ops.attention import _NEG_INF, _interpret
 from apex_tpu.ops.common import shape_struct
 
-try:
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-except Exception:  # pragma: no cover
-    pl = None
-    pltpu = None
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 __all__ = [
     "fmha_decode",
@@ -357,14 +353,12 @@ def _decode_pallas(q, q_rot, cos, sin, k_pages, v_pages, k_scales,
             pltpu.VMEM((bh * sq, _LANES), jnp.float32),
         ],
     )
-    from apex_tpu.ops.common import tpu_compiler_params
-
     return pl.pallas_call(
         functools.partial(_decode_kernel, cfg=cfg),
         grid_spec=grid_spec,
         out_shape=shape_struct((b, h, sq, d), q.dtype, q, k_pages,
                                v_pages),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=_interpret(),
@@ -518,7 +512,7 @@ def fmha_decode(
                     f"ancestor row {i} attends a later row — the tree "
                     "must be topologically ordered (lower-triangular)")
 
-    from apex_tpu.ops.common import KernelLoweringError, run_kernel
+    from apex_tpu.ops.common import run_kernel
     from apex_tpu.utils.platform import default_implementation
 
     if implementation not in (None, "pallas", "xla", "decode"):
@@ -528,13 +522,7 @@ def fmha_decode(
         )
     if implementation == "decode":
         implementation = "pallas"
-    if pl is None and implementation == "pallas":
-        raise KernelLoweringError(
-            "implementation='pallas' requested but Pallas failed to import"
-        )
     impl = implementation or default_implementation()
-    if pl is None:
-        impl = "xla"
 
     def _xla_path():
         qq = q
@@ -578,7 +566,7 @@ def fmha_decode(
         )
 
     return run_kernel(
-        "fmha_decode", _pallas_path, _xla_path, implementation, impl
+        "fmha_decode", _pallas_path, _xla_path, impl
     )
 
 

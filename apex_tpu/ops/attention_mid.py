@@ -2,7 +2,7 @@
 
 The middle tier of the attention dispatch ladder
 (``docs/attention.md``), covering 512 < s <= ~2048 — the band the
-flagship actually trains in.  PROFILE_r05.md measured the flash kernel
+flagship actually trains in.  KERNELS_TPU.json measured the flash kernel
 at 10.2 TF/s fwd at s=1024 causal vs ~50 TF/s at s>=4096: with the
 measured-optimal 1024x1024 blocks the whole K/V sequence sits in ONE
 block, so the streamed-K/V design degenerates to one fused attention
@@ -84,12 +84,8 @@ from apex_tpu.ops.attention import (
 from apex_tpu.ops.common import shape_struct
 from apex_tpu.utils.platform import default_implementation
 
-try:
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-except Exception:  # pragma: no cover
-    pl = None
-    pltpu = None
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 __all__ = [
     "fmha_mid", "FMHA_MID_MAX_SEQ", "mid_seq_threshold",
@@ -505,18 +501,14 @@ def _in_specs(cfg, bb, d_p, has_bias, has_segs, has_dropout,
 
 
 def _compiler_params():
-    from apex_tpu.ops.common import tpu_compiler_params
-
-    return tpu_compiler_params(
+    return pltpu.CompilerParams(
         dimension_semantics=("parallel", "parallel", "arbitrary")
     )
 
 
 def _bwd_compiler_params():
-    from apex_tpu.ops.common import tpu_compiler_params
-
     # both block axes are serialized: dq accumulates across kb AND jq
-    return tpu_compiler_params(
+    return pltpu.CompilerParams(
         dimension_semantics=("parallel", "arbitrary", "arbitrary")
     )
 
@@ -812,7 +804,7 @@ def fmha_mid(
         raise ValueError("dropout_rate > 0 requires dropout_seed")
     if bias is not None and bias.ndim < 4:
         bias = bias.reshape((1,) * (4 - bias.ndim) + bias.shape)
-    from apex_tpu.ops.common import KernelLoweringError, run_kernel
+    from apex_tpu.ops.common import run_kernel
 
     if implementation == "mid":
         # the flash_attention-facing spelling: forcing "mid" on the mid
@@ -823,13 +815,7 @@ def fmha_mid(
             f"unknown implementation {implementation!r}; expected None, "
             "'pallas'/'mid', or 'xla'"
         )
-    if pl is None and implementation == "pallas":
-        raise KernelLoweringError(
-            "implementation='pallas' requested but Pallas failed to import"
-        )
     impl = implementation or default_implementation()
-    if pl is None:
-        impl = "xla"
 
     def _xla_path():
         if return_lse:
@@ -851,7 +837,7 @@ def fmha_mid(
         )
 
     return run_kernel(
-        "fmha_mid", _pallas_path, _xla_path, implementation, impl
+        "fmha_mid", _pallas_path, _xla_path, impl
     )
 
 

@@ -64,12 +64,8 @@ from apex_tpu.ops.attention import (
 from apex_tpu.ops.common import shape_struct
 from apex_tpu.utils.platform import default_implementation
 
-try:
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-except Exception:  # pragma: no cover
-    pl = None
-    pltpu = None
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["fmha_short", "FMHA_SHORT_MAX_SEQ", "short_seq_threshold"]
 
@@ -337,10 +333,8 @@ def _in_specs(cfg, sq_p, sk_p, d_p, has_bias, has_segs, has_dropout):
 
 
 def _compiler_params():
-    from apex_tpu.ops.common import tpu_compiler_params
-
     # every axis parallel: no serialized reduction dimension exists
-    return tpu_compiler_params(dimension_semantics=("parallel",))
+    return pltpu.CompilerParams(dimension_semantics=("parallel",))
 
 
 def _short_fwd_pallas(q, k, v, bias, qseg, kseg, seed, cfg: _ShortConfig):
@@ -552,7 +546,7 @@ def fmha_short(
         raise ValueError("dropout_rate > 0 requires dropout_seed")
     if bias is not None and bias.ndim < 4:
         bias = bias.reshape((1,) * (4 - bias.ndim) + bias.shape)
-    from apex_tpu.ops.common import KernelLoweringError, run_kernel
+    from apex_tpu.ops.common import run_kernel
 
     if implementation == "short":
         # the flash_attention-facing spelling: forcing "short" on the
@@ -565,13 +559,7 @@ def fmha_short(
             f"unknown implementation {implementation!r}; expected None, "
             "'pallas'/'short', or 'xla'"
         )
-    if pl is None and implementation == "pallas":
-        raise KernelLoweringError(
-            "implementation='pallas' requested but Pallas failed to import"
-        )
     impl = implementation or default_implementation()
-    if pl is None:
-        impl = "xla"
 
     def _xla_path():
         return mha_reference(
@@ -588,7 +576,7 @@ def fmha_short(
         )
 
     return run_kernel(
-        "fmha_short", _pallas_path, _xla_path, implementation, impl
+        "fmha_short", _pallas_path, _xla_path, impl
     )
 
 

@@ -2,68 +2,35 @@
 
 from __future__ import annotations
 
-import logging
-import os
-
 import jax
 
-__all__ = [
-    "shape_struct", "run_kernel", "KernelLoweringError",
-    "tpu_compiler_params",
-]
-
-
-def tpu_compiler_params(**kwargs):
-    """Version-portable ``pltpu.CompilerParams`` (renamed from
-    ``TPUCompilerParams`` across jax releases; 0.4.x ships the old
-    name)."""
-    from jax.experimental.pallas import tpu as pltpu
-
-    cls = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-    return cls(**kwargs)
-
-_logger = logging.getLogger("apex_tpu")
+__all__ = ["shape_struct", "run_kernel", "KernelLoweringError"]
 
 
 class KernelLoweringError(RuntimeError):
-    """A Pallas kernel failed to trace/lower on a path where falling back
-    silently is not allowed (explicit ``implementation='pallas'`` or
-    ``APEX_TPU_STRICT_KERNELS=1``)."""
+    """A Pallas kernel the dispatcher selected failed to trace/lower."""
 
 
-def run_kernel(name, pallas_fn, xla_fn, requested_impl, resolved_impl):
-    """Dispatch between a Pallas kernel and its XLA fallback.
+def run_kernel(name, pallas_fn, xla_fn, resolved_impl):
+    """Dispatch between a Pallas kernel and its XLA twin.
 
-    Fallback policy (the assertable contract the reference gets from its
-    import-time extension probing, apex/parallel/distributed.py:13-23):
-
-    - ``requested_impl == "pallas"``: the user asked for the kernel —
-      a lowering failure RAISES ``KernelLoweringError`` instead of
-      silently degrading.
-    - auto mode (``requested_impl is None``): a failure falls back to
-      XLA with a logged warning, unless ``APEX_TPU_STRICT_KERNELS=1``
-      makes every fallback an error (CI smoke mode).
+    ``resolved_impl`` is the dispatcher's choice (``"xla"`` off the TPU
+    unless the caller forced ``implementation="pallas"``).  A kernel
+    that was selected either runs or RAISES ``KernelLoweringError``
+    naming it: rerouting to XLA would hide from the user that the
+    device is running a slower program than the one they configured
+    (the assertable contract the reference gets from its import-time
+    extension probing, apex/parallel/distributed.py:13-23).
     """
     if resolved_impl != "pallas":
         return xla_fn()
-    strict = (
-        requested_impl == "pallas"
-        or bool(os.environ.get("APEX_TPU_STRICT_KERNELS"))
-    )
     try:
         return pallas_fn()
     except Exception as e:  # trace-time shape/lowering rejection
-        if strict:
-            raise KernelLoweringError(
-                f"pallas kernel {name!r} failed to lower and strict mode "
-                f"is on (explicit implementation='pallas' or "
-                f"APEX_TPU_STRICT_KERNELS=1): {e}"
-            ) from e
-        _logger.warning(
-            "pallas kernel %s unavailable (%s); falling back to XLA",
-            name, e,
-        )
-        return xla_fn()
+        raise KernelLoweringError(
+            f"pallas kernel {name!r} was selected and failed to "
+            f"lower: {e}"
+        ) from e
 
 
 def shape_struct(shape, dtype, *varying_like) -> jax.ShapeDtypeStruct:
@@ -71,11 +38,5 @@ def shape_struct(shape, dtype, *varying_like) -> jax.ShapeDtypeStruct:
     the union of the given operands' — required so ``pallas_call`` results
     type-check under ``shard_map(check_vma=True)``, e.g. when a kernel
     runs on dp-sharded activations inside a tensor-parallel region."""
-    try:
-        sets = [jax.typeof(x).vma for x in varying_like]
-        vma = frozenset().union(*sets) if sets else frozenset()
-        return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
-    except Exception:
-        # jax without typeof().vma / the ShapeDtypeStruct vma kwarg:
-        # plain struct (check_vma shard_map is unavailable there anyway)
-        return jax.ShapeDtypeStruct(shape, dtype)
+    vma = frozenset().union(*(jax.typeof(x).vma for x in varying_like))
+    return jax.ShapeDtypeStruct(shape, dtype, vma=vma)

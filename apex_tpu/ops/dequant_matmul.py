@@ -50,9 +50,10 @@ from typing import Any, Dict, Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from apex_tpu.ops.attention import _interpret
-from apex_tpu.ops.common import run_kernel, shape_struct, tpu_compiler_params
+from apex_tpu.ops.common import run_kernel, shape_struct
 from apex_tpu.ops.quantization import (
     dequantize_rows,
     quantize_rows,
@@ -133,7 +134,7 @@ def _int8_pallas(x, qw, scales, block_size):
         ],
         out_specs=pl.BlockSpec((m, bn), lambda j: (0, j)),
         out_shape=shape_struct((m, n), jnp.float32, x, qw, scales),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)
         ),
         interpret=_interpret(),
@@ -158,7 +159,7 @@ def _int4_pallas(x, qp, scales, block_size):
         ],
         out_specs=pl.BlockSpec((2, m, bn), lambda j: (0, 0, j)),
         out_shape=shape_struct((2, m, n2), jnp.float32, x, qp, scales),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)
         ),
         interpret=_interpret(),
@@ -253,8 +254,7 @@ def dequant_matmul(
             x2, qweight, scales, weight_dtype=weight_dtype,
             block_size=bs)
 
-    out = run_kernel("dequant_matmul", _pallas, _xla, implementation,
-                     impl)
+    out = run_kernel("dequant_matmul", _pallas, _xla, impl)
     return out.reshape(*lead, n)
 
 

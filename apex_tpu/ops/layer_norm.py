@@ -29,12 +29,8 @@ from apex_tpu.ops.common import run_kernel, shape_struct
 
 from apex_tpu.utils.platform import is_tpu
 
-try:  # imported lazily on CPU-only hosts that lack Mosaic
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-except Exception:  # pragma: no cover
-    pl = None
-    pltpu = None
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 __all__ = [
     "fused_layer_norm",
@@ -143,7 +139,6 @@ def _ln_fwd(x2d, eps, rms, implementation: Optional[str]):
         "fused_layer_norm",
         lambda: _ln_fwd_pallas(x2d, eps, rms),
         lambda: _ln_fwd_xla(x2d, eps, rms),
-        implementation,
         implementation or "xla",
     )
 

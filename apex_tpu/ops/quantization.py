@@ -139,12 +139,6 @@ def as_compression_config(
     )
 
 
-def _axis_size(axis_name) -> int:
-    from apex_tpu._compat import axis_size
-
-    return int(axis_size(axis_name))
-
-
 def _blocks(flat: jnp.ndarray, block_size: int) -> jnp.ndarray:
     n = flat.size
     pad = (-n) % block_size
@@ -500,7 +494,7 @@ def quantized_psum(
     when ``residual`` is None; the output has ``x``'s shape and dtype.
     """
     cfg = as_compression_config(compression)
-    world = _axis_size(axis_name)
+    world = jax.lax.axis_size(axis_name)
     block = cfg.block_size
     shape, dtype, n = x.shape, x.dtype, int(jnp.size(x))
     padded, shard = comm_residual_sizes(n, world, block)
@@ -576,7 +570,7 @@ def quantized_reduce_scatter(
     back as ``new_residual``).  Returns ``(chunk (n/world,),
     new_residual_or_None)``."""
     cfg = as_compression_config(compression)
-    world = _axis_size(axis_name)
+    world = jax.lax.axis_size(axis_name)
     n = int(jnp.size(x))
     if n % world:
         raise ValueError(
@@ -620,7 +614,7 @@ def quantized_all_gather(
     ``residual`` is the ``(shard,)`` ``ici_pull`` error-feedback
     buffer.  Returns ``(full (world*shard,), new_residual_or_None)``."""
     cfg = as_compression_config(compression)
-    world = _axis_size(axis_name)
+    world = jax.lax.axis_size(axis_name)
     shard = int(jnp.size(x))
     flat = x.reshape(-1).astype(jnp.float32)
     rkey = _rounding_key(cfg, axis_name, key, step)

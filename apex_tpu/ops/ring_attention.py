@@ -44,7 +44,6 @@ import jax.numpy as jnp
 from jax import lax
 
 from apex_tpu.transformer.parallel_state import CONTEXT_PARALLEL_AXIS
-from apex_tpu._compat import axis_size as _axis_size
 
 __all__ = ["ring_attention", "ring_attention_reference"]
 
@@ -83,10 +82,7 @@ def ring_attention(
     ``None`` for production XLA runs — both via the lse-merge
     formulation, which also
     SKIPS fully-masked source shards under causal (``block_k`` is then
-    unused; the kernel blocks internally).  On jax 0.4.x the Pallas
-    variants need the enclosing ``shard_map`` built with
-    ``check_rep=False`` (pallas_call has no replication rule there;
-    newer jax type-checks via the vma-aware ``shape_struct``).
+    unused; the kernel blocks internally).
     """
     b, h, s_local, d = q.shape
     scale = (1.0 / d**0.5) if sm_scale is None else float(sm_scale)
@@ -94,7 +90,7 @@ def ring_attention(
         return _ring_attention_merge(
             q, k, v, axis_name, causal, scale, remat, attention_impl
         )
-    cp = _axis_size(axis_name)
+    cp = jax.lax.axis_size(axis_name)
     rank = lax.axis_index(axis_name)
     perm = [(i, (i + 1) % cp) for i in range(cp)]
     bk = min(block_k, s_local)
@@ -188,7 +184,7 @@ def _ring_attention_merge(q, k, v, axis_name, causal, scale, remat, impl):
             "'mid'/'short'/'pallas', or 'xla'"
         )
     kernel_impl = "xla" if impl == "xla" else "pallas"
-    cp = _axis_size(axis_name)
+    cp = jax.lax.axis_size(axis_name)
     rank = lax.axis_index(axis_name)
     perm = [(i, (i + 1) % cp) for i in range(cp)]
 

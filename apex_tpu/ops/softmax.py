@@ -64,12 +64,8 @@ def _softmax_fwd_kernel(x_ref, o_ref, *, scale, causal, block_q):
     o_ref[0] = (ex / jnp.sum(ex, axis=-1, keepdims=True)).astype(o_ref.dtype)
 
 
-try:  # imported lazily on CPU-only hosts that lack Mosaic
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-except Exception:  # pragma: no cover
-    pl = None
-    pltpu = None
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _interpret() -> bool:
@@ -133,10 +129,6 @@ def _softmax_fwd_xla(
 def _softmax_fwd(x3d, mask, scale, causal, implementation):
     from apex_tpu.ops.common import KernelLoweringError
 
-    if pl is None and implementation == "pallas":
-        raise KernelLoweringError(
-            "implementation='pallas' requested but Pallas failed to import"
-        )
     if implementation == "pallas" and mask is not None:
         # no pallas kernel exists for the arbitrary-mask variant — honor
         # the no-silent-degradation contract by saying so loudly
@@ -153,7 +145,7 @@ def _softmax_fwd(x3d, mask, scale, causal, implementation):
     # cross-check tier; the fast path that matters for attention is the
     # flash kernel, which supersedes this op entirely.
     impl = implementation or "xla"
-    if mask is not None or pl is None:
+    if mask is not None:
         # the padded-mask variant is XLA-only by design: XLA fuses the
         # mask+softmax chain optimally, and the arbitrary-mask fast path
         # in this library is the flash-attention kernel's segment-id /
@@ -163,7 +155,6 @@ def _softmax_fwd(x3d, mask, scale, causal, implementation):
         "scaled_softmax",
         lambda: _softmax_fwd_pallas(x3d, scale, causal),
         lambda: _softmax_fwd_xla(x3d, scale, causal, mask),
-        implementation if mask is None else None,
         impl,
     )
 

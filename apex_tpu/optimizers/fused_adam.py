@@ -16,7 +16,7 @@ off / parity-preserving):
   unscale → clip → moment update → cast chain as ONE multi-tensor
   pass per buffer (:mod:`apex_tpu.optimizers.fused_tail`) —
   bit-identical at default settings, targeting the measured
-  440 → 819 GB/s optimizer-tail bandwidth gap (PROFILE_r05.md);
+  440 → 819 GB/s optimizer-tail bandwidth gap (PROFILE_r05.json);
 - ``exp_avg_sq_dtype=jnp.bfloat16`` stores the second moment sub-fp32
   (math stays fp32; only the storage rounds).  Halves the
   ``exp_avg_sq`` bytes the tail reads and writes; safe for typical

@@ -1,8 +1,8 @@
 """Fused optimizer tail: ONE multi-tensor pass over bucketed buffers.
 
-PROFILE_r05.md pins the flagship's optimizer tail at ~440 GB/s against
-the chip's ~819 GB/s paper bandwidth — 11.85 ms measured vs 6.35 ms
-ideal, the single biggest non-attention step-time hole left.  The gap
+PROFILE_r05.json (a chip run before PR 1) puts the flagship's optimizer
+tail at 41.32 − 29.47 = 11.85 ms, ~440 GB/s against the chip's
+~819 GB/s paper bandwidth (6.35 ms ideal), the single biggest non-attention step-time hole left.  The gap
 is pass structure, not math: the seed chain runs the scaler's unscale
 as its own read+write over every gradient (``amp/scaler.py``), a
 separate finiteness reduction, and then the per-leaf ``upd`` chain in
@@ -198,7 +198,7 @@ def tail_traffic_bytes(params: Any, opt) -> int:
     """HBM bytes one fused tail step moves under the paper model: read
     grads + moments (+ master), write params + moments (+ master) —
     the denominator of the achieved-GB/s number
-    (PROFILE_r05.md's 440-vs-819 GB/s framing)."""
+    (the 440-vs-819 GB/s framing above)."""
     total = 0
     master = bool(getattr(opt, "master_weights", False))
     v_itemsize = jnp.dtype(

@@ -74,14 +74,6 @@ __all__ = [
 ]
 
 
-def _axis_size(axis_name):
-    """Version-portable ``jax.lax.axis_size`` (absent in jax 0.4.x,
-    where the axis extent comes from the bound mesh context)."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    return jax.lax.psum(1, axis_name)
-
-
 def data_parallel_mesh(
     devices: Optional[Sequence] = None, axis_name: str = "dp"
 ) -> Mesh:
@@ -139,7 +131,7 @@ def _hierarchical_psum(g: jnp.ndarray, dcn_axis: str, ici_axis: str,
     )
 
     n = g.size
-    ici = _axis_size(ici_axis)
+    ici = jax.lax.axis_size(ici_axis)
     flat = g.reshape(-1)
     pad = (-n) % ici
     if pad:
@@ -309,9 +301,9 @@ def all_reduce_gradients(
         )
     if hierarchical:
         dcn_axis, ici_axis = axis_name
-        world = _axis_size(dcn_axis) * _axis_size(ici_axis)
+        world = jax.lax.axis_size(dcn_axis) * jax.lax.axis_size(ici_axis)
     else:
-        world = _axis_size(axis_name)
+        world = jax.lax.axis_size(axis_name)
 
     step = None if comm_state is None else comm_state["step"]
 
@@ -419,9 +411,9 @@ def emit_bucket_comm_events(plan, axis_name, cfg, where: str) -> None:
     hierarchical = isinstance(axis_name, (tuple, list))
     if hierarchical:
         dcn_axis, ici_axis = axis_name
-        dcn, ici = _axis_size(dcn_axis), _axis_size(ici_axis)
+        dcn, ici = jax.lax.axis_size(dcn_axis), jax.lax.axis_size(ici_axis)
     else:
-        world = _axis_size(axis_name)
+        world = jax.lax.axis_size(axis_name)
     for name, b in zip(plan.names, plan.buckets):
         itemsize = int(np.dtype(b.dtype).itemsize)
         fields = {
@@ -492,7 +484,7 @@ def _check_bucketed_state(plan, comm_state, cfg, dcn_axis,
         )
     if not cfg.error_feedback:
         return
-    dcn, ici = _axis_size(dcn_axis), _axis_size(ici_axis)
+    dcn, ici = jax.lax.axis_size(dcn_axis), jax.lax.axis_size(ici_axis)
     for name, b in zip(plan.names, plan.buckets):
         sizes = hierarchical_residual_sizes(
             b.size, dcn, ici, cfg.block_size, cfg.ici_legs
@@ -572,7 +564,7 @@ def init_comm_state(
         dcn, ici = mesh.shape[dcn_axis], mesh.shape[ici_axis]
         replicas = dcn * ici
     else:
-        dcn, ici = _axis_size(dcn_axis), _axis_size(ici_axis)
+        dcn, ici = jax.lax.axis_size(dcn_axis), jax.lax.axis_size(ici_axis)
         replicas = 1
 
     def local_size(leaf, spec) -> int:
@@ -1108,9 +1100,9 @@ class Reducer:
         if isinstance(self.axis_name, (tuple, list)):
             world = 1
             for ax in self.axis_name:
-                world *= _axis_size(ax)
+                world *= jax.lax.axis_size(ax)
         else:
-            world = _axis_size(self.axis_name)
+            world = jax.lax.axis_size(self.axis_name)
         # the exact scaling ops of the deferred path (sync()'s post
         # divide, then the microbatch mean), so K=1 is bit-identical
         if self.gradient_average:

@@ -15,11 +15,19 @@ APEX_TPU_COORDINATOR env vars; call :func:`initialize_distributed` at
 the top of the script to join the cluster (the analog of the
 reference's ``initialize_distributed`` env-var recipe,
 apex/transformer/testing/commons.py:81-113).
+
+This is a multi-HOST launcher.  On a host with local TPU chips ONE
+process drives all of them: every child would see every local chip, the
+first to start takes them, and the second fails or hangs.  So
+``--nprocs > 1`` is refused there unless the caller has divided the
+chips itself (``TPU_VISIBLE_CHIPS`` and friends) or pinned the children
+off the TPU (``JAX_PLATFORMS=cpu``).
 """
 
 from __future__ import annotations
 
 import argparse
+import glob
 import os
 import subprocess
 import sys
@@ -42,6 +50,40 @@ def initialize_distributed() -> None:
     )
 
 
+#: libtpu's chip-visibility variables: a caller that sets one has
+#: divided the local chips between the processes itself
+_CHIP_VISIBILITY_VARS = (
+    "TPU_VISIBLE_CHIPS", "TPU_VISIBLE_DEVICES",
+    "TPU_CHIPS_PER_PROCESS_BOUNDS", "TPU_PROCESS_BOUNDS",
+)
+
+
+def _local_tpu_chips() -> list:
+    """Device nodes of this host's TPU chips — found WITHOUT importing
+    jax, which would make the launcher itself take the chips."""
+    return sorted(glob.glob("/dev/accel[0-9]*")
+                  + glob.glob("/dev/vfio/[0-9]*"))
+
+
+def _refuse_shared_chips(nprocs: int) -> None:
+    if nprocs <= 1:
+        return
+    platforms = os.environ.get("JAX_PLATFORMS", "")
+    if platforms and "tpu" not in platforms.split(","):
+        return
+    if any(os.environ.get(v) for v in _CHIP_VISIBILITY_VARS):
+        return
+    chips = _local_tpu_chips()
+    if chips:
+        raise SystemExit(
+            f"multiproc: --nprocs {nprocs} on a host with local TPU "
+            f"chips ({', '.join(chips)}): one process drives all local "
+            "chips, so a second process that sees them fails or hangs. "
+            "Run the script directly, or divide the chips yourself "
+            f"({' / '.join(_CHIP_VISIBILITY_VARS[:2])}), or pin the "
+            "children off the TPU with JAX_PLATFORMS=cpu.")
+
+
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(
         description="spawn N local processes for multi-host-style SPMD"
@@ -52,6 +94,7 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
     if not args.script:
         ap.error("no script given")
+    _refuse_shared_chips(args.nprocs)
 
     procs = []
     for rank in range(args.nprocs):

@@ -359,9 +359,8 @@ def bucket_comm_state(
         dcn, ici = mesh.shape[dcn_axis], mesh.shape[ici_axis]
         replicas = dcn * ici
     else:
-        from apex_tpu._compat import axis_size
-
-        dcn, ici = int(axis_size(dcn_axis)), int(axis_size(ici_axis))
+        dcn = jax.lax.axis_size(dcn_axis)
+        ici = jax.lax.axis_size(ici_axis)
         replicas = 1
 
     residuals = {}

@@ -24,7 +24,6 @@ from typing import Any, Optional, Tuple
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
-from apex_tpu._compat import axis_size as _axis_size
 
 __all__ = ["sync_batch_norm", "SyncBatchNorm"]
 
@@ -81,7 +80,7 @@ def sync_batch_norm(
             stacked_c = jax.lax.all_gather(local_count, axis_name)
             stacked_s = jax.lax.all_gather(local_sum, axis_name)
             stacked_q = jax.lax.all_gather(local_sumsq, axis_name)
-            world = _axis_size(axis_name)
+            world = jax.lax.axis_size(axis_name)
             members = (jnp.arange(world) // process_group_size) == group
             count = jnp.sum(jnp.where(members, stacked_c, 0.0))
             total_sum = jnp.sum(

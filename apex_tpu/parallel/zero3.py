@@ -4,8 +4,9 @@ gather-on-use / reduce-scatter-into-shard collectives.
 The ZeRO optimizers (:mod:`apex_tpu.contrib.optimizers.distributed`)
 shard the *optimizer state* over the data axis but keep a replicated
 copy of every parameter on every device — which is exactly what caps
-the flagship at h≈1024 on 16 GB HBM (PROFILE_r05.md: MFU 0.55+ is an
-h≥4096 property, and the replicated layout cannot hold that model).
+the flagship at h≈1024 on 16 GB HBM (the roofline argument, not yet
+measured — ROADMAP S2 — is that MFU 0.55+ needs h≥4096, and the
+replicated layout cannot hold that model).
 "Automatic Cross-Replica Sharding of Weight Update in Data-Parallel
 Training" (PAPERS.md, arXiv 2004.13336) is the TPU design this module
 implements: parameters live *permanently* as 1-D fp32 shards, are
@@ -76,12 +77,6 @@ from apex_tpu.parallel.overlap import (
 from apex_tpu.telemetry import events as _events
 
 __all__ = ["Zero3Layout", "zero3_comm_state", "zero3_comm_specs"]
-
-
-def _axis_size(axis_name) -> int:
-    from apex_tpu._compat import axis_size
-
-    return int(axis_size(axis_name))
 
 
 def _split_axes(axis_name) -> Tuple[Optional[str], str]:
@@ -456,7 +451,7 @@ class Zero3Layout:
         from apex_tpu.telemetry.events import ring_wire_bytes
 
         _, shard_axis = _split_axes(axis_name)
-        ici = _axis_size(shard_axis)
+        ici = jax.lax.axis_size(shard_axis)
         quantize = cfg is not None and cfg.ici_legs
         for i, (name, b) in enumerate(
             zip(self.names, self.plan.buckets)
@@ -521,7 +516,7 @@ def zero3_comm_state(layout: Zero3Layout, axis_name, compression,
     if mesh is not None:
         dcn, ici = mesh.shape[dcn_axis], mesh.shape[ici_axis]
     else:
-        dcn, ici = _axis_size(dcn_axis), _axis_size(ici_axis)
+        dcn, ici = jax.lax.axis_size(dcn_axis), jax.lax.axis_size(ici_axis)
     sizes = layout.residual_sizes(dcn, ici, cfg)
     residuals = {}
     for name, per in sizes.items():

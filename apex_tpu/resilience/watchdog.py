@@ -19,10 +19,9 @@ Externally visible liveness: with a ``heartbeat_file`` (or
 ``$APEX_TPU_HEARTBEAT_FILE``) each :meth:`beat` also writes a tiny
 JSON record — ``{"at": <unix>, "pid": ..., "step": ...}`` — atomically
 (tmp + rename) and throttled to ~1 write/s, where out-of-process
-observers read it: ``tools/tpu_watch.py`` reports the trainer's
-heartbeat age while it waits on the chip pool, so "the training job is
-alive but stalled" and "the training job is gone" are distinguishable
-from outside.  Stall detections additionally emit a
+observers read it with :func:`read_heartbeat` (the record plus its
+age), so "the training job is alive but stalled" and "the training job
+is gone" are distinguishable from outside.  Stall detections additionally emit a
 ``watchdog_stall`` telemetry event.
 """
 
@@ -112,7 +111,7 @@ class Watchdog:
         logged, never raised, and never cancel the abort.
     heartbeat_file:
         Where :meth:`beat` mirrors liveness for out-of-process readers
-        (:func:`read_heartbeat`, ``tools/tpu_watch.py``).  Defaults to
+        (:func:`read_heartbeat`).  Defaults to
         ``$APEX_TPU_HEARTBEAT_FILE``; None/unset disables the mirror
         (the in-process stall detection is unaffected).
 
@@ -196,8 +195,8 @@ class Watchdog:
         observers see ``{"at", "pid", "step"}`` plus any ``extra``
         fields — the serving fleet passes
         ``{"replica", "serving_step", "live_slots"}`` per pump so
-        ``tools/tpu_watch.py`` can NAME the stalled replica, not just
-        report a stale timestamp."""
+        a :func:`read_heartbeat` caller can NAME the stalled replica,
+        not just report a stale timestamp."""
         self._last_beat = time.monotonic()
         self._tripped = False
         hb = self.heartbeat_file

@@ -216,21 +216,25 @@ class HandoffPacket:
     hashes: Optional[List[bytes]] = None
 
 
-def init_carry(max_seqs: int, key: Optional[jnp.ndarray] = None
-               ) -> Dict[str, jnp.ndarray]:
+def init_carry(max_seqs: int, key: Optional[jnp.ndarray] = None,
+               sharding: Optional[Any] = None) -> Dict[str, jnp.ndarray]:
     """The decode step's per-slot device state: all slots idle.
     ``sample_keys`` holds one PRNG key row per slot (overwritten at
-    admission — from ``Request.seed`` when given)."""
+    admission — from ``Request.seed`` when given).  ``sharding`` places
+    it once where the compiled steps return it
+    (``GPTDecodeFns.carry_sharding``): a carry that starts off the mesh
+    makes the second decode step compile again."""
     s = max_seqs
     base = jnp.asarray(
         key if key is not None else jax.random.PRNGKey(0), jnp.uint32)
-    return {
+    carry = {
         "tokens": jnp.zeros((s,), jnp.int32),
         "lengths": jnp.zeros((s,), jnp.int32),
         "steps_left": jnp.zeros((s,), jnp.int32),
         "done": jnp.ones((s,), bool),
         "sample_keys": jnp.broadcast_to(base[None], (s,) + base.shape),
     }
+    return carry if sharding is None else jax.device_put(carry, sharding)
 
 
 class ContinuousBatcher:
@@ -460,7 +464,9 @@ class ContinuousBatcher:
         self.harvest_every = int(harvest_every)
         self.eos_id = eos_id
         self.logger = logger
-        self.carry = init_carry(cache.config.max_seqs, key)
+        self.carry = init_carry(
+            cache.config.max_seqs, key,
+            sharding=getattr(decode_fn, "carry_sharding", None))
         self._base_key = (key if key is not None
                           else jax.random.PRNGKey(0))
         self._n_admits = 0

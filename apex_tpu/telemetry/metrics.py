@@ -79,9 +79,11 @@ def transformer_flops_per_token(n_params: int, num_layers: int,
 
 
 def device_peak_flops(device: Any = None) -> Optional[float]:
-    """Per-chip peak bf16 FLOP/s by device kind (public spec sheets);
-    None for hosts with no table entry (CPU) — MFU is then omitted
-    rather than fabricated."""
+    """Per-chip peak bf16 FLOP/s by device kind (public spec sheets).
+    ``None`` off the TPU (a CPU host has no peak worth dividing by —
+    MFU is then omitted rather than fabricated); a TPU whose
+    ``device_kind`` matches no row RAISES, so MFU is never silently
+    dropped on the machine it exists for."""
     if device is None:
         device = jax.devices()[0]
     kind = getattr(device, "device_kind", "").lower()
@@ -96,6 +98,10 @@ def device_peak_flops(device: Any = None) -> Optional[float]:
     for key, peak in table:
         if key in kind:
             return peak
+    if getattr(device, "platform", "") == "tpu":
+        raise ValueError(
+            f"no peak-FLOP/s row for TPU device_kind "
+            f"{device.device_kind!r}; add it to device_peak_flops")
     return None
 
 
@@ -152,11 +158,15 @@ class StepStats:
 
     @property
     def peak_flops(self) -> Optional[float]:
+        """The denominator of MFU: the peak of EVERY device the job
+        runs on (``tokens_per_step`` is the global batch, so the peak
+        must be global too — one chip's peak would report a four-chip
+        job at four times its utilization).  ``"auto"`` resolves it
+        from the device table; an unknown TPU kind raises there."""
         if self._peak == "auto":
-            try:
-                self._peak = device_peak_flops()
-            except Exception:  # backend not initialized / unreachable
-                self._peak = None
+            per_chip = device_peak_flops()
+            self._peak = (None if per_chip is None
+                          else per_chip * jax.device_count())
         return self._peak
 
     @property

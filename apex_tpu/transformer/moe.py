@@ -34,7 +34,6 @@ from apex_tpu.transformer.parallel_state import (
     DATA_PARALLEL_AXIS,
     TENSOR_PARALLEL_AXIS,
 )
-from apex_tpu._compat import axis_size as _axis_size
 
 __all__ = ["MoEMLP"]
 
@@ -127,7 +126,7 @@ class MoEMLP:
         n = b * s
         E = self.num_experts
         k = self.top_k
-        ep = _axis_size(self.ep_axis)
+        ep = jax.lax.axis_size(self.ep_axis)
         e_local = E // ep
         # expected assignments per expert: k*n/E (each token makes k
         # choices — GShard/ST-MoE convention)

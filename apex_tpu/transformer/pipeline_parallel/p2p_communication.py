@@ -24,7 +24,6 @@ import jax
 from jax import lax
 
 from apex_tpu.transformer.parallel_state import PIPELINE_PARALLEL_AXIS
-from apex_tpu._compat import axis_size as _axis_size
 
 __all__ = [
     "send_forward",
@@ -39,7 +38,7 @@ def _ring_perm(size: int, shift: int):
 
 
 def _shift(tree: Any, axis_name: str, shift: int) -> Any:
-    size = _axis_size(axis_name)
+    size = jax.lax.axis_size(axis_name)
     perm = _ring_perm(size, shift)
     return jax.tree.map(lambda x: lax.ppermute(x, axis_name, perm), tree)
 

@@ -41,7 +41,6 @@ from apex_tpu.transformer.pipeline_parallel.p2p_communication import (
     send_forward,
     send_forward_recv_backward,
 )
-from apex_tpu._compat import axis_size as _axis_size, pcast as _pcast
 
 __all__ = [
     "pipeline",
@@ -68,7 +67,7 @@ def _ensure_varying(tree: Any, axis_name: str) -> Any:
                 return x
         except Exception:
             pass
-        return _pcast(x, axis_name, to="varying")
+        return jax.lax.pcast(x, axis_name, to="varying")
 
     return jax.tree.map(cast, tree)
 
@@ -94,7 +93,7 @@ def _cast_varying(tree: Any, axes: set) -> Any:
         except AttributeError:
             have = set()
         for ax in sorted(axes - have):
-            x = _pcast(x, ax, to="varying")
+            x = jax.lax.pcast(x, ax, to="varying")
         return x
 
     return jax.tree.map(cast, tree)
@@ -197,7 +196,7 @@ def pipeline(
     replicated over the pipeline axis.  Differentiate through this for
     the backward pipeline.
     """
-    pp = _axis_size(axis_name)
+    pp = jax.lax.axis_size(axis_name)
     stage = lax.axis_index(axis_name)
     num_micro = jax.tree.leaves(microbatches)[0].shape[0]
     ticks = num_micro + pp - 1
@@ -325,8 +324,7 @@ def _bwd_tick(
 
     The head and embedding vjps ride ``lax.cond``s gated on
     ``bwd_valid`` too, so each runs exactly M times per schedule —
-    matching the reference's per-microbatch count (VERDICT r3 weak #3;
-    the old exit-stage predicate paid one head per tick).  Safe in
+    matching the reference's per-microbatch count (the old exit-stage predicate paid one head per tick).  Safe in
     SPMD: the predicates depend only on (t, pipeline rank), so every
     device in a tp group takes the same branch and the head's tp
     collectives stay consistent within their groups.
@@ -405,7 +403,7 @@ def pipeline_1f1b(
     Returns ``(losses, grads)``: the (M,) per-microbatch losses
     (replicated over the pipeline axis) and ``d(mean losses)/d params``.
     """
-    pp = _axis_size(axis_name)
+    pp = jax.lax.axis_size(axis_name)
     stage = lax.axis_index(axis_name)
     num_micro = jax.tree.leaves(microbatches)[0].shape[0]
     ticks = num_micro + 2 * pp - 2
@@ -561,7 +559,7 @@ def pipeline_1f1b_interleaved(
 
     Returns ``(losses, grads)`` exactly like :func:`pipeline_1f1b`.
     """
-    pp = _axis_size(axis_name)
+    pp = jax.lax.axis_size(axis_name)
     stage = lax.axis_index(axis_name)
     V = num_model_chunks
     num_micro = jax.tree.leaves(microbatches)[0].shape[0]
@@ -700,7 +698,7 @@ def pipeline_encdec(
     microbatch after the ring scan.  Differentiate through the result
     for the reverse pipeline.
     """
-    pp = _axis_size(axis_name)
+    pp = jax.lax.axis_size(axis_name)
     stage = lax.axis_index(axis_name)
     if not (1 <= split_stage < pp):
         raise ValueError(
@@ -813,7 +811,7 @@ def pipeline_encdec_fused(
     microbatch after the scan.  Differentiate through the result for
     the reverse pipeline.
     """
-    pp = _axis_size(axis_name)
+    pp = jax.lax.axis_size(axis_name)
     stage = lax.axis_index(axis_name)
     if not (1 <= split_stage < pp):
         raise ValueError(
@@ -917,7 +915,7 @@ def pipeline_encdec_fused_1f1b(
     with grads = d(mean losses)/d params, shard-local in the data axes,
     shared-param pp-sync NOT yet applied.
     """
-    pp = _axis_size(axis_name)
+    pp = jax.lax.axis_size(axis_name)
     stage = lax.axis_index(axis_name)
     if not (1 <= split_stage < pp):
         raise ValueError(
@@ -1140,7 +1138,7 @@ def forward_backward_pipelining_with_interleaving(
       :func:`pipeline`.
     Returns per-microbatch ``last_fn`` results, replicated over pp.
     """
-    pp = _axis_size(axis_name)
+    pp = jax.lax.axis_size(axis_name)
     rank = lax.axis_index(axis_name)
     V = num_model_chunks
     num_micro = jax.tree.leaves(microbatches)[0].shape[0]

@@ -21,7 +21,6 @@ from jax import lax
 
 from apex_tpu.transformer.parallel_state import TENSOR_PARALLEL_AXIS
 from apex_tpu.transformer.tensor_parallel.utils import VocabUtility
-from apex_tpu._compat import axis_size as _axis_size, pcast as _pcast
 
 __all__ = [
     "vocab_parallel_cross_entropy",
@@ -31,7 +30,7 @@ __all__ = [
 
 
 # one measured default for BOTH fused-CE entry points (v5e bench config:
-# −1.6 ms/step at 8192, PROFILE_r03.md exp 5); ADVICE r3: the two
+# −1.6 ms/step at 8192, chip run before PR 1); ADVICE r3: the two
 # signatures previously disagreed (8192 vs 4096)
 FUSED_CE_DEFAULT_CHUNK = 8192
 
@@ -43,7 +42,7 @@ FUSED_CE_DEFAULT_CHUNK = 8192
 # big enough to hit the HBM wall.  Measured on TPU v5 lite at the
 # flagship GPT config (8192 tokens x 32768 vocab = 1.07 GB residual):
 # two-step 107.4 ms/step vs fused@8192 110.1 — reproduced across two
-# chip sessions (BENCH r4+r5 A/B, LAST_TPU_BENCH.json ab.fused_ce).
+# chip sessions before PR 1 (PROFILE_r05.json: 103.53 vs 106.07).
 FUSED_CE_AUTO_BYTES = int(
     os.environ.get("APEX_TPU_FUSED_CE_BYTES", str(2 << 30))
 )
@@ -122,7 +121,7 @@ def vocab_parallel_cross_entropy(
     Returns (...) float32 losses.
     """
     logits = vocab_parallel_logits.astype(jnp.float32)
-    world = _axis_size(axis_name)
+    world = jax.lax.axis_size(axis_name)
     rank = jax.lax.axis_index(axis_name)
     per = logits.shape[-1]
     start, end = VocabUtility.vocab_range_from_per_partition_vocab_size(
@@ -183,12 +182,12 @@ def _varying_like(arr, axis_name, *refs):
     except AttributeError:
         have = set()
     for ax in sorted(need - have):
-        arr = _pcast(arr, ax, to="varying")
+        arr = jax.lax.pcast(arr, ax, to="varying")
     return arr
 
 
 def _vocab_range(weight, axis_name):
-    world = _axis_size(axis_name)
+    world = jax.lax.axis_size(axis_name)
     rank = lax.axis_index(axis_name)
     return VocabUtility.vocab_range_from_per_partition_vocab_size(
         weight.shape[0], rank, world
@@ -257,7 +256,7 @@ def _ce_fwd_scan(x, weight, bias, target, axis_name, chunk, smoothing):
         # semantics): loss = lse - (1-s)*target - s*mean(logits).
         # One stacked psum carries both the target logit and the logit
         # sum, keeping the collective count at three.
-        vocab_global = weight.shape[0] * _axis_size(axis_name)
+        vocab_global = weight.shape[0] * jax.lax.axis_size(axis_name)
         target_logit, sl_g = lax.psum(
             jnp.stack([picked, sl]), axis_name
         )
@@ -284,7 +283,7 @@ def _ce_bwd(axis_name, chunk, smoothing, residuals, g):
     recomputed, never stored); dx accumulates across chunks, dW stacks."""
     x, weight, bias, local_target, in_range, global_max, sum_exp = residuals
     num_chunks = weight.shape[0] // chunk
-    vocab_global = weight.shape[0] * _axis_size(axis_name)
+    vocab_global = weight.shape[0] * jax.lax.axis_size(axis_name)
     gf = g.astype(jnp.float32)
 
     def body(dx, c):

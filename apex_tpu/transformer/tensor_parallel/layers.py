@@ -38,7 +38,6 @@ from apex_tpu.transformer.tensor_parallel.mappings import (
     reduce_from_tensor_model_parallel_region,
     scatter_to_tensor_model_parallel_region,
 )
-from apex_tpu._compat import axis_size as _axis_size
 
 __all__ = ["ColumnParallelLinear", "RowParallelLinear", "VocabParallelEmbedding", "state_specs_like"]
 
@@ -244,7 +243,7 @@ class VocabParallelEmbedding:
 
     def apply(self, params: Dict[str, jnp.ndarray], ids: jnp.ndarray) -> jnp.ndarray:
         w = params["weight"]
-        world = _axis_size(self.axis_name)
+        world = jax.lax.axis_size(self.axis_name)
         rank = jax.lax.axis_index(self.axis_name)
         start, end = VocabUtility.vocab_range_from_per_partition_vocab_size(
             self.num_embeddings // world, rank, world

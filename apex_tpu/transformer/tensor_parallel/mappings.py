@@ -36,7 +36,6 @@ import jax.numpy as jnp
 from jax._src.lax import parallel as _lax_parallel
 
 from apex_tpu.transformer.parallel_state import TENSOR_PARALLEL_AXIS
-from apex_tpu._compat import axis_size as _axis_size
 
 __all__ = [
     "copy_to_tensor_model_parallel_region",
@@ -50,16 +49,12 @@ __all__ = [
 def all_gather_invariant(x, axis_name, *, axis: int = 0, tiled: bool = False):
     """All-gather producing a vma-*invariant* (replicated-typed) result.
 
-    Single shim point for the private JAX symbol (no public export in the
-    pinned jax version); everything in apex_tpu gathers through here.
-    jax 0.4.x has no vma typing (and no such symbol): the plain
-    all_gather is already replicated-typed under its check_rep.
+    Single shim point for the private JAX symbol (no public export in
+    the installed jax); everything in apex_tpu gathers through here.
     """
-    if hasattr(_lax_parallel, "all_gather_invariant"):
-        return _lax_parallel.all_gather_invariant(
-            x, axis_name, axis=axis, tiled=tiled
-        )
-    return jax.lax.all_gather(x, axis_name, axis=axis, tiled=tiled)
+    return _lax_parallel.all_gather_invariant(
+        x, axis_name, axis=axis, tiled=tiled
+    )
 
 
 def copy_to_tensor_model_parallel_region(x, axis_name=TENSOR_PARALLEL_AXIS):
@@ -81,7 +76,7 @@ def reduce_from_tensor_model_parallel_region(x, axis_name=TENSOR_PARALLEL_AXIS):
 def scatter_to_tensor_model_parallel_region(x, axis_name=TENSOR_PARALLEL_AXIS):
     """Keep this rank's chunk of the last dim; backward all-gathers
     (reference: apex/transformer/tensor_parallel/mappings.py:113-127)."""
-    world = _axis_size(axis_name)
+    world = jax.lax.axis_size(axis_name)
     if x.shape[-1] % world != 0:
         raise ValueError(
             f"scatter_to_tensor_model_parallel_region: last dim "

@@ -4,7 +4,6 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from apex_tpu._compat import axis_size as _axis_size
 
 __all__ = [
     "ensure_divisibility",
@@ -31,7 +30,7 @@ def split_tensor_into_1d_equal_chunks(x: jnp.ndarray, axis_name: str = "tp"):
     shard_map — the scatter half of the pipeline scatter/gather
     optimization (reference: apex/transformer/utils.py:19-27)."""
     flat = x.reshape(-1)
-    world = _axis_size(axis_name)
+    world = jax.lax.axis_size(axis_name)
     ensure_divisibility(flat.shape[0], world)
     rank = jax.lax.axis_index(axis_name)
     chunk = flat.shape[0] // world

@@ -24,7 +24,6 @@ import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from apex_tpu import amp
-from apex_tpu._compat import shard_map
 from apex_tpu.models import BertConfig, BertModel
 from apex_tpu.optimizers import FusedAdam
 from apex_tpu.telemetry.metrics import MetricsLogger, StepStats
@@ -154,7 +153,7 @@ def main(argv=None):
         opt.build_layout(params, mesh=mesh)
         shard_spec = opt.shard_spec(model_axes=("tp",))
         opt_specs = opt.state_specs(model_axes=("tp",))
-        init_shards = jax.jit(shard_map(
+        init_shards = jax.jit(jax.shard_map(
             opt.init_shards, mesh=mesh, in_specs=(specs,),
             out_specs=shard_spec))
     else:
@@ -241,7 +240,7 @@ def main(argv=None):
     data_spec = P(data_axes if hier else "dp")
     store_spec = shard_spec if args.zero3 else specs
     jstep = jax.jit(
-        shard_map(
+        jax.shard_map(
             train_step, mesh=mesh,
             in_specs=(store_spec, opt_specs, comm_specs,
                       data_spec, data_spec, data_spec),
@@ -257,7 +256,7 @@ def main(argv=None):
                 p = reestablish_replicated(p, specs)
         return cls_loss(p, tokens, mask, labels)
 
-    jeval = jax.jit(shard_map(
+    jeval = jax.jit(jax.shard_map(
         eval_fn, mesh=mesh,
         in_specs=(store_spec, data_spec, data_spec, data_spec),
         out_specs=(P(), P()),
@@ -268,7 +267,7 @@ def main(argv=None):
                         is_leaf=lambda x: isinstance(x, P)))
     if args.zero3:
         p = init_shards(place(params, specs))
-        s = jax.jit(shard_map(
+        s = jax.jit(jax.shard_map(
             opt.init, mesh=mesh, in_specs=(shard_spec,),
             out_specs=opt_specs))(p)
         jax.block_until_ready(p)

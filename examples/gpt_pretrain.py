@@ -40,7 +40,6 @@ import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from apex_tpu import amp
-from apex_tpu._compat import shard_map
 from apex_tpu.models import GPTConfig, GPTModel
 from apex_tpu.optimizers import FusedAdam
 from apex_tpu.telemetry.metrics import (
@@ -57,11 +56,18 @@ from apex_tpu.utils.autoresume import AutoResume
 
 
 def batches(rng, n_batches, global_batch, seq, vocab):
-    """Pre-generated synthetic LM batches (see --data for a corpus)."""
+    """Pre-generated synthetic LM batches (see --data for a corpus).
+
+    Token ids are Zipf-distributed (p ∝ 1/rank), like text: a uniform
+    stream sits at its entropy floor ln(vocab) from step 0, so its loss
+    cannot fall and a few-step run could not tell a working optimizer
+    from a broken one."""
+    p = 1.0 / np.arange(1, vocab + 1)
+    p /= p.sum()
     pool = []
     for _ in range(n_batches):
         tokens = jnp.asarray(
-            rng.integers(0, vocab, (global_batch, seq)), jnp.int32)
+            rng.choice(vocab, size=(global_batch, seq), p=p), jnp.int32)
         pool.append((tokens, jnp.roll(tokens, -1, axis=1)))
     return pool
 
@@ -209,7 +215,8 @@ def main(argv=None):
                     help="stall watchdog deadline in seconds (dumps "
                          "all-thread stacks on heartbeat silence; "
                          "heartbeats mirror to "
-                         "$APEX_TPU_HEARTBEAT_FILE for tpu_watch)")
+                         "$APEX_TPU_HEARTBEAT_FILE for "
+                         "resilience.watchdog.read_heartbeat)")
     args = ap.parse_args(argv)
 
     hier = args.dp_ici_size is not None
@@ -283,6 +290,13 @@ def main(argv=None):
     model = GPTModel(cfg)
     pp_path = args.pp > 1
     specs = model.pipeline_param_specs() if pp_path else model.param_specs()
+    if args.fused_opt_tail:
+        # the packed buffers concatenate leaves across bucket
+        # boundaries, so nothing is sharded over a model axis (checked
+        # above).  The specs say so: a size-1 "tp" entry would still
+        # type every packed bucket tp-varying
+        specs = jax.tree.map(lambda _: P(), specs,
+                             is_leaf=lambda x: isinstance(x, P))
     params = model.init(jax.random.PRNGKey(0))
     use_scaler = mp.policy.loss_scale is not None
     amp_state = mp.init()
@@ -315,16 +329,16 @@ def main(argv=None):
         if args.zero3:
             opt.build_layout(params, mesh=mesh)
             shard_spec = opt.shard_spec(model_axes=("pp", "tp"))
-            init_shards = jax.jit(shard_map(
+            init_shards = jax.jit(jax.shard_map(
                 opt.init_shards, mesh=mesh, in_specs=(specs,),
                 out_specs=shard_spec))
             opt_specs = opt.state_specs(model_axes=("pp", "tp"))
-            init_opt = jax.jit(shard_map(
+            init_opt = jax.jit(jax.shard_map(
                 opt.init, mesh=mesh, in_specs=(shard_spec,),
                 out_specs=opt_specs))
         else:
             opt_specs = opt.state_specs(model_axes=("pp", "tp"))
-            init_opt = jax.jit(shard_map(
+            init_opt = jax.jit(jax.shard_map(
                 opt.init, mesh=mesh, in_specs=(specs,),
                 out_specs=opt_specs))
     else:
@@ -381,8 +395,7 @@ def main(argv=None):
         # pp/tp is re-established for the pipeline/TP collectives.
         if args.zero3:
             weights, opt_state = opt.gather_params(params, opt_state)
-            if args.pp > 1 or args.tp > 1:
-                weights = reestablish_replicated(weights, specs)
+            weights = reestablish_replicated(weights, specs)
         else:
             weights = params
         # tlm.* phase scopes: xprof segments the compiled step's
@@ -511,7 +524,7 @@ def main(argv=None):
     # replicated tree never exists between steps
     store_spec = shard_spec if args.zero3 else specs
     step = jax.jit(
-        shard_map(
+        jax.shard_map(
             train_step, mesh=mesh,
             in_specs=(store_spec, opt_specs, amp_specs, comm_specs,
                       data_spec, data_spec),
@@ -563,6 +576,9 @@ def main(argv=None):
                      else place(opt_state, opt_specs))
 
     comm_state = place(comm_state, comm_specs)
+    # on the mesh like everything the step returns, or the second call
+    # sees differently-typed scaler state and compiles the step again
+    amp_state = place(amp_state, amp_specs)
     global_batch = args.micro_batch * args.num_micro * dp
     pool = (file_batches(args.data, 8, global_batch, args.seq, args.vocab)
             if args.data else
@@ -632,7 +648,15 @@ def main(argv=None):
             if "mfu" in summary:
                 line += f"  mfu {summary['mfu']:.3f}"
             print(line)
-        return {"loss": float(loss), "params": placed}
+        # beside the result: what a caller needs to go on from here
+        # without rebuilding it (chip_smoke.py serves these params and
+        # reads the compiled step's text) — the model, the jitted step
+        # and arguments it can be lowered with (the live state; the
+        # step donates its first two)
+        return {"loss": float(loss), "params": placed,
+                "summary": summary, "model": model, "step": step,
+                "step_args": (placed, opt_state, amp_state, comm_state)
+                + pool[0]}
     finally:
         if wd is not None:
             wd.stop()

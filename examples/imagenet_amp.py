@@ -34,7 +34,6 @@ import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from apex_tpu import checkpoint
-from apex_tpu._compat import shard_map
 from apex_tpu.models.resnet import ResNet, ResNetConfig
 from apex_tpu.optimizers import FusedSGD
 from apex_tpu.telemetry.metrics import MetricsLogger, StepStats
@@ -120,14 +119,14 @@ def build_steps(model, opt, num_classes, mesh, param_tree, opt_tree,
                 jax.lax.psum(jnp.stack([c1, c5, n]), "dp"))
 
     train = jax.jit(
-        shard_map(
+        jax.shard_map(
             train_step, mesh=mesh,
             in_specs=(pspec, ospec, sspec, P("dp"), P("dp")),
             out_specs=(pspec, ospec, sspec, P(), P()),
         ),
         donate_argnums=(0, 1, 2),
     )
-    evaluate = jax.jit(shard_map(
+    evaluate = jax.jit(jax.shard_map(
         eval_step, mesh=mesh,
         in_specs=(pspec, sspec, P("dp"), P("dp")),
         out_specs=(P(), P()),

@@ -14,7 +14,6 @@ import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from apex_tpu import amp
-from apex_tpu._compat import shard_map
 from apex_tpu.mlp import MLP
 from apex_tpu.optimizers import FusedAdam
 from apex_tpu.transformer import parallel_state
@@ -51,7 +50,7 @@ def main():
     ospec = jax.tree.map(lambda _: P(), opt_state)
     aspec = jax.tree.map(lambda _: P(), amp_state)
     step = jax.jit(
-        shard_map(
+        jax.shard_map(
             train_step, mesh=mesh,
             in_specs=(pspec, ospec, aspec, P("dp"), P("dp")),
             out_specs=(pspec, ospec, aspec, P()),

@@ -22,7 +22,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from apex_tpu._compat import shard_map
 from apex_tpu.models import T5Config, T5Model
 from apex_tpu.optimizers import FusedAdam
 from apex_tpu.telemetry.metrics import MetricsLogger, StepStats
@@ -142,7 +141,7 @@ def main(argv=None):
         opt.build_layout(params, mesh=mesh)
         shard_spec = opt.shard_spec(model_axes=("pp", "tp"))
         opt_specs = opt.state_specs(model_axes=("pp", "tp"))
-        init_shards = jax.jit(shard_map(
+        init_shards = jax.jit(jax.shard_map(
             opt.init_shards, mesh=mesh, in_specs=(specs,),
             out_specs=shard_spec))
     else:
@@ -224,7 +223,7 @@ def main(argv=None):
 
     data_spec = P(data_axes if hier else "dp")
     store_spec = shard_spec if args.zero3 else specs
-    step = jax.jit(shard_map(
+    step = jax.jit(jax.shard_map(
         train_step, mesh=mesh,
         in_specs=(store_spec, opt_specs, comm_specs,
                   data_spec, data_spec, data_spec),
@@ -242,7 +241,7 @@ def main(argv=None):
 
     if args.zero3:
         p = init_shards(place(params, specs))
-        s = jax.jit(shard_map(
+        s = jax.jit(jax.shard_map(
             opt.init, mesh=mesh, in_specs=(shard_spec,),
             out_specs=opt_specs))(p)
         jax.block_until_ready(p)

@@ -7,8 +7,6 @@
 #                               ~7 min on a 1-core host (283 tests, r5)
 #   tests/run_tests.sh full     the whole suite, chunked so no single
 #                               pytest invocation exceeds a CI timeout
-#   tests/run_tests.sh strict   l0 with APEX_TPU_STRICT_KERNELS=1 — any
-#                               silent Pallas->XLA kernel fallback FAILS
 #
 # Exit code is nonzero on any failure, so this is CI-ready as-is.
 set -euo pipefail
@@ -19,9 +17,6 @@ tier="${1:-l0}"
 case "$tier" in
   l0)
     exec python -m pytest tests/ -m l0 -q --durations=10
-    ;;
-  strict)
-    APEX_TPU_STRICT_KERNELS=1 exec python -m pytest tests/ -m l0 -q
     ;;
   full)
     # chunked: the full suite needs ~20 min serial on a 1-core host, so
@@ -43,7 +38,7 @@ case "$tier" in
         --ignore=tests/test_moe.py --ignore=tests/test_ring_attention.py
     ;;
   *)
-    echo "usage: tests/run_tests.sh [l0|full|strict]" >&2
+    echo "usage: tests/run_tests.sh [l0|full]" >&2
     exit 2
     ;;
 esac

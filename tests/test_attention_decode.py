@@ -370,8 +370,8 @@ class TestChunkedPrefill:
             assert bh >= 1 and h % bh == 0
         # past the budget the PALLAS path refuses (even block_h=1
         # cannot honor the scratch bound) — surfaced through
-        # run_kernel's strict contract for explicit pallas requests;
-        # the XLA path (and auto-mode fallback) still serves
+        # run_kernel's runs-or-raises contract; the XLA path still
+        # serves when asked for
         from apex_tpu.ops.common import KernelLoweringError
 
         sq = FMHA_DECODE_MAX_ROWS + 1

@@ -301,7 +301,7 @@ class TestLadderDispatch:
         monkeypatch.setattr(short_mod, "_fmha_short_pallas", fake("short"))
         monkeypatch.setattr(mid_mod, "_fmha_mid_pallas", fake("mid"))
         monkeypatch.setattr(plat, "_current_platform", lambda: "tpu")
-        for var in ("APEX_TPU_DISABLE_PALLAS", "APEX_TPU_STRICT_KERNELS",
+        for var in ("APEX_TPU_DISABLE_PALLAS",
                     "APEX_TPU_FMHA_SHORT_MAX_SEQ",
                     "APEX_TPU_FMHA_MID_MAX_SEQ"):
             monkeypatch.delenv(var, raising=False)
@@ -404,13 +404,12 @@ class TestRingInnerImpl:
         parallel_state.destroy_model_parallel()
 
     def _run(self, mesh, fn, *args):
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
 
         spec = P(None, None, "cp")
-        return jax.jit(shard_map(
+        return jax.jit(jax.shard_map(
             fn, mesh=mesh, in_specs=(spec,) * len(args),
-            out_specs=spec, check_rep=False,
+            out_specs=spec,
         ))(*args)
 
     @pytest.mark.parametrize("impl", ["mid", "xla"])
@@ -436,14 +435,13 @@ class TestRingInnerImpl:
                 q, k, v, causal=True, attention_impl="mid",
                 remat=remat) ** 2)
 
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
 
         spec = P(None, None, "cp")
-        rg = jax.jit(shard_map(
+        rg = jax.jit(jax.shard_map(
             jax.grad(ring_loss, argnums=(0, 1, 2)), mesh=mesh,
             in_specs=(spec,) * 3, out_specs=(spec,) * 3,
-            check_rep=False))(q, k, v)
+        ))(q, k, v)
         dg = jax.grad(
             lambda q, k, v: jnp.sum(
                 mha_reference(q, k, v, causal=True) ** 2),

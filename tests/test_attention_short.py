@@ -283,7 +283,6 @@ class TestShortDispatch:
         monkeypatch.setattr(mid_mod, "_fmha_mid_pallas", fake("mid"))
         monkeypatch.setattr(plat, "_current_platform", lambda: "tpu")
         monkeypatch.delenv("APEX_TPU_DISABLE_PALLAS", raising=False)
-        monkeypatch.delenv("APEX_TPU_STRICT_KERNELS", raising=False)
         monkeypatch.delenv("APEX_TPU_FMHA_SHORT_MAX_SEQ", raising=False)
         monkeypatch.delenv("APEX_TPU_FMHA_MID_MAX_SEQ", raising=False)
         return calls

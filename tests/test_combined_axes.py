@@ -1,7 +1,7 @@
 """Combined-axes proof on the 8-device virtual CPU mesh: ONE jitted
 train step over dp x pp x cp x tp simultaneously with a Switch-MoE layer
 in the stack (ep over "dp"), parity vs a single device — the same case
-``dryrun_multichip`` runs (VERDICT r3 item 7)."""
+``dryrun_multichip`` runs."""
 
 import pytest
 

@@ -351,6 +351,25 @@ class TestFleetFailover:
         with pytest.raises(RuntimeError, match="no replica is alive"):
             router.drain()
 
+    def test_dead_fleet_names_the_pump_error(self, fleet_setup):
+        """When the fleet died of its replicas' own faults, the error
+        carries the last pump exception as its cause — a compile
+        failure must not read as "dead fleet"."""
+        mesh, model, params, ccfg, fns, maxp = fleet_setup
+        router = FleetRouter(_replicas(ccfg, fns, maxp))
+
+        def boom(work):
+            raise ValueError("lowering failed")
+
+        for r in router.replicas:
+            r.batcher.pump = boom
+        router.submit(_req("a", [1, 2, 3]))
+        with pytest.raises(RuntimeError,
+                           match="no replica is alive") as exc:
+            router.drain()
+        assert isinstance(exc.value.__cause__, ValueError)
+        assert "lowering failed" in str(exc.value.__cause__)
+
 
 class TestCrossReplicaSamplingDeterminism:
     def test_same_seed_same_stream_across_batchers_and_order(

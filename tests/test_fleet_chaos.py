@@ -31,7 +31,7 @@ The load-bearing claims, each pinned here:
   survivors' streams are untouched, pages are released, the slot is
   reusable (the regression test speculation's cancel path rides on);
 - every pump heartbeat carries the replica's name so
-  ``tools/tpu_watch.py`` can name a stalled replica.
+  ``resilience.watchdog.read_heartbeat`` can name a stalled replica.
 """
 
 import json
@@ -375,9 +375,7 @@ class TestHealthMonitoring:
 
     def test_heartbeat_names_the_replica(self, chaos_setup, tmp_path,
                                          monkeypatch):
-        from apex_tpu.resilience.watchdog import Watchdog
-
-        import tools.tpu_watch as tpu_watch
+        from apex_tpu.resilience.watchdog import Watchdog, read_heartbeat
 
         mesh, model, params, ccfg, fns, maxp = chaos_setup
         hb = str(tmp_path / "heartbeat.json")
@@ -390,9 +388,12 @@ class TestHealthMonitoring:
         rec = json.load(open(hb))
         assert rec["replica"] == "r0"
         assert "serving_step" in rec and "live_slots" in rec
+        # the outside observer's view: the reader finds the file
+        # through the environment and names the replica
         monkeypatch.setenv("APEX_TPU_HEARTBEAT_FILE", hb)
-        note = tpu_watch.heartbeat_note()
-        assert "replica r0" in note and "live slots" in note
+        seen = read_heartbeat()
+        assert seen["replica"] == "r0" and "live_slots" in seen
+        assert seen["age_s"] >= 0.0
         router.drain()
 
 

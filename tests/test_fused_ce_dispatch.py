@@ -11,7 +11,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.experimental.shard_map import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from apex_tpu.models import GPTConfig, GPTModel
@@ -85,7 +84,7 @@ class TestGPTDispatch:
             params, jax.tree.map(lambda s: NamedSharding(mesh, s), specs,
                                  is_leaf=lambda x: isinstance(x, P)))
         tokens = jax.random.randint(jax.random.PRNGKey(1), (8, 16), 0, 64)
-        fn = jax.jit(shard_map(
+        fn = jax.jit(jax.shard_map(
             model.loss, mesh=mesh,
             in_specs=(specs, P("dp"), P("dp")), out_specs=P(),
         ))

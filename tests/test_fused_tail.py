@@ -267,7 +267,6 @@ class TestGPTTrainingParity:
         from apex_tpu.transformer.tensor_parallel.layers import (
             state_specs_like,
         )
-        from apex_tpu._compat import shard_map
 
         if parallel_state.model_parallel_is_initialized():
             parallel_state.destroy_model_parallel()
@@ -283,7 +282,11 @@ class TestGPTTrainingParity:
             )
             model = GPTModel(cfg)
             params = model.init(jax.random.PRNGKey(0))
-            specs = model.param_specs()
+            # replicated params, as the fused tail requires (its
+            # packed buckets concatenate leaves and cannot shard over a
+            # model axis): model.param_specs() would type every bucket
+            # tp-varying through its size-1 "tp" entries
+            specs = jax.tree.map(lambda _: P(), params)
             opt = FusedAdam(lr=1e-2, master_weights=True,
                             fused_tail=fused,
                             exp_avg_sq_dtype=exp_avg_sq_dtype)
@@ -310,7 +313,7 @@ class TestGPTTrainingParity:
                         jax.lax.pmean(loss, "dp"))
 
             sspec = jax.tree.map(lambda _: P(), sstate)
-            step = jax.jit(shard_map(
+            step = jax.jit(jax.shard_map(
                 step_fn, mesh=mesh,
                 in_specs=(specs, opt_specs, sspec, P("dp"), P("dp")),
                 out_specs=(specs, opt_specs, sspec, P()),

@@ -383,7 +383,7 @@ def test_gpt_interleaved_1f1b_matches_gpipe_pipeline():
 def test_measured_optimal_defaults_pinned():
     """The bench flagship inherits GPTConfig's defaults, so an
     accidental default change silently regresses the headline capture.
-    Pin the measured-optimal set (PROFILE_r03 exp 1, PROFILE_r05):
+    Pin the measured-optimal set (PROFILE_r05.json, pre-PR-1 chip run):
     any deliberate re-tune must update this test WITH fresh chip
     evidence."""
     cfg = GPTConfig()

@@ -153,8 +153,8 @@ class TestModules:
 
 
 class TestKernelFallbackPolicy:
-    """A Pallas lowering failure must be loud where it matters
-    (VERDICT r2: no silent kernel regressions)."""
+    """A Pallas lowering failure must be loud: no silent kernel
+    regressions."""
 
     def _broken(self, monkeypatch):
         from apex_tpu.ops import layer_norm as ln
@@ -172,10 +172,11 @@ class TestKernelFallbackPolicy:
         with pytest.raises(KernelLoweringError):
             fused_layer_norm(x, 64, implementation="pallas")
 
-    def test_strict_env_raises_in_auto_mode(self, monkeypatch):
+    def test_auto_mode_raises_kernel_lowering_error(self, monkeypatch):
         # flash attention is the kernel whose auto mode resolves to
         # pallas on TPU (layernorm/softmax auto-route to XLA by
-        # measurement, so strict mode does not apply to them)
+        # measurement).  A kernel the dispatcher selected runs or
+        # raises by name — it never reroutes to XLA.
         from apex_tpu.ops import attention as attn_mod
         from apex_tpu.ops.common import KernelLoweringError
         from apex_tpu.utils import platform as plat
@@ -186,34 +187,9 @@ class TestKernelFallbackPolicy:
         monkeypatch.setattr(attn_mod, "_flash_attention_pallas", boom)
         monkeypatch.setattr(plat, "_current_platform", lambda: "tpu")
         monkeypatch.delenv("APEX_TPU_DISABLE_PALLAS", raising=False)
-        monkeypatch.setenv("APEX_TPU_STRICT_KERNELS", "1")
-        # bf16: fp32 short-seq auto-routes to XLA by measurement and
-        # would never reach the pallas machinery under test
-        q = jnp.ones((1, 1, 8, 8), jnp.bfloat16)
-        with pytest.raises(KernelLoweringError):
+        # bf16 past the short/mid windows: the flash kernel is the one
+        # the dispatcher selects (fp32 short-seq auto-routes to XLA by
+        # measurement and would never reach the machinery under test)
+        q = jnp.ones((1, 1, 4096, 8), jnp.bfloat16)
+        with pytest.raises(KernelLoweringError, match="flash_attention"):
             attn_mod.flash_attention(q, q, q, implementation=None)
-
-    def test_auto_mode_falls_back_with_warning(self, monkeypatch, caplog):
-        import logging
-
-        from apex_tpu.ops import attention as attn_mod
-        from apex_tpu.utils import platform as plat
-
-        def boom(*a, **k):
-            raise RuntimeError("mosaic lowering exploded")
-
-        monkeypatch.setattr(attn_mod, "_flash_attention_pallas", boom)
-        monkeypatch.setattr(plat, "_current_platform", lambda: "tpu")
-        monkeypatch.delenv("APEX_TPU_DISABLE_PALLAS", raising=False)
-        monkeypatch.delenv("APEX_TPU_STRICT_KERNELS", raising=False)
-        q = jax.random.normal(
-            jax.random.PRNGKey(0), (1, 1, 8, 8), jnp.bfloat16
-        )
-        with caplog.at_level(logging.WARNING, logger="apex_tpu"):
-            out = attn_mod.flash_attention(q, q, q, implementation=None)
-        assert any("falling back to XLA" in r.message for r in caplog.records)
-        want = attn_mod.mha_reference(q, q, q)
-        np.testing.assert_allclose(
-            np.asarray(out, np.float32), np.asarray(want, np.float32),
-            atol=1e-2,
-        )

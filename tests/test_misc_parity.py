@@ -65,6 +65,28 @@ def test_multiproc_launcher_wires_env(tmp_path):
     assert lines == ["0 2", "1 2"]
 
 
+def test_multiproc_refuses_to_share_local_chips(monkeypatch):
+    """On a host with local TPU chips one process drives them all: the
+    launcher refuses --nprocs > 1 unless the caller divided the chips
+    or pinned the children off the TPU."""
+    import pytest
+
+    from apex_tpu.parallel import multiproc
+
+    monkeypatch.setattr(multiproc, "_local_tpu_chips",
+                        lambda: ["/dev/accel0", "/dev/accel1"])
+    for var in multiproc._CHIP_VISIBILITY_VARS + ("JAX_PLATFORMS",):
+        monkeypatch.delenv(var, raising=False)
+    with pytest.raises(SystemExit, match="one process drives all"):
+        multiproc._refuse_shared_chips(2)
+    multiproc._refuse_shared_chips(1)
+    monkeypatch.setenv("TPU_VISIBLE_CHIPS", "0")
+    multiproc._refuse_shared_chips(2)
+    monkeypatch.delenv("TPU_VISIBLE_CHIPS")
+    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
+    multiproc._refuse_shared_chips(2)
+
+
 def test_platform_detection_tracks_backend(monkeypatch):
     """A mid-process backend switch must not leave is_tpu() stale
     (the situation __graft_entry__._force_cpu_platform creates)."""

@@ -409,11 +409,6 @@ class TestReducer:
         dividing by K — Reducer docstring)."""
         from apex_tpu.parallel import Reducer
 
-        try:
-            shard_map = jax.shard_map
-        except AttributeError:  # jax 0.4.x spelling
-            from jax.experimental.shard_map import shard_map
-
         ours = Reducer(axis_name="dp")
         ref = Reducer(axis_name="dp", average_over_microbatches=False)
 
@@ -427,7 +422,7 @@ class TestReducer:
                 outs.append(g)
             return tuple(outs)
 
-        g_ours, g_ref = jax.jit(shard_map(
+        g_ours, g_ref = jax.jit(jax.shard_map(
             step, mesh=mesh, in_specs=(P("dp"),), out_specs=(P(), P()),
         ))(jnp.arange(8.0).reshape(8, 1))
         # mean over world of the per-device value 0..7 is 3.5

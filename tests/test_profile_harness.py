@@ -55,17 +55,14 @@ def _build_small(mesh):
             place(params, specs), place(opt_state, opt_specs))
 
 
-# the optimizer-stepping variants are exercised end-to-end by the real
-# capture and need newer jax's vma-aware out_specs replication checking
-# (0.4.x cannot statically infer the opt-state replication); the bug
-# class this file guards is the loss-only variants' out_specs P()
-@pytest.mark.parametrize("variant", ["no_opt", "fwd_only"])
+# the bug class this file guards is out_specs P() on a tp>1 mesh: the
+# no_opt row is the one that failed during the r05 capture
+@pytest.mark.parametrize("variant",
+                         ["no_opt", "fwd_only", "opt_only", "full"])
 def test_variants_compile_and_run_on_tp2(tp2_mesh, variant):
-    """The loss-returning decomposition variants must compile on a tp>1
-    mesh — the no_opt row is the one that failed during the r05
-    capture."""
+    """Every decomposition variant must compile on a tp>1 mesh."""
     model, opt, specs, opt_specs, params, opt_state = _build_small(tp2_mesh)
-    kw = {"no_opt": variant == "no_opt", "fwd_only": variant == "fwd_only"}
+    kw = {k: variant == k for k in ("no_opt", "fwd_only", "opt_only")}
     step = profile_r05.make_step(model, opt, tp2_mesh, specs, opt_specs,
                                  **kw)
     tokens = jax.random.randint(jax.random.PRNGKey(1), (4, 16), 0, 64)

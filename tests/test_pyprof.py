@@ -170,10 +170,7 @@ def test_trace_region_nesting(tmp_path):
         return y.sum()
 
     lowered = jax.jit(f).lower(jnp.ones((16, 16)))
-    try:  # newer jax spells it debug_info=; 0.4.x has compiled HLO only
-        text = lowered.as_text(debug_info=True)
-    except TypeError:
-        text = lowered.compile().as_text()
+    text = lowered.as_text(debug_info=True)
     assert "outer" in text and "inner" in text
     # named scopes nest: the inner op's metadata carries BOTH scopes
     assert "outer/inner" in text
@@ -195,7 +192,6 @@ def test_cost_analysis_sharded_mesh_function():
     """cost_analysis on a shard_map'd (mesh) function — the sharded
     path the telemetry StepStats MFU model sits on top of; the seed
     suite only exercised single-device cost analysis."""
-    from apex_tpu._compat import shard_map
     from apex_tpu.pyprof import cost_analysis, summarize
     from apex_tpu.transformer import parallel_state
     from jax.sharding import PartitionSpec as P
@@ -211,7 +207,7 @@ def test_cost_analysis_sharded_mesh_function():
             y = jnp.tanh(x @ w)
             return jax.lax.pmean(jnp.sum(y * y) / y.size, "dp")
 
-        fn = shard_map(local_step, mesh=mesh,
+        fn = jax.shard_map(local_step, mesh=mesh,
                        in_specs=(P(), P("dp")), out_specs=P())
         w = jnp.ones((N, N))
         x = jnp.ones((8 * dp, N))

@@ -34,21 +34,13 @@ from apex_tpu.parallel.distributed import (
     init_comm_state,
 )
 
-try:  # jax >= 0.6 spelling
-    _shard_map = jax.shard_map
-    _SM_KW = {"check_vma": False}
-except AttributeError:  # jax 0.4.x
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    _SM_KW = {"check_rep": False}
-
-
 def smap(f, mesh, in_specs, out_specs):
-    """Replication checking is off on BOTH spellings: every test here
-    reduces explicitly (the DDP.value_and_grad convention), so the
-    autodiff-inserted psum the checker enables is never relied on."""
-    return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, **_SM_KW)
+    """The vma checker is off: every test here reduces explicitly (the
+    DDP.value_and_grad convention), so the autodiff-inserted psum the
+    checker enables is never relied on, and the per-device comm
+    residuals are written with data-axis-only specs."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 DCN, ICI = 2, 4

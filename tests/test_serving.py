@@ -514,6 +514,46 @@ def _serve(gpt_setup, n_req, max_seqs, harvest_every, eos_id=None,
     return batcher, fns, batcher.run(reqs)
 
 
+def test_decode_fns_builds_from_the_same_specs_at_every_tp(gpt_setup):
+    """One layout for every tp: the single-chip (1,1,1,1) mesh and a
+    tp=2 mesh build their steps from the SAME partition specs — pools
+    head-sharded on "tp", logits gathered — so there is no tp=1 branch
+    to rot, and a quantized pool shards the same way too."""
+    from jax.sharding import PartitionSpec as P
+
+    from apex_tpu.transformer import parallel_state
+
+    mesh1, model, params = gpt_setup[:3]
+    assert dict(mesh1.shape) == {"dp": 1, "pp": 1, "cp": 1, "tp": 1}
+    ccfg = KVCacheConfig(
+        num_layers=2, num_heads=4, head_dim=8, num_pages=9, page_size=4,
+        max_seqs=2, pages_per_seq=4, dtype=jnp.float32)
+
+    def specs_on(mesh, **kw):
+        fns = model.decode_fns(params, mesh, ccfg, max_prompt_len=10,
+                               **kw)
+        return fns.param_specs, fns.pool_specs
+
+    one = specs_on(mesh1)
+    one_q = specs_on(mesh1, weight_dtype="int8", weight_block=8)
+    parallel_state.destroy_model_parallel()
+    try:
+        mesh2 = parallel_state.initialize_model_parallel(
+            tensor_model_parallel_size_=2, devices=jax.devices()[:2])
+        two = specs_on(mesh2)
+        two_q = specs_on(mesh2, weight_dtype="int8", weight_block=8)
+    finally:
+        # hand the module-scoped fixture its single-device mesh back
+        parallel_state.destroy_model_parallel()
+        parallel_state.initialize_model_parallel(
+            devices=jax.devices()[:1])
+    assert one == two and one_q == two_q
+    pool_spec = P(None, None, "tp", None, None)
+    assert all(s == pool_spec for s in one[1].values())
+    assert one_q[0]["layers"]["qkv"]["q8"] == P(None, None, "tp")
+    assert one_q[0]["layers"]["fc2"]["q8"] == P(None, "tp", None)
+
+
 class TestContinuousBatching:
     def test_three_generations_ragged_finishes_no_recompile(
             self, gpt_setup):

@@ -487,7 +487,6 @@ class TestFp32DispatchWindow:
             lambda q, *a, **kw: fake_pallas(q, None, None))
         monkeypatch.setattr(plat, "_current_platform", lambda: "tpu")
         monkeypatch.delenv("APEX_TPU_DISABLE_PALLAS", raising=False)
-        monkeypatch.delenv("APEX_TPU_STRICT_KERNELS", raising=False)
         monkeypatch.delenv("APEX_TPU_FMHA_MID_MAX_SEQ", raising=False)
         return attn_mod, calls
 

@@ -408,8 +408,6 @@ class TestSpeculativeServing:
         committed semantics."""
         from jax.sharding import PartitionSpec as P
 
-        from apex_tpu._compat import shard_map
-
         mesh, model, params, prompts, maxp = spec_setup
         # a LIVE cache state (retired tables alias the null-page sink,
         # which the two paths fill with different scratch): admit two
@@ -449,11 +447,13 @@ class TestSpeculativeServing:
             return l1, l2[:, 0]
 
         specs = model.param_specs()
-        pool_specs = jax.tree.map(lambda _: P(), pools)
-        run = jax.jit(shard_map(
+        # the serving layout: head-sharded pools, vocab-parallel logits
+        pool_specs = jax.tree.map(
+            lambda _: P(None, None, "tp", None, None), pools)
+        run = jax.jit(jax.shard_map(
             both, mesh=mesh,
             in_specs=(specs, pool_specs, P(), P(), P()),
-            out_specs=(P(), P())))
+            out_specs=(P(None, "tp"), P(None, "tp"))))
         toks = jnp.asarray(firsts, jnp.int32)
         lens = jnp.asarray([len(prompts[0]), len(prompts[1])],
                            jnp.int32)

@@ -31,7 +31,6 @@ import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
 
-from apex_tpu._compat import shard_map
 from apex_tpu.ops.dequant_matmul import (
     dequant_matmul,
     dequant_matmul_reference,
@@ -202,7 +201,7 @@ class TestUnshardQuantizeSeam:
                                        bucket_bytes=4096)
             opt.build_layout(params, mesh=mesh)
             pspec = jax.tree.map(lambda _: P(), params)
-            shards = jax.jit(shard_map(
+            shards = jax.jit(jax.shard_map(
                 opt.init_shards, mesh=mesh, in_specs=(pspec,),
                 out_specs=opt.shard_spec()))(params)
             ckpt = np.asarray(jax.device_get(shards))

@@ -18,10 +18,10 @@ import numpy as np
 import pytest
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from apex_tpu._compat import shard_map
 from apex_tpu.contrib.optimizers import (
     DistributedFusedAdam,
     DistributedFusedLAMB,
+    reestablish_replicated,
 )
 from apex_tpu.ops.quantization import (
     CompressionConfig,
@@ -72,10 +72,10 @@ def zero3_roundtrip(mesh, opt, params, grads, steps=3,
     opt.build_layout(params, mesh=mesh)
     pspec = jax.tree.map(lambda _: P(), params)
     sspec, stspecs = opt.shard_spec(), opt.state_specs()
-    init_sh = jax.jit(shard_map(
+    init_sh = jax.jit(jax.shard_map(
         opt.init_shards, mesh=mesh, in_specs=(pspec,), out_specs=sspec))
     shards = init_sh(params)
-    state = jax.jit(shard_map(
+    state = jax.jit(jax.shard_map(
         opt.init, mesh=mesh, in_specs=(sspec,), out_specs=stspecs
     ))(shards)
 
@@ -84,7 +84,7 @@ def zero3_roundtrip(mesh, opt, params, grads, steps=3,
         del p  # the gathered weights feed fwd/bwd in a real step
         return opt.step(st, g, sh, grads_finite=fin)
 
-    step = jax.jit(shard_map(
+    step = jax.jit(jax.shard_map(
         train, mesh=mesh,
         in_specs=(sspec, stspecs, pspec, P()),
         out_specs=(sspec, stspecs),
@@ -92,7 +92,7 @@ def zero3_roundtrip(mesh, opt, params, grads, steps=3,
     for i in range(steps):
         fin = jnp.array(True if finite_seq is None else finite_seq[i])
         shards, state = step(shards, state, grads, fin)
-    gather = jax.jit(shard_map(
+    gather = jax.jit(jax.shard_map(
         lambda s, t: opt.gather_params(s, t)[0], mesh=mesh,
         in_specs=(sspec, stspecs), out_specs=pspec))
     return gather(shards, state), shards, state
@@ -102,10 +102,10 @@ def zero1_reference(mesh, make_opt, params, grads, steps=3):
     opt = make_opt()
     specs = opt.state_specs()
     pspec = jax.tree.map(lambda _: P(), params)
-    init = jax.jit(shard_map(
+    init = jax.jit(jax.shard_map(
         opt.init, mesh=mesh, in_specs=(pspec,), out_specs=specs))
     state = init(params)
-    step = jax.jit(shard_map(
+    step = jax.jit(jax.shard_map(
         lambda st, g, p: opt.step(st, g, p), mesh=mesh,
         in_specs=(specs, pspec, pspec), out_specs=(pspec, specs)))
     p = params
@@ -147,7 +147,7 @@ class TestLayout:
                                       bf16_leaf=True)
         lay = Zero3Layout(params, world=8, bucket_bytes=64)
         pspec = jax.tree.map(lambda _: P(), params)
-        shard = jax.jit(shard_map(
+        shard = jax.jit(jax.shard_map(
             lambda p: lay.shard_params(p, jax.lax.axis_index("dp")),
             mesh=mesh, in_specs=(pspec,), out_specs=P("dp")))(params)
         rebuilt = lay.unshard(np.asarray(jax.device_get(shard)))
@@ -186,10 +186,10 @@ class TestZero3Adam:
         opt.build_layout(params, mesh=mesh)
         pspec = jax.tree.map(lambda _: P(), params)
         sspec = opt.shard_spec()
-        shards = jax.jit(shard_map(
+        shards = jax.jit(jax.shard_map(
             opt.init_shards, mesh=mesh, in_specs=(pspec,),
             out_specs=sspec))(params)
-        gathered = jax.jit(shard_map(
+        gathered = jax.jit(jax.shard_map(
             lambda s: opt.gather_params(s)[0], mesh=mesh,
             in_specs=(sspec,), out_specs=pspec))(shards)
         for k in params:
@@ -322,10 +322,10 @@ class TestZero3Compression:
         opt.build_layout(params, mesh=hier_mesh)
         pspec = jax.tree.map(lambda _: P(), params)
         sspec = opt.shard_spec()
-        shards = jax.jit(shard_map(
+        shards = jax.jit(jax.shard_map(
             opt.init_shards, mesh=hier_mesh, in_specs=(pspec,),
             out_specs=sspec))(params)
-        gathered = jax.jit(shard_map(
+        gathered = jax.jit(jax.shard_map(
             lambda s: opt.gather_params(s)[0], mesh=hier_mesh,
             in_specs=(sspec,), out_specs=pspec))(shards)
         for k in params:
@@ -372,11 +372,11 @@ class TestZero3Compression:
         place = lambda t, sp: jax.device_put(
             t, jax.tree.map(lambda s: NamedSharding(hier_mesh, s), sp,
                             is_leaf=lambda x: isinstance(x, P)))
-        init_sh = jax.jit(shard_map(
+        init_sh = jax.jit(jax.shard_map(
             opt.init_shards, mesh=hier_mesh, in_specs=(pspec,),
             out_specs=sspec))
         shards = init_sh(params)
-        state = jax.jit(shard_map(
+        state = jax.jit(jax.shard_map(
             opt.init, mesh=hier_mesh, in_specs=(sspec,),
             out_specs=stspecs))(shards)
 
@@ -385,7 +385,7 @@ class TestZero3Compression:
             del p
             return opt.step(st, g, sh)
 
-        step = jax.jit(shard_map(
+        step = jax.jit(jax.shard_map(
             train, mesh=hier_mesh,
             in_specs=(sspec, stspecs, pspec), out_specs=(sspec, stspecs)))
         for _ in range(2):
@@ -445,7 +445,7 @@ class TestZero3Validation:
                                    bucket_bytes=64)
         opt.build_layout(params, mesh=mesh)
         with pytest.raises(ValueError, match="flat"):
-            jax.jit(shard_map(
+            jax.jit(jax.shard_map(
                 opt.init, mesh=mesh,
                 in_specs=(jax.tree.map(lambda _: P(), params),),
                 out_specs=opt.state_specs()))(params)
@@ -461,7 +461,7 @@ class TestZero3Telemetry:
         opt.build_layout(params, mesh=mesh)
         pspec = jax.tree.map(lambda _: P(), params)
         sspec = opt.shard_spec()
-        shards = jax.jit(shard_map(
+        shards = jax.jit(jax.shard_map(
             opt.init_shards, mesh=mesh, in_specs=(pspec,),
             out_specs=sspec))(params)
 
@@ -474,7 +474,7 @@ class TestZero3Telemetry:
         sink = Sink()
         tlm_events.add_sink(sink)
         try:
-            fn = jax.jit(shard_map(
+            fn = jax.jit(jax.shard_map(
                 lambda s: opt.gather_params(s)[0], mesh=mesh,
                 in_specs=(sspec,), out_specs=pspec))
             txt = fn.lower(shards).compile().as_text()
@@ -510,10 +510,10 @@ class TestZero3Telemetry:
             try:
                 pspec = jax.tree.map(lambda _: P(), params)
                 sspec = opt.shard_spec()
-                shards = jax.jit(shard_map(
+                shards = jax.jit(jax.shard_map(
                     opt.init_shards, mesh=hier_mesh, in_specs=(pspec,),
                     out_specs=sspec))(params)
-                jax.jit(shard_map(
+                jax.jit(jax.shard_map(
                     lambda s: opt.gather_params(s)[0], mesh=hier_mesh,
                     in_specs=(sspec,), out_specs=pspec))(shards)
             finally:
@@ -600,7 +600,7 @@ class TestZero3GPTTraining:
                     p, s = opt.step(s, grads, p)
                     return p, s, loss
 
-                step = jax.jit(shard_map(
+                step = jax.jit(jax.shard_map(
                     train, mesh=mesh,
                     in_specs=(pspec, stspecs, P("dp"), P("dp")),
                     out_specs=(pspec, stspecs, P())))
@@ -612,24 +612,31 @@ class TestZero3GPTTraining:
             opt = DistributedFusedAdam(
                 lr=1e-2, shard_params=(mode == "zero3"),
                 bucket_bytes=16 * 1024, compression=compression)
+            # the GPT specs name "tp" (and the mesh carries pp): the
+            # flat buffers then live per (pp, tp) position, which the
+            # ZeRO specs must say — the trainer's composition rule
+            # (examples/gpt_pretrain.py)
+            maxes = ("pp", "tp")
             if mode == "zero3":
                 opt.build_layout(params, mesh=mesh)
-                sspec, stspecs = opt.shard_spec(), opt.state_specs()
-                shards = jax.jit(shard_map(
+                sspec = opt.shard_spec(model_axes=maxes)
+                stspecs = opt.state_specs(model_axes=maxes)
+                shards = jax.jit(jax.shard_map(
                     opt.init_shards, mesh=mesh, in_specs=(pspec,),
                     out_specs=sspec))(params)
-                st = jax.jit(shard_map(
+                st = jax.jit(jax.shard_map(
                     opt.init, mesh=mesh, in_specs=(sspec,),
                     out_specs=stspecs))(shards)
 
                 def train(sh, s, tok, tgt):
                     p, s = opt.gather_params(sh, s)
+                    p = reestablish_replicated(p, specs)
                     loss, grads = jax.value_and_grad(model.loss)(
                         p, tok, tgt)
                     sh, s = opt.step(s, grads, sh)
                     return sh, s, loss
 
-                step = jax.jit(shard_map(
+                step = jax.jit(jax.shard_map(
                     train, mesh=mesh,
                     in_specs=(sspec, stspecs, P("dp"), P("dp")),
                     out_specs=(sspec, stspecs, P())))
@@ -637,14 +644,15 @@ class TestZero3GPTTraining:
                     shards, st, loss = step(shards, st, tokens,
                                             targets)
                     losses.append(float(loss))
-                gather = jax.jit(shard_map(
-                    lambda s, t: opt.gather_params(s, t)[0],
+                gather = jax.jit(jax.shard_map(
+                    lambda s, t: reestablish_replicated(
+                        opt.gather_params(s, t)[0], specs),
                     mesh=mesh, in_specs=(sspec, stspecs),
                     out_specs=pspec))
                 return losses, gather(shards, st)
             # zero1
-            stspecs = opt.state_specs()
-            st = jax.jit(shard_map(
+            stspecs = opt.state_specs(model_axes=maxes)
+            st = jax.jit(jax.shard_map(
                 opt.init, mesh=mesh, in_specs=(pspec,),
                 out_specs=stspecs))(params)
 
@@ -652,9 +660,9 @@ class TestZero3GPTTraining:
                 loss, grads = jax.value_and_grad(model.loss)(
                     p, tok, tgt)
                 p, s = opt.step(s, grads, p)
-                return p, s, loss
+                return reestablish_replicated(p, specs), s, loss
 
-            step = jax.jit(shard_map(
+            step = jax.jit(jax.shard_map(
                 train, mesh=mesh,
                 in_specs=(pspec, stspecs, P("dp"), P("dp")),
                 out_specs=(pspec, stspecs, P())))

@@ -273,20 +273,6 @@ def audit_fn(jitted, args, mesh, dcn_axis="dcn", ici_axis="ici"):
     return totals, legs, records
 
 
-def _shard_map():
-    import jax
-
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map
-    from jax.experimental.shard_map import shard_map
-
-    def compat(f, mesh, in_specs, out_specs):
-        return shard_map(f, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_rep=False)
-
-    return compat
-
-
 def audit_gradient_sync(compression, ici_size=4, block_size=256,
                         shapes=GPT_ISH_SHAPES, dtype=None):
     """Compile the hierarchical gradient-sync step over a GPT-shaped
@@ -313,7 +299,6 @@ def audit_gradient_sync(compression, ici_size=4, block_size=256,
         is_leaf=lambda x: isinstance(x, tuple),
     )
     pspec = jax.tree.map(lambda _: P(), grads)
-    shard_map = _shard_map()
 
     if isinstance(compression, CompressionConfig):
         cfg = compression
@@ -327,16 +312,17 @@ def audit_gradient_sync(compression, ici_size=4, block_size=256,
     if cfg is not None and cfg.error_feedback:
         cstate = init_comm_state(grads, axes, cfg, mesh=mesh)
         cspecs = comm_state_specs(cstate, axes)
-        fn = shard_map(
+        fn = jax.shard_map(
             lambda g, st: all_reduce_gradients(
                 g, axes, compression=cfg, comm_state=st),
-            mesh, (pspec, cspecs), (pspec, cspecs),
+            mesh=mesh, in_specs=(pspec, cspecs),
+            out_specs=(pspec, cspecs),
         )
         args = (grads, cstate)
     else:
-        fn = shard_map(
+        fn = jax.shard_map(
             lambda g: all_reduce_gradients(g, axes, compression=cfg),
-            mesh, (pspec,), pspec,
+            mesh=mesh, in_specs=(pspec,), out_specs=pspec,
         )
         args = (grads,)
 
@@ -440,7 +426,6 @@ def audit_zero3_step(compression, ici_size=4, block_size=256,
 
     mesh = hierarchical_data_parallel_mesh(ici_size=ici_size)
     axes = ("dcn", "ici")
-    shard_map = _shard_map()
     params = jax.tree.map(
         lambda s: jax.ShapeDtypeStruct(s, jnp.float32), shapes,
         is_leaf=lambda x: isinstance(x, tuple),
@@ -463,8 +448,9 @@ def audit_zero3_step(compression, ici_size=4, block_size=256,
         g = jax.tree.map(lambda gi, pi: gi + 0.0 * pi, g, p)
         return opt.step(st, g, sh)
 
-    fn = jax.jit(shard_map(
-        step, mesh, (sspec, stspec, pspec), (sspec, stspec),
+    fn = jax.jit(jax.shard_map(
+        step, mesh=mesh, in_specs=(sspec, stspec, pspec),
+        out_specs=(sspec, stspec),
     ))
     sh = jax.ShapeDtypeStruct(
         (ici_size * layout.shard_size,), jnp.float32)
@@ -823,7 +809,6 @@ def compile_grad_sync_loop(overlap, compression=None, ici_size=4,
     from apex_tpu.parallel.distributed import Reducer
 
     mesh = hierarchical_data_parallel_mesh(ici_size=ici_size)
-    shard_map = _shard_map()
     params = _overlap_params()
     red = Reducer(
         axis_name=("dcn", "ici"), overlap_grad_sync=overlap,
@@ -842,8 +827,9 @@ def compile_grad_sync_loop(overlap, compression=None, ici_size=4,
     data = jnp.zeros(
         (num_micro, rows * mesh.devices.size, _OVERLAP_WIDTH)
     )
-    fn = jax.jit(shard_map(
-        step, mesh, (pspec, P(None, ("dcn", "ici"))), pspec,
+    fn = jax.jit(jax.shard_map(
+        step, mesh=mesh, in_specs=(pspec, P(None, ("dcn", "ici"))),
+        out_specs=pspec,
     ))
     txt = fn.lower(params, data).compile().as_text()
     return txt, mesh

@@ -158,7 +158,6 @@ def build_step(mode, mesh, model, batch=8, bucket_mb=4.0):
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
 
-    from apex_tpu._compat import shard_map
 
     tpl = _param_template(model)
     specs = model.param_specs()
@@ -183,7 +182,7 @@ def build_step(mode, mesh, model, batch=8, bucket_mb=4.0):
             return p, s, loss
 
         in_specs = (specs, st_specs, P("dp"), P("dp"))
-        jitted = jax.jit(shard_map(
+        jitted = jax.jit(jax.shard_map(
             train, mesh=mesh,
             in_specs=in_specs,
             out_specs=(specs, st_specs, P()),
@@ -214,7 +213,7 @@ def build_step(mode, mesh, model, batch=8, bucket_mb=4.0):
         return sh, s, loss
 
     in_specs = (sspec, st_specs, P("dp"), P("dp"))
-    jitted = jax.jit(shard_map(
+    jitted = jax.jit(jax.shard_map(
         train, mesh=mesh,
         in_specs=in_specs,
         out_specs=(sspec, st_specs, P()),
@@ -318,7 +317,6 @@ def train_zero3(vocab=None, layers=None, hidden=None, heads=None,
     import numpy as np
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    from apex_tpu._compat import shard_map
     from apex_tpu.contrib.optimizers import DistributedFusedAdam
 
     cfg = dict(FLAGSHIP_1B)
@@ -342,12 +340,12 @@ def train_zero3(vocab=None, layers=None, hidden=None, heads=None,
         t, jax.tree.map(lambda s: NamedSharding(mesh, s), sp,
                         is_leaf=lambda x: isinstance(x, P)))
     params = place(params, specs)
-    shards = jax.jit(shard_map(
+    shards = jax.jit(jax.shard_map(
         opt.init_shards, mesh=mesh, in_specs=(specs,),
         out_specs=sspec))(params)
     jax.block_until_ready(shards)
     del params  # the replicated tree is gone: shards are the storage
-    state = jax.jit(shard_map(
+    state = jax.jit(jax.shard_map(
         opt.init, mesh=mesh, in_specs=(sspec,),
         out_specs=st_specs))(shards)
     init_s = time.perf_counter() - t0
@@ -358,7 +356,7 @@ def train_zero3(vocab=None, layers=None, hidden=None, heads=None,
         sh, s = opt.step(s, grads, sh)
         return sh, s, loss
 
-    step = jax.jit(shard_map(
+    step = jax.jit(jax.shard_map(
         train, mesh=mesh,
         in_specs=(sspec, st_specs, P("dp"), P("dp")),
         out_specs=(sspec, st_specs, P()),
@@ -461,7 +459,7 @@ def _serve_weight_pool_bytes(model, width, block=128) -> int:
     return _tree_bytes(_serve_pool_tree(model, width, block))
 
 
-def _serve_pool_specs(model, width, pool, tp):
+def _serve_pool_specs(model, width, pool):
     """Partition specs matching ``pool``'s pytree — the same specs
     :meth:`GPTModel.decode_fns` shards the served pool with (column
     leaves split the stacked output dim, row leaves the contraction
@@ -472,7 +470,7 @@ def _serve_pool_specs(model, width, pool, tp):
     specs = model.param_specs()
     if width in ("int8", "int4"):
         specs["layers"] = _quantized_layer_specs(
-            specs["layers"], pool["layers"], "tp", tp)
+            specs["layers"], pool["layers"], "tp")
     return specs
 
 
@@ -645,7 +643,7 @@ def run_serve_audit(hbm_gb=DEFAULT_HBM_GB, max_seqs=4, context=1024,
                     tp_rows[str(t)] = {"fits_hbm": None,
                                        "note": str(e)}
                     continue
-                specs = _serve_pool_specs(model, w, pool, t)
+                specs = _serve_pool_specs(model, w, pool)
                 wps = _serve_per_shard_bytes(pool, specs, t)
                 kvs = kv["int8"] // t         # head-sharded pool
                 totals = wps + kvs + act

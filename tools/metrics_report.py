@@ -35,7 +35,7 @@ Usage::
 
     python tools/metrics_report.py run_metrics.jsonl
     python tools/metrics_report.py run_metrics.jsonl --json out.json
-    python tools/metrics_report.py run_metrics.jsonl --bench BENCH_r05.json
+    python tools/metrics_report.py run_metrics.jsonl --bench bench_line.json
 """
 
 from __future__ import annotations

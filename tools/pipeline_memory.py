@@ -1,5 +1,5 @@
 """Pipeline activation-memory profile: compiled temp memory vs microbatch
-count (VERDICT r2 item 4's committed artifact).
+count (writes PIPELINE_MEMORY.json).
 
 The compiled GPipe-with-remat schedule keeps per-tick stage inputs for the
 backward; the table below measures how compiled temp memory actually
