@@ -40,6 +40,7 @@ from apex_tpu.ops.layer_norm import (
     fused_layer_norm_affine,
     fused_rms_norm_affine,
 )
+from apex_tpu.telemetry.spans import phase
 from apex_tpu.transformer.parallel_state import (
     DATA_PARALLEL_AXIS,
     TENSOR_PARALLEL_AXIS,
@@ -1623,6 +1624,7 @@ class GPTModel:
             gather_from_tensor_model_parallel_region,
             axis_name=self.axis_name)
 
+        @phase("prefill")
         def _prefill(params, pools, toks, length, page_row, key):
             hidden, ks, vs = self.prefill_forward(params, toks)
             pos = jnp.arange(toks.shape[1], dtype=jnp.int32)
@@ -1647,6 +1649,7 @@ class GPTModel:
                          temperature, top_k, top_p)[0]
             return pools, tok
 
+        @phase("prefill")
         def _chunk(params, pools, toks, start, plen, write_from,
                    page_row, key):
             logits, pools = self.prefill_chunk(
@@ -1658,6 +1661,7 @@ class GPTModel:
                          temperature, top_k, top_p)[0]
             return pools, tok, logits
 
+        @phase("decode")
         def _decode(params, pools, carry, page_table):
             active = jnp.logical_not(carry["done"])
             logits, pools = self.decode_step(
@@ -1693,6 +1697,7 @@ class GPTModel:
                 "sample_keys": carry["sample_keys"],
             }
 
+        @phase("decode")
         def _spec(params, pools, carry, page_table, drafts, draft_len):
             # verify-and-commit: k+1 rows through ONE weight stream,
             # then the fused acceptance rule, then a multi-token carry
@@ -1757,6 +1762,7 @@ class GPTModel:
             }
             return pools, new_carry, targets, n_c
 
+        @phase("decode")
         def _spec_tree(params, pools, carry, page_table, drafts,
                        draft_len):
             # tree verify-and-commit: R candidate rows (a static

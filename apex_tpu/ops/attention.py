@@ -47,6 +47,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from apex_tpu.ops.common import shape_struct
+from apex_tpu.telemetry.spans import kernel_name
 from apex_tpu.utils.platform import default_implementation, is_tpu
 
 from jax.experimental import pallas as pl
@@ -411,6 +412,7 @@ def _fa_fwd_pallas(q, k, v, bias, qseg, kseg, seed, cfg: _FAConfig):
         ],
         compiler_params=_compiler_params(),
         interpret=_interpret(),
+        name=kernel_name("fmha_flash.fwd"),
     )(*inputs)
     return out, lse[:, 0]
 
@@ -687,6 +689,7 @@ def _fa_bwd_pallas(q, k, v, bias, qseg, kseg, seed, out, lse, do,
         ],
         compiler_params=_compiler_params(),
         interpret=_interpret(),
+        name=kernel_name("fmha_flash.bwd_dkv"),
     )(*common, do, lse3, delta3)
 
     emit_dbias = has_bias and cfg.bias_grad
@@ -725,6 +728,7 @@ def _fa_bwd_pallas(q, k, v, bias, qseg, kseg, seed, out, lse, do,
         compiler_params=_compiler_params(),
         scratch_shapes=[pltpu.VMEM((cfg.block_q, d), jnp.float32)],
         interpret=_interpret(),
+        name=kernel_name("fmha_flash.bwd_dq"),
     )(*common, do, lse3, delta3)
     if emit_dbias:
         dq, dbias = res

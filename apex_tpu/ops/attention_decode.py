@@ -69,6 +69,7 @@ import jax.numpy as jnp
 
 from apex_tpu.ops.attention import _NEG_INF, _interpret
 from apex_tpu.ops.common import shape_struct
+from apex_tpu.telemetry.spans import kernel_name
 
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
@@ -362,6 +363,7 @@ def _decode_pallas(q, q_rot, cos, sin, k_pages, v_pages, k_scales,
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=_interpret(),
+        name=kernel_name("paged_decode"),
     )(page_table.astype(jnp.int32), lengths.astype(jnp.int32), *inputs)
 
 

@@ -82,6 +82,7 @@ from apex_tpu.ops.attention import (
     mha_reference,
 )
 from apex_tpu.ops.common import shape_struct
+from apex_tpu.telemetry.spans import kernel_name
 from apex_tpu.utils.platform import default_implementation
 
 from jax.experimental import pallas as pl
@@ -553,6 +554,7 @@ def _mid_fwd_pallas(q, k, v, bias, qseg, kseg, seed, cfg: _MidConfig):
         ],
         compiler_params=_compiler_params(),
         interpret=_interpret(),
+        name=kernel_name("fmha_mid.fwd"),
     )(*inputs)
     return out, lse
 
@@ -644,6 +646,7 @@ def _mid_bwd_pallas(q, k, v, bias, qseg, kseg, seed, out, lse, do, dlse,
         ],
         compiler_params=_bwd_compiler_params(),
         interpret=_interpret(),
+        name=kernel_name("fmha_mid.bwd"),
     )(*inputs)
     if emit_dbias:
         dq, dk, dv, dbias = res

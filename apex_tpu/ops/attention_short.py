@@ -62,6 +62,7 @@ from apex_tpu.ops.attention import (
     mha_reference,
 )
 from apex_tpu.ops.common import shape_struct
+from apex_tpu.telemetry.spans import kernel_name
 from apex_tpu.utils.platform import default_implementation
 
 from jax.experimental import pallas as pl
@@ -369,6 +370,7 @@ def _short_fwd_pallas(q, k, v, bias, qseg, kseg, seed, cfg: _ShortConfig):
         ],
         compiler_params=_compiler_params(),
         interpret=_interpret(),
+        name=kernel_name("fmha_short.fwd"),
     )(*inputs)
     return out, lse
 
@@ -446,6 +448,7 @@ def _short_bwd_pallas(q, k, v, bias, qseg, kseg, seed, out, lse, do,
         out_shape=out_shape,
         compiler_params=_compiler_params(),
         interpret=_interpret(),
+        name=kernel_name("fmha_short.bwd"),
     )(*inputs)
     if emit_dbias:
         dq, dk, dv, dbias = res

@@ -60,6 +60,7 @@ from apex_tpu.ops.quantization import (
     quantize_rows_int4,
     unpack_int4,
 )
+from apex_tpu.telemetry.spans import kernel_name
 from apex_tpu.utils.platform import default_implementation
 
 __all__ = [
@@ -138,6 +139,7 @@ def _int8_pallas(x, qw, scales, block_size):
             dimension_semantics=("parallel",)
         ),
         interpret=_interpret(),
+        name=kernel_name("dequant_matmul.int8"),
     )(x, qw, scales)
     return out.astype(x.dtype)
 
@@ -163,6 +165,7 @@ def _int4_pallas(x, qp, scales, block_size):
             dimension_semantics=("parallel",)
         ),
         interpret=_interpret(),
+        name=kernel_name("dequant_matmul.int4"),
     )(x, qp, s3)
     # the halves layout: slab 0 = output columns [0, n/2), slab 1 =
     # [n/2, n) — one concat restores the original order

@@ -26,6 +26,7 @@ import jax
 import jax.numpy as jnp
 
 from apex_tpu.ops.common import run_kernel, shape_struct
+from apex_tpu.telemetry.spans import kernel_name
 
 from apex_tpu.utils.platform import is_tpu
 
@@ -108,6 +109,7 @@ def _ln_fwd_pallas(x2d: jnp.ndarray, eps: float, rms: bool):
         ],
         # interpreter mode off-TPU so the kernel body stays testable
         interpret=not is_tpu(),
+        name=kernel_name("layer_norm.fwd"),
     )(x2d)
     mean = mean.reshape(padded_rows)
     invvar = invvar.reshape(padded_rows)

@@ -29,6 +29,7 @@ import jax
 import jax.numpy as jnp
 
 from apex_tpu.ops.common import run_kernel, shape_struct
+from apex_tpu.telemetry.spans import kernel_name
 
 __all__ = [
     "scaled_softmax",
@@ -101,6 +102,7 @@ def _softmax_fwd_pallas(x3d: jnp.ndarray, scale: float, causal: bool):
         ),
         out_shape=shape_struct((m, padded_sq, sk), x3d.dtype, x3d),
         interpret=_interpret(),
+        name=kernel_name("softmax.fwd"),
     )(x3d)
     if pad:
         out = out[:, :sq]

@@ -74,11 +74,22 @@ reporting at per-step sync cost, the same knob ``MetricsLogger``'s
 chunk-granular (TTFT grows with interleaved decode steps but decoding
 slots never stall for a whole prompt).
 
-Telemetry: ``tlm.prefill`` / ``tlm.decode`` phase scopes wrap the
-dispatches, and ``span`` (``prefill`` / ``prefill_chunk`` / ``decode``)
-/ ``request_admitted`` / ``prefix_hit`` / ``request_done`` events land
-in the metrics stream — ``tools/metrics_report.py``'s serving section
-reads them.  ``measure_stall=True`` additionally blocks on each
+Telemetry: every scheduler turn writes host spans into the profiler's
+trace (:func:`apex_tpu.telemetry.spans.host_span`, on the device
+trace's clock, recorded only while a profiler session runs):
+``tlm.serve.pump`` holds ``admit`` (with a ``dispatch_prefill`` per
+admission on the monolithic path), a ``dispatch_prefill`` per chunk and
+a ``dispatch_decode`` per step (``draft`` before a verify step),
+``harvest`` around each ``device_get``, ``commit`` with a
+``first_token`` per request, and ``retire`` — names, stats and the
+metric each is read by are in docs/observability.md "Serving spans".
+The device side is segmented by the ``tlm.prefill`` / ``tlm.decode``
+scopes opened inside the traced step bodies
+(``GPTModel.decode_fns``).  Independently, ``span`` (``prefill`` /
+``prefill_chunk`` / ``decode``) / ``request_admitted`` / ``prefix_hit``
+/ ``request_done`` events land in the metrics stream —
+``tools/metrics_report.py``'s serving section reads them.
+``measure_stall=True`` additionally blocks on each
 prefill dispatch to measure real decode-stall time (``decode_stall_s``
 total / ``max_prefill_stall_s`` worst single stall while decode slots
 were live) — the number the ``_dryrun_chunked_prefill`` gate and the
@@ -106,7 +117,7 @@ from apex_tpu.serving.kv_cache import (
     prompt_page_hashes,
     staged_nbytes,
 )
-from apex_tpu.telemetry.spans import phase
+from apex_tpu.telemetry.spans import host_span
 
 __all__ = ["Request", "Completion", "HandoffPacket",
            "ContinuousBatcher", "init_carry"]
@@ -157,12 +168,18 @@ class Request:
     stops after ``max_new_tokens`` or at the server's ``eos_id``.
     ``seed`` (optional) pins the request's sampling stream: every draw
     folds the request's own key, so a seeded request reproduces its
-    sampled tokens regardless of admission order or slot assignment."""
+    sampled tokens regardless of admission order or slot assignment.
+    ``arrival_s`` (optional) is when the request reached the server, on
+    the ``time.perf_counter()`` clock, stamped by whoever received it:
+    with it the batcher reports the request's queue wait
+    (``Completion.queue_wait_s``, ``queue_wait_us`` on its
+    ``tlm.serve.dispatch_prefill`` spans); without it nothing changes."""
 
     uid: Any
     prompt: Sequence[int]
     max_new_tokens: int
     seed: Optional[int] = None
+    arrival_s: Optional[float] = None
 
     def __post_init__(self):
         if self.max_new_tokens < 1:
@@ -173,7 +190,12 @@ class Request:
 
 @dataclasses.dataclass
 class Completion:
-    """``tokens`` are the generated ids (EOS included when hit)."""
+    """``tokens`` are the generated ids (EOS included when hit).
+    ``ttft_s`` and ``duration_s`` start at ADMISSION (the slot was
+    assigned) and end at the harvest that showed the first / last
+    token.  ``queue_wait_s`` is ``Request.arrival_s`` → admission, None
+    when the request carried no arrival time; the TTFT a client sees,
+    anchored at arrival, is ``queue_wait_s + ttft_s``."""
 
     uid: Any
     tokens: List[int]
@@ -181,6 +203,7 @@ class Completion:
     reason: str                 # "eos" | "budget"
     ttft_s: Optional[float] = None
     duration_s: Optional[float] = None
+    queue_wait_s: Optional[float] = None
 
 
 @dataclasses.dataclass
@@ -477,6 +500,7 @@ class ContinuousBatcher:
         self.completions: Dict[Any, Completion] = {}
         self.steps = 0
         self.windows = 0
+        self.turns = 0          # scheduler turns (``pump`` calls)
         self.prefill_chunks = 0
         #: prefill wall time spent while >= 1 decoding slot was live
         #: (total, and the worst single stall) — meaningful when
@@ -561,6 +585,17 @@ class ContinuousBatcher:
             return jax.random.PRNGKey(int(req.seed))
         return jax.random.fold_in(self._base_key, self._n_admits)
 
+    def _prefill_span(self, req: Request, slot: int, chunk: int,
+                      t_admit: float):
+        """The ``dispatch_prefill`` span of one prefill / chunk call
+        (``chunk`` -1 on the monolithic path)."""
+        span = host_span("serve.dispatch_prefill", uid=req.uid, slot=slot,
+                         prompt_tokens=len(req.prompt), chunk=chunk)
+        if req.arrival_s is not None:
+            span.set_metadata(
+                queue_wait_us=int(1e6 * (t_admit - req.arrival_s)))
+        return span
+
     def _slot_live(self, slot: int, first, req: Request, plen: int,
                    t_admit: float, skey) -> None:
         """Prefill finished: flip the slot into the decoding set."""
@@ -585,9 +620,19 @@ class ContinuousBatcher:
 
     # ------------------------------------------------------------- admit
     def _admit(self, queue) -> None:
+        with host_span("serve.admit") as span:
+            admitted, backpressured = self._admit_free_slots(queue)
+            span.set_metadata(admitted=admitted,
+                              backpressured=backpressured)
+        self._emit_gauges(len(queue))
+
+    def _admit_free_slots(self, queue):
+        """Returns (requests admitted, 1 if the page allocator refused
+        the next one else 0)."""
         cfg = self.cache.config
         free = [s for s in range(cfg.max_seqs)
                 if s not in self._meta and s not in self._prefilling]
+        admitted = 0
         for slot in free:
             if not queue:
                 break
@@ -608,8 +653,9 @@ class ContinuousBatcher:
                     prompt_tokens=(req.prompt if self.prefix_cache
                                    else None))
             except CacheOutOfPages:
-                break                       # backpressure: wait for pages
+                return admitted, 1          # backpressure: wait for pages
             queue.popleft()
+            admitted += 1
             skey = self._slot_key(req)
             self._n_admits += 1
             t_admit = time.perf_counter()
@@ -625,7 +671,7 @@ class ContinuousBatcher:
             # prompt, the slot joins decode immediately
             toks = np.zeros((1, self.max_prompt_len), np.int32)
             toks[0, :plen] = np.asarray(req.prompt, np.int32)
-            with phase("prefill"):
+            with self._prefill_span(req, slot, -1, t_admit):
                 if self.measure_stall:
                     # drain the in-order device queue first, so the
                     # measured stall is THIS prefill's work, not the
@@ -643,7 +689,7 @@ class ContinuousBatcher:
             self._slot_live(slot, first, req, plen, t_admit, skey)
             self._event("span", span="prefill", slot=slot,
                         tokens=plen, dispatch_s=round(dispatch_s, 6))
-        self._emit_gauges(len(queue))
+        return admitted, 0
 
     def _admit_chunked(self, slot, req, res, skey, t_admit,
                        page_row) -> None:
@@ -697,7 +743,8 @@ class ContinuousBatcher:
         st = self._prefilling[slot]
         C = self.prefill_chunk
         c0 = st["next_chunk"] * C
-        with phase("prefill"):
+        with self._prefill_span(st["req"], slot, st["next_chunk"],
+                                st["t_admit"]):
             if self.measure_stall:
                 # drain the queue (see _admit): attribute only this
                 # chunk's work to the stall, not the decode step it
@@ -760,21 +807,27 @@ class ContinuousBatcher:
         (shared by the plain harvest and the speculative window)."""
         for slot, tok in firsts_h.items():
             m = self._meta[slot]
-            m["tokens"].append(int(tok))
-            m["t_first"] = t_h
-            if self.eos_id is not None and int(tok) == self.eos_id:
-                m["finished"] = "eos"
-            elif len(m["tokens"]) >= m["req"].max_new_tokens:
-                m["finished"] = "budget"
+            with host_span("serve.first_token", uid=m["req"].uid,
+                           slot=slot):
+                m["tokens"].append(int(tok))
+                m["t_first"] = t_h
+                if self.eos_id is not None and int(tok) == self.eos_id:
+                    m["finished"] = "eos"
+                elif len(m["tokens"]) >= m["req"].max_new_tokens:
+                    m["finished"] = "budget"
 
     def _retire(self, done_h, t_h: float) -> None:
         """Retire finished slots: device ``done`` and host finish
         detection agree by construction (same eos/budget rules); host
         is authoritative for truncation, device for freezing."""
-        for slot in list(self._meta):
+        finished = [s for s, m in self._meta.items()
+                    if m["finished"] is not None or bool(done_h[s])]
+        with host_span("serve.retire", retired=len(finished)):
+            self._retire_slots(finished, t_h)
+
+    def _retire_slots(self, finished, t_h: float) -> None:
+        for slot in finished:
             m = self._meta[slot]
-            if m["finished"] is None and not bool(done_h[slot]):
-                continue
             reason = m["finished"] or (
                 "eos" if (self.eos_id is not None and m["tokens"]
                           and m["tokens"][-1] == self.eos_id)
@@ -786,6 +839,8 @@ class ContinuousBatcher:
                 ttft_s=(None if m["t_first"] is None
                         else m["t_first"] - m["t_admit"]),
                 duration_s=t_h - m["t_admit"],
+                queue_wait_s=(None if req.arrival_s is None
+                              else m["t_admit"] - req.arrival_s),
             )
             self.completions[req.uid] = comp
             self.cache.retire(slot)
@@ -797,6 +852,118 @@ class ContinuousBatcher:
                         ttft_s=(None if comp.ttft_s is None
                                 else round(comp.ttft_s, 6)),
                         duration_s=round(comp.duration_s, 6))
+
+    def _draft(self, live):
+        """Host-side drafts for one verify step: (drafts (S, cols),
+        draft lengths (S,), slot -> source name, seconds spent in the
+        draft source)."""
+        k = self.speculate_k
+        S = self.cache.config.max_seqs
+        tree = self.spec_tree
+        # chain mode offers k draft columns; tree mode offers one per
+        # non-root node (rows 1..R-1 of the static parents tuple)
+        n_cols = k if tree is None else len(tree) - 1
+        chain_rows = self._tree_chain_rows
+        draft_s = 0.0
+        drafts = np.zeros((S, n_cols), np.int32)
+        dlens = np.zeros((S,), np.int32)
+        sources: Dict[int, str] = {}
+        for s, m in live:
+            # exact multi-token budget: cap the draft under the
+            # slot's remaining tokens (the +1 verify bonus row
+            # fills the rest), so the device can never be offered
+            # more rows than the budget admits
+            rem = m["req"].max_new_tokens - len(m["tokens"])
+            cap = min(k, rem - 1)
+            if cap <= 0:
+                continue
+            td = time.perf_counter()
+            toks, src = self.draft_source.draft(
+                list(m["req"].prompt) + m["tokens"],
+                len(m["req"].prompt))
+            draft_s += time.perf_counter() - td
+            if tree is not None and len(toks) == n_cols:
+                # tree-aware source: one token per non-root node,
+                # already laid out in row order; the device's
+                # depth-vs-draft_len mask trims anything past cap
+                drafts[s, :] = toks
+                dlens[s] = min(k, cap)
+                sources[s] = src
+                continue
+            toks = toks[:cap]
+            if toks:
+                if tree is None:
+                    drafts[s, :len(toks)] = toks
+                else:
+                    # chain-shaped source under a tree verify:
+                    # place the chain on the tree's first-child
+                    # spine, leave sibling rows padded (pad rows
+                    # only commit when they EQUAL the coupled
+                    # target draw, which is the identical token)
+                    for i, row in enumerate(chain_rows[:len(toks)]):
+                        drafts[s, row - 1] = toks[i]
+                dlens[s] = len(toks)
+                sources[s] = src
+        return drafts, dlens, sources, draft_s
+
+    def _commit_verified(self, live, out_h, nc_h, path_h, dlens,
+                         sources) -> int:
+        """Fold one verify step's resolved commits into the host
+        streams and the speculation scoreboard; returns the tokens
+        committed."""
+        drafted = accepted = committed = offramp = 0
+        commits: List[int] = []
+        ev_src: Dict[str, Dict[str, int]] = {}
+        chain_set = set(self._tree_chain_rows)
+        for s, m in live:
+            nc = int(nc_h[s])
+            for j in range(nc):
+                tok = int(out_h[s, j])
+                m["tokens"].append(tok)
+                # host length mirror follows the device's commit
+                self.cache.lengths[s] += 1
+                if self.eos_id is not None and tok == self.eos_id:
+                    m["finished"] = "eos"
+                elif len(m["tokens"]) >= m["req"].max_new_tokens:
+                    m["finished"] = "budget"
+            dl = int(dlens[s])
+            acc = max(min(nc - 1, dl), 0)
+            if path_h is not None:
+                # committed tree nodes off the first-child spine =
+                # tokens a chain verify would have rejected
+                offramp += sum(
+                    1 for t in range(1, acc + 1)
+                    if int(path_h[s, t]) not in chain_set)
+            drafted += dl
+            accepted += acc
+            committed += nc
+            commits.append(nc)
+            src = sources.get(s)
+            if src is not None:
+                rec = ev_src.setdefault(
+                    src, {"drafted": 0, "accepted": 0})
+                rec["drafted"] += dl
+                rec["accepted"] += acc
+        st = self.spec_stats
+        st["steps"] += 1
+        st["slot_steps"] += len(live)
+        st["drafted"] += drafted
+        st["accepted"] += accepted
+        st["committed"] += committed
+        st["offramp"] += offramp
+        for src, rec in ev_src.items():
+            tot = st["by_source"].setdefault(
+                src, {"drafted": 0, "accepted": 0})
+            tot["drafted"] += rec["drafted"]
+            tot["accepted"] += rec["accepted"]
+        # one spec_accept event per verify step, built entirely
+        # from the commit resolve this loop already performs — no
+        # host syncs beyond the per-step one the draft seam needs
+        self._event("spec_accept", slots=len(live),
+                    drafted=drafted, accepted=accepted,
+                    committed=committed, commits=commits,
+                    by_source=ev_src, offramp=offramp)
+        return committed
 
     def _spec_window(self) -> None:
         """One harvest window of speculative serving steps: draft on
@@ -813,13 +980,7 @@ class ContinuousBatcher:
         does not survive multi-token advances.  The draft length is
         additionally capped at remaining-budget − 1 so no live row is
         ever written past the slot's reserved pages."""
-        k = self.speculate_k
-        S = self.cache.config.max_seqs
         tree = self.spec_tree
-        # chain mode offers k draft columns; tree mode offers one per
-        # non-root node (rows 1..R-1 of the static parents tuple)
-        n_cols = k if tree is None else len(tree) - 1
-        chain_rows = self._tree_chain_rows
         page_table = jnp.asarray(self.cache.page_table)
         t0 = time.perf_counter()
         chunk_s = 0.0
@@ -840,8 +1001,11 @@ class ContinuousBatcher:
             if self._first_tok:
                 firsts = {s: self._first_tok.pop(s)
                           for s in list(self._first_tok)}
-                self._absorb_firsts(_device_get(firsts),
-                                    time.perf_counter())
+                with host_span("serve.harvest", steps=0,
+                               firsts=len(firsts)):
+                    firsts_h = _device_get(firsts)
+                with host_span("serve.commit", tokens=len(firsts_h)):
+                    self._absorb_firsts(firsts_h, time.perf_counter())
             # a prefill-role replica stops here: chunks ran, firsts
             # resolved, but no verify step — slots await handoff
             if not self.decode_enabled:
@@ -854,48 +1018,12 @@ class ContinuousBatcher:
                 if not did_chunk:
                     break
                 continue
-            drafts = np.zeros((S, n_cols), np.int32)
-            dlens = np.zeros((S,), np.int32)
-            sources: Dict[int, str] = {}
-            for s, m in live:
-                # exact multi-token budget: cap the draft under the
-                # slot's remaining tokens (the +1 verify bonus row
-                # fills the rest), so the device can never be offered
-                # more rows than the budget admits
-                rem = m["req"].max_new_tokens - len(m["tokens"])
-                cap = min(k, rem - 1)
-                if cap <= 0:
-                    continue
-                td = time.perf_counter()
-                toks, src = self.draft_source.draft(
-                    list(m["req"].prompt) + m["tokens"],
-                    len(m["req"].prompt))
-                draft_s += time.perf_counter() - td
-                if tree is not None and len(toks) == n_cols:
-                    # tree-aware source: one token per non-root node,
-                    # already laid out in row order; the device's
-                    # depth-vs-draft_len mask trims anything past cap
-                    drafts[s, :] = toks
-                    dlens[s] = min(k, cap)
-                    sources[s] = src
-                    continue
-                toks = toks[:cap]
-                if toks:
-                    if tree is None:
-                        drafts[s, :len(toks)] = toks
-                    else:
-                        # chain-shaped source under a tree verify:
-                        # place the chain on the tree's first-child
-                        # spine, leave sibling rows padded (pad rows
-                        # only commit when they EQUAL the coupled
-                        # target draw, which is the identical token)
-                        for i, row in enumerate(
-                                chain_rows[:len(toks)]):
-                            drafts[s, row - 1] = toks[i]
-                    dlens[s] = len(toks)
-                    sources[s] = src
+            with host_span("serve.draft", slots=len(live)):
+                drafts, dlens, sources, dt = self._draft(live)
+            draft_s += dt
             path_h = None
-            with phase("decode"):
+            with host_span("serve.dispatch_decode", step=self.steps,
+                           live_slots=len(live)):
                 if tree is None:
                     self.pools, self.carry, out, n_commit = \
                         self.spec_fn(self.pools, self.carry,
@@ -904,72 +1032,26 @@ class ContinuousBatcher:
                     (self.pools, self.carry, out, n_commit,
                      path) = self.spec_fn(self.pools, self.carry,
                                           page_table, drafts, dlens)
-            if tree is None:
-                out_h, nc_h, done_h = _device_get(
-                    (out, n_commit, self.carry["done"]))
-            else:
-                out_h, nc_h, path_h, done_h = _device_get(
-                    (out, n_commit, path, self.carry["done"]))
+            with host_span("serve.harvest", steps=1, firsts=0):
+                if tree is None:
+                    out_h, nc_h, done_h = _device_get(
+                        (out, n_commit, self.carry["done"]))
+                else:
+                    out_h, nc_h, path_h, done_h = _device_get(
+                        (out, n_commit, path, self.carry["done"]))
             self.steps += 1
             steps += 1
-            drafted = accepted = committed = offramp = 0
-            commits: List[int] = []
-            ev_src: Dict[str, Dict[str, int]] = {}
-            chain_set = set(chain_rows)
-            for s, m in live:
-                nc = int(nc_h[s])
-                for j in range(nc):
-                    tok = int(out_h[s, j])
-                    m["tokens"].append(tok)
-                    kept += 1
-                    # host length mirror follows the device's commit
-                    self.cache.lengths[s] += 1
-                    if self.eos_id is not None and tok == self.eos_id:
-                        m["finished"] = "eos"
-                    elif len(m["tokens"]) >= m["req"].max_new_tokens:
-                        m["finished"] = "budget"
-                dl = int(dlens[s])
-                acc = max(min(nc - 1, dl), 0)
-                if path_h is not None:
-                    # committed tree nodes off the first-child spine =
-                    # tokens a chain verify would have rejected
-                    offramp += sum(
-                        1 for t in range(1, acc + 1)
-                        if int(path_h[s, t]) not in chain_set)
-                drafted += dl
-                accepted += acc
-                committed += nc
-                commits.append(nc)
-                src = sources.get(s)
-                if src is not None:
-                    rec = ev_src.setdefault(
-                        src, {"drafted": 0, "accepted": 0})
-                    rec["drafted"] += dl
-                    rec["accepted"] += acc
-            st = self.spec_stats
-            st["steps"] += 1
-            st["slot_steps"] += len(live)
-            st["drafted"] += drafted
-            st["accepted"] += accepted
-            st["committed"] += committed
-            st["offramp"] += offramp
-            for src, rec in ev_src.items():
-                tot = st["by_source"].setdefault(
-                    src, {"drafted": 0, "accepted": 0})
-                tot["drafted"] += rec["drafted"]
-                tot["accepted"] += rec["accepted"]
-            # one spec_accept event per verify step, built entirely
-            # from the commit resolve this loop already performs — no
-            # host syncs beyond the per-step one the draft seam needs
-            self._event("spec_accept", slots=len(live),
-                        drafted=drafted, accepted=accepted,
-                        committed=committed, commits=commits,
-                        by_source=ev_src, offramp=offramp)
+            with host_span("serve.commit") as span:
+                committed = self._commit_verified(
+                    live, out_h, nc_h, path_h, dlens, sources)
+                span.set_metadata(tokens=committed)
+            kept += committed
         t_h = time.perf_counter()
         self.windows += 1
         self.spec_stats["draft_s"] += draft_s
         if done_h is None:
-            done_h = _device_get(self.carry["done"])
+            with host_span("serve.harvest", steps=0, firsts=0):
+                done_h = _device_get(self.carry["done"])
         self._event(
             "span", span="decode", steps=steps,
             slots=len(self._meta), tokens=kept,
@@ -1001,7 +1083,8 @@ class ContinuousBatcher:
             # prefill-role replica never dispatches one: its
             # prompt-complete slots wait for the handoff sweep)
             if self.decode_enabled and self._window_budget(base) > 0:
-                with phase("decode"):
+                with host_span("serve.dispatch_decode", step=self.steps,
+                               live_slots=len(self._meta)):
                     self.pools, self.carry = self.decode_fn(
                         self.pools, self.carry, page_table)
                 window.append(self.carry["tokens"])
@@ -1013,28 +1096,32 @@ class ContinuousBatcher:
         steps = len(window)
         firsts = {s: self._first_tok.pop(s) for s in list(self._first_tok)}
         stacked = jnp.stack(window) if window else None
-        harvested, firsts_h, done_h = _device_get(
-            (stacked, firsts, self.carry["done"]))
+        with host_span("serve.harvest", steps=steps, firsts=len(firsts)):
+            harvested, firsts_h, done_h = _device_get(
+                (stacked, firsts, self.carry["done"]))
         t_h = time.perf_counter()
         self.windows += 1
 
-        self._absorb_firsts(firsts_h, t_h)
-        kept = 0
-        for i in range(steps):
-            for slot, m in self._meta.items():
-                if m["finished"] is not None:
-                    continue
-                if base + i < m.get("since_step", base):
-                    continue        # slot joined mid-window, later step
-                tok = int(harvested[i, slot])
-                m["tokens"].append(tok)
-                kept += 1
-                # host length mirror follows the device's write position
-                self.cache.lengths[slot] += 1
-                if self.eos_id is not None and tok == self.eos_id:
-                    m["finished"] = "eos"
-                elif len(m["tokens"]) >= m["req"].max_new_tokens:
-                    m["finished"] = "budget"
+        with host_span("serve.commit") as span:
+            self._absorb_firsts(firsts_h, t_h)
+            kept = 0
+            for i in range(steps):
+                for slot, m in self._meta.items():
+                    if m["finished"] is not None:
+                        continue
+                    if base + i < m.get("since_step", base):
+                        continue    # slot joined mid-window, later step
+                    tok = int(harvested[i, slot])
+                    m["tokens"].append(tok)
+                    kept += 1
+                    # host length mirror follows the device's write
+                    # position
+                    self.cache.lengths[slot] += 1
+                    if self.eos_id is not None and tok == self.eos_id:
+                        m["finished"] = "eos"
+                    elif len(m["tokens"]) >= m["req"].max_new_tokens:
+                        m["finished"] = "budget"
+            span.set_metadata(tokens=kept + len(firsts_h))
         # tokens = KEPT tokens only: slots that finish (or freeze)
         # mid-window decode garbage for the rest of it, and counting
         # that would inflate the serving summary's tokens/s exactly in
@@ -1318,15 +1405,18 @@ class ContinuousBatcher:
         admissions).  ``queue`` is a ``collections.deque`` of
         :class:`Request`; admitted entries are popped, backpressured
         ones stay."""
-        self._admit(queue)
-        if not self._meta and not self._prefilling:
-            if queue:
-                raise CacheOutOfPages(
-                    "no slot can ever admit the next request "
-                    f"(prompt+budget needs more pages than the "
-                    f"pool holds: {queue[0].uid!r})")
-            return False
-        self._decode_window()
+        with host_span("serve.pump", turn=self.turns, queued=len(queue),
+                       live_slots=self.live_slots):
+            self.turns += 1
+            self._admit(queue)
+            if not self._meta and not self._prefilling:
+                if queue:
+                    raise CacheOutOfPages(
+                        "no slot can ever admit the next request "
+                        f"(prompt+budget needs more pages than the "
+                        f"pool holds: {queue[0].uid!r})")
+                return False
+            self._decode_window()
         return bool(self._meta or self._prefilling or queue)
 
     # --------------------------------------------------------------- run
@@ -1337,13 +1427,5 @@ class ContinuousBatcher:
         are reused."""
         queue = collections.deque(requests)
         while queue or self._meta or self._prefilling:
-            self._admit(queue)
-            if not self._meta and not self._prefilling:
-                if queue:
-                    raise CacheOutOfPages(
-                        "no slot can ever admit the next request "
-                        f"(prompt+budget needs more pages than the "
-                        f"pool holds: {queue[0].uid!r})")
-                break
-            self._decode_window()
+            self.pump(queue)
         return self.completions

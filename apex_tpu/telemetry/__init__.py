@@ -17,8 +17,10 @@ the offline half: trace capture + XLA cost analysis).  Three modules:
   :func:`~apex_tpu.telemetry.events.emit` here; free when no sink
   listens.
 - :mod:`~apex_tpu.telemetry.spans` — ``tlm.<phase>`` named-scope step
-  segmentation for xprof, and :class:`TraceTrigger` (touch-file / env
-  armed mid-run xplane capture of K steps).
+  segmentation for xprof, ``tlm.kernel.<name>`` kernel names,
+  ``tlm.<name>`` host spans on the profiler's clock, and
+  :class:`TraceTrigger` (touch-file / env armed mid-run xplane capture
+  of K steps).
 
 ``tools/metrics_report.py`` turns the JSONL stream into a run summary;
 the workflow is documented in docs/observability.md.
@@ -38,6 +40,8 @@ _LAZY_ATTRS = {
     "transformer_flops_per_token": "apex_tpu.telemetry.metrics",
     "device_peak_flops": "apex_tpu.telemetry.metrics",
     "phase": "apex_tpu.telemetry.spans",
+    "kernel_name": "apex_tpu.telemetry.spans",
+    "host_span": "apex_tpu.telemetry.spans",
     "PHASES": "apex_tpu.telemetry.spans",
     "TraceTrigger": "apex_tpu.telemetry.spans",
     "emit": "apex_tpu.telemetry.events",

@@ -1,6 +1,7 @@
-"""Phase-segmented traces: named-scope spans + an on-demand trigger.
+"""Phase-segmented traces: device scopes, host spans and an on-demand
+trigger.
 
-Two halves:
+Three parts, one channel (the jax profiler's trace):
 
 **Phase spans** — :func:`phase` wraps a region of a (traced) step
 function in ``jax.named_scope`` under a common ``tlm.<name>`` prefix,
@@ -15,6 +16,24 @@ save).  Being ``jax.named_scope``, the spans cost nothing at runtime —
 they exist only in compile-time metadata (the same mechanism
 :func:`apex_tpu.pyprof.annotate` uses; this module adds the shared
 naming convention and the mid-run capture below).
+
+**Kernel names** — :func:`kernel_name` is the same mechanism one level
+down: every Mosaic ``pallas_call`` takes ``tlm.kernel.<name>`` as its
+``name=``, which scopes the call and names its instruction, so a
+reduction finds the kernel by name after a refactor changes its
+operands.
+
+**Host spans** — :func:`host_span` is the host-side half:
+``jax.profiler.TraceAnnotation("tlm.<name>", **stats)``.  A compiled
+function's CALL carries no scope (a named scope names operations while
+a function is traced and does nothing at a call), so what the host does
+between and around the device's programs — admit, dispatch, harvest,
+commit (:mod:`apex_tpu.serving.serve`) — is marked with these.  They
+land in the same ``.xplane.pb`` as the device's operations, on one
+clock, whenever a profiler session runs (a :class:`TraceTrigger`
+capture, :func:`apex_tpu.pyprof.trace`, a benchmark's ``--trace 1``);
+the session IS the switch.  With none a span is a TraceMe check, about
+a microsecond.
 
 **On-demand trace trigger** — :class:`TraceTrigger` answers "the run
 is live and slow *now*; get me a trace without restarting".  The
@@ -39,16 +58,18 @@ import jax
 
 from apex_tpu.telemetry import events as _events
 
-__all__ = ["PHASES", "phase", "TraceTrigger"]
+__all__ = ["PHASES", "phase", "kernel_name", "host_span", "TraceTrigger"]
 
 logger = logging.getLogger("apex_tpu.telemetry")
 
 #: The step-anatomy phases the example trainers annotate.
 #: ``param_gather`` is the ZeRO-3 gather-on-use weight all-gather
 #: (apex_tpu/parallel/zero3.py) — present only under ``shard_params``.
-#: ``prefill``/``decode`` are the SERVING step anatomy
-#: (apex_tpu/serving/serve.py): prompt ingestion through the training
-#: attention ladder, and the fused per-token cache-attend-sample step.
+#: ``prefill``/``decode`` are the SERVING step anatomy, opened inside
+#: the traced bodies of ``GPTModel.decode_fns`` (``_prefill``/``_chunk``;
+#: ``_decode``/``_spec``/``_spec_tree``): prompt ingestion through the
+#: training attention ladder, and the fused per-token
+#: cache-attend-sample step.
 PHASES = ("data", "param_gather", "fwd_bwd", "grad_sync", "optimizer",
           "checkpoint", "prefill", "decode")
 
@@ -56,14 +77,43 @@ PHASES = ("data", "param_gather", "fwd_bwd", "grad_sync", "optimizer",
 #: shows exactly the phase segmentation.
 PHASE_PREFIX = "tlm."
 
+#: Kernel names sit under this prefix: ``tlm.kernel.fmha_mid.fwd``.
+KERNEL_PREFIX = PHASE_PREFIX + "kernel."
+
 
 @contextlib.contextmanager
 def phase(name: str) -> Iterator[None]:
     """Annotate a region as one step phase (``tlm.<name>`` named
-    scope).  Free at runtime; use inside OR outside jit — scopes nest
-    (``tlm.fwd_bwd/tlm.attention``) like any ``jax.named_scope``."""
+    scope).  It names the operations emitted while the region is TRACED
+    and does nothing around a call of an already-compiled function
+    (mark that with :func:`host_span`).  Scopes nest
+    (``tlm.fwd_bwd/tlm.attention``) like any ``jax.named_scope``; as a
+    decorator (``@phase("decode")``) it scopes a whole traced body and
+    keeps the function's name."""
     with jax.named_scope(PHASE_PREFIX + name):
         yield
+
+
+def kernel_name(name: str) -> str:
+    """``tlm.kernel.<name>``, for a Mosaic kernel call's ``name=``::
+
+        out = pl.pallas_call(kernel, ..., name=kernel_name("fmha_mid.fwd"))
+
+    ``pallas_call`` opens a named scope of that name around the call it
+    emits and names the compiled instruction after it, so the kernel is
+    found by name in the compiled text and the device trace — a
+    ``custom_vjp`` backward under its own, a rematerialised forward
+    under ``rematted_computation/tlm.kernel.<name>``."""
+    return KERNEL_PREFIX + name
+
+
+def host_span(name: str, **stats) -> jax.profiler.TraceAnnotation:
+    """A host span ``tlm.<name>`` in the profiler's trace, for a
+    ``with`` statement.  ``stats`` are values already at hand (ints, an
+    id as given) and come back as the event's stats; one known only at
+    the end is added inside the block with ``span.set_metadata(k=v)``.
+    Nothing is recorded, formatted or kept when no session runs."""
+    return jax.profiler.TraceAnnotation(PHASE_PREFIX + name, **stats)
 
 
 class TraceTrigger:
