@@ -371,11 +371,19 @@ class GPTConfig:
     # across O0..O5
     policy: Optional[Any] = None
     remat: bool = True
-    # measured on v5e (12L/h1024/b8/s1024 train step): no_batch_dims
-    # 103.1 ms vs dots_saveable 107.1 vs nothing_saveable 106.4 vs
-    # remat off 111.7 — batch-dim dot outputs are cheap to recompute and
-    # expensive to keep resident
-    remat_policy: Optional[str] = "dots_with_no_batch_dims_saveable"
+    # batch-dim dot outputs are cheap to recompute and expensive to
+    # keep resident, so the dots_with_no_batch_dims policy is the base.
+    # A Mosaic call is not a dot: under that policy alone the attention
+    # kernel's (out, lse) are thrown away and the backward runs the
+    # forward kernel a second time.  The default keeps the two under
+    # the names the kernels' forward rules give them (ops/common.py).
+    # Measured in the train-345m cell (24L/h1024, 16 x 1024 tokens a
+    # step on one v5e; PERF.md section 6, PR 27): 487.3 ms a step
+    # against 532.6 with the dots policy alone, for 64 MB a layer kept.
+    # A job at its memory limit still has "nothing_saveable"
+    # (tensor_parallel/random.py CHECKPOINT_POLICIES)
+    remat_policy: Optional[str] = (
+        "dots_with_no_batch_dims_and_attention_saveable")
     # LM-head/CE dispatch: None = auto by materialized-logits size
     # (tensor_parallel.cross_entropy.FUSED_CE_AUTO_BYTES) — small logits
     # take the two-step path (faster: 107.4 vs 110.1 ms/step at the v5e

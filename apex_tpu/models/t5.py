@@ -76,7 +76,8 @@ class T5Config:
     remat: bool = True
     # same chip-measured defaults as GPTConfig (fused_ce None = auto
     # by logits size, see GPTConfig)
-    remat_policy: Optional[str] = "dots_with_no_batch_dims_saveable"
+    remat_policy: Optional[str] = (
+        "dots_with_no_batch_dims_and_attention_saveable")
     fused_ce: Optional[bool] = None
     fused_ce_chunk: int = 8192
     # "short" | "mid" | "pallas" | "xla" | None = auto via the measured

@@ -46,7 +46,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from apex_tpu.ops.common import shape_struct
+from apex_tpu.ops.common import name_attention_residuals, shape_struct
 from apex_tpu.telemetry.spans import kernel_name
 from apex_tpu.utils.platform import default_implementation, is_tpu
 
@@ -749,7 +749,8 @@ def _flash(q, k, v, bias, qseg, kseg, seed, cfg):
 
 
 def _flash_fwd(q, k, v, bias, qseg, kseg, seed, cfg):
-    out, lse = _fa_fwd_pallas(q, k, v, bias, qseg, kseg, seed, cfg)
+    out, lse = name_attention_residuals(
+        *_fa_fwd_pallas(q, k, v, bias, qseg, kseg, seed, cfg))
     return out, (q, k, v, bias, qseg, kseg, seed, out, lse)
 
 

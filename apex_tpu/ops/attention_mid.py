@@ -81,7 +81,7 @@ from apex_tpu.ops.attention import (
     BIAS_PER_HEAD,
     mha_reference,
 )
-from apex_tpu.ops.common import shape_struct
+from apex_tpu.ops.common import name_attention_residuals, shape_struct
 from apex_tpu.telemetry.spans import kernel_name
 from apex_tpu.utils.platform import default_implementation
 
@@ -669,7 +669,8 @@ def _mid(q, k, v, bias, qseg, kseg, seed, cfg):
 
 
 def _mid_fwd(q, k, v, bias, qseg, kseg, seed, cfg):
-    out, lse = _mid_fwd_pallas(q, k, v, bias, qseg, kseg, seed, cfg)
+    out, lse = name_attention_residuals(
+        *_mid_fwd_pallas(q, k, v, bias, qseg, kseg, seed, cfg))
     res = (q, k, v, bias, qseg, kseg, seed, out, lse)
     if cfg.with_lse:
         return (out, lse[:, 0]), res

@@ -61,7 +61,7 @@ from apex_tpu.ops.attention import (
     _prec,
     mha_reference,
 )
-from apex_tpu.ops.common import shape_struct
+from apex_tpu.ops.common import name_attention_residuals, shape_struct
 from apex_tpu.telemetry.spans import kernel_name
 from apex_tpu.utils.platform import default_implementation
 
@@ -469,7 +469,8 @@ def _short(q, k, v, bias, qseg, kseg, seed, cfg):
 
 
 def _short_fwd(q, k, v, bias, qseg, kseg, seed, cfg):
-    out, lse = _short_fwd_pallas(q, k, v, bias, qseg, kseg, seed, cfg)
+    out, lse = name_attention_residuals(
+        *_short_fwd_pallas(q, k, v, bias, qseg, kseg, seed, cfg))
     return out, (q, k, v, bias, qseg, kseg, seed, out, lse)
 
 

@@ -3,8 +3,26 @@
 from __future__ import annotations
 
 import jax
+from jax.ad_checkpoint import checkpoint_name
 
-__all__ = ["shape_struct", "run_kernel", "KernelLoweringError"]
+__all__ = ["shape_struct", "run_kernel", "KernelLoweringError",
+           "ATTENTION_RESIDUAL_NAMES", "name_attention_residuals"]
+
+#: ``checkpoint_name`` tags of the two residuals that only the forward
+#: kernel can produce.  A remat policy that saves these names
+#: (``tensor_parallel.random.CHECKPOINT_POLICIES``) keeps them across
+#: ``jax.checkpoint``, so the backward does not run the forward kernel
+#: a second time; under any other policy the tags do nothing.
+ATTENTION_RESIDUAL_NAMES = ("fmha_out", "fmha_lse")
+
+
+def name_attention_residuals(out, lse):
+    """Tag a training attention kernel's ``(out, lse)``, in its
+    ``custom_vjp`` forward rule, BEFORE they go into the residual tuple:
+    the backward reads the residuals, so a tag on the primal output
+    alone would still leave ``lse`` to be recomputed."""
+    out_name, lse_name = ATTENTION_RESIDUAL_NAMES
+    return checkpoint_name(out, out_name), checkpoint_name(lse, lse_name)
 
 
 class KernelLoweringError(RuntimeError):

@@ -26,6 +26,7 @@ from typing import Callable, Optional
 
 import jax
 
+from apex_tpu.ops.common import ATTENTION_RESIDUAL_NAMES
 from apex_tpu.transformer.parallel_state import (
     DATA_PARALLEL_AXIS,
     TENSOR_PARALLEL_AXIS,
@@ -58,6 +59,18 @@ CHECKPOINT_POLICIES = {
         jax.checkpoint_policies.dots_with_no_batch_dims_saveable
     ),
     "everything_saveable": jax.checkpoint_policies.everything_saveable,
+    # the models' default: the dots policy above, plus the two residuals
+    # the attention kernels' forward rules name (out, lse).  A Mosaic
+    # call is not a dot, so without this the backward runs the forward
+    # kernel a second time; the XLA attention path carries no tag and
+    # compiles as under the dots policy alone
+    "dots_with_no_batch_dims_and_attention_saveable": (
+        jax.checkpoint_policies.save_from_both_policies(
+            jax.checkpoint_policies.dots_with_no_batch_dims_saveable,
+            jax.checkpoint_policies.save_only_these_names(
+                *ATTENTION_RESIDUAL_NAMES),
+        )
+    ),
 }
 
 

@@ -388,7 +388,9 @@ def test_measured_optimal_defaults_pinned():
     evidence."""
     cfg = GPTConfig()
     assert cfg.remat is True
-    assert cfg.remat_policy == "dots_with_no_batch_dims_saveable"
+    # PR 27: the dots policy plus the attention kernels' (out, lse)
+    assert cfg.remat_policy == (
+        "dots_with_no_batch_dims_and_attention_saveable")
     assert cfg.fused_ce is None  # auto by logits size (PROFILE_r05)
     assert cfg.fused_ce_chunk == 8192
     assert cfg.attention_impl is None  # auto -> pallas on TPU

@@ -13,8 +13,8 @@ plain path BEFORE it (no session), so that:
   per request, ``commit`` tokens = tokens generated);
 - with no session nothing is recorded and the tokens are the same;
 - the compiled serving programs carry ``tlm.prefill`` / ``tlm.decode``
-  and a train step's compiled text names its attention kernels, the
-  rematerialised forward apart;
+  and a train step's compiled text names its attention kernels, a
+  rematerialised forward (``nothing_saveable``) apart;
 - ``Request.arrival_s`` becomes ``Completion.queue_wait_s`` and the
   ``queue_wait_us`` stat of the request's ``dispatch_prefill`` span.
 """
@@ -357,20 +357,25 @@ def test_compiled_serving_program_holds_its_phase(stack, which, scope):
 # ---------------------------------------------------------------------------
 # kernels found by name
 # ---------------------------------------------------------------------------
-@pytest.mark.parametrize("remat", [True, False])
+@pytest.mark.parametrize("remat,policy,recomputed", [
+    (True, None, False), (True, "nothing_saveable", True),
+    (False, None, False)], ids=["True", "True-nothing_saveable", "False"])
 def test_train_step_s_compiled_text_names_its_attention_kernels(
-        stack, remat):
+        stack, remat, policy, recomputed):
     """The kernel path is forced (``attention_impl="mid"``: off the TPU
     ``auto`` resolves to XLA, and the forced kernel runs in interpret
     mode).  Forward and backward carry their own names under the layer
-    scan's ``while``; with remat the forward is there a second time,
-    under ``rematted_computation``."""
+    scan's ``while``.  Under the default remat policy the forward's
+    ``out`` and ``lse`` are kept (PR 27), so it is NOT there a second
+    time; under ``nothing_saveable`` it is, under
+    ``rematted_computation``."""
     from apex_tpu.models import GPTConfig, GPTModel
 
+    override = {} if policy is None else {"remat_policy": policy}
     model = GPTModel(GPTConfig(
         vocab_size=64, num_layers=2, hidden_size=32, num_attention_heads=4,
         max_position_embeddings=16, compute_dtype=jnp.float32, remat=remat,
-        attention_impl="mid"))
+        attention_impl="mid", **override))
     params = model.init(jax.random.PRNGKey(0))
     specs = model.param_specs()
     step = jax.jit(jax.shard_map(
@@ -388,7 +393,7 @@ def test_train_step_s_compiled_text_names_its_attention_kernels(
                  if fwd in n and "rematted_computation" in n]
     assert plain_fwd and all("while/body" in n for n in plain_fwd)
     assert any(bwd in n and "transpose(jvp" in n for n in op_names)
-    assert bool(remat_fwd) == remat
+    assert bool(remat_fwd) == recomputed
     assert not any(bwd in n and fwd in n for n in op_names)
 
 
