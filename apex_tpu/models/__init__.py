@@ -7,6 +7,7 @@ flagship benchmark drivers).
 """
 
 from apex_tpu.models.bert import BertConfig, BertModel
+from apex_tpu.models.deepseek_v32 import DeepSeekV32Config, DeepSeekV32Model
 from apex_tpu.models.gpt import GPTConfig, GPTModel
 from apex_tpu.models.resnet import ResNet, ResNetConfig, resnet50
 from apex_tpu.models.t5 import T5Config, T5Model
@@ -16,6 +17,8 @@ __all__ = [
     "GPTModel",
     "BertConfig",
     "BertModel",
+    "DeepSeekV32Config",
+    "DeepSeekV32Model",
     "ResNet",
     "ResNetConfig",
     "resnet50",

@@ -26,6 +26,15 @@ from apex_tpu.ops.attention import (  # noqa: F401
 from apex_tpu.ops.attention_short import (  # noqa: F401
     fmha_short,
 )
+from apex_tpu.ops.attention_latent import (  # noqa: F401
+    mla_absorbed,
+    mla_expanded,
+)
+from apex_tpu.ops.sparse_index import (  # noqa: F401
+    index_scores,
+    topk_indices,
+    topk_mask,
+)
 from apex_tpu.ops.attention_mid import (  # noqa: F401
     fmha_mid,
 )
@@ -49,6 +58,11 @@ __all__ = [
     "quantize_weight",
     "fmha_mid",
     "fmha_short",
+    "index_scores",
+    "mla_absorbed",
+    "mla_expanded",
+    "topk_indices",
+    "topk_mask",
     "fused_layer_norm",
     "fused_layer_norm_affine",
     "fused_rms_norm",

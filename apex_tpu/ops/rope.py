@@ -26,7 +26,7 @@ import jax.numpy as jnp
 
 __all__ = [
     "rope_cos_sin", "apply_rope", "apply_rope_tables", "rope_table",
-    "apply_rope_at",
+    "apply_rope_at", "yarn_inv_freq", "yarn_mscale", "yarn_table",
 ]
 
 
@@ -162,3 +162,54 @@ def apply_rope_at(
             )
         cos, sin = cos[:, None], sin[:, None]   # broadcast over heads
     return apply_rope_tables(x, cos, sin)
+
+
+# ---------------------------------------------------------------------------
+# YaRN: frequencies blended for a context stretched past the trained one
+# ---------------------------------------------------------------------------
+
+
+def yarn_inv_freq(
+    head_dim: int, *, base: float = 10000.0, factor: float,
+    beta_fast: float, beta_slow: float, original_max_position: int,
+):
+    """The ``head_dim // 2`` rotary frequencies under YaRN
+    (arXiv:2309.00071 as DeepSeek-V2/V3 apply it): ``theta_i`` below
+    the correction dimension of ``beta_fast`` (wavelengths that fit the
+    trained context many times), ``theta_i / factor`` above that of
+    ``beta_slow``, a linear ramp between the two.  A numpy float64
+    array: it is a constant of the model, computed once."""
+    import math
+
+    import numpy as np
+
+    d = int(head_dim)
+    theta = 1.0 / float(base) ** (np.arange(0, d, 2, dtype=np.float64) / d)
+
+    def correction_dim(rotations: float) -> float:
+        return d * math.log(original_max_position
+                            / (rotations * 2 * math.pi)) / (
+            2 * math.log(base))
+
+    low = max(math.floor(correction_dim(beta_fast)), 0)
+    high = min(math.ceil(correction_dim(beta_slow)), d - 1)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((np.arange(d // 2) - low) / (high - low), 0.0, 1.0)
+    return theta * (1.0 - ramp) + theta / float(factor) * ramp
+
+
+def yarn_mscale(factor: float, mscale: float = 1.0) -> float:
+    """YaRN's attention temperature ``m = 0.1 * mscale * ln(factor) +
+    1``; MLA multiplies its softmax scale by ``m ** 2``."""
+    import math
+
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_table(max_len: int, inv_freq) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """fp32 ``(cos, sin)`` tables ``(max_len, len(inv_freq))`` for
+    :func:`apply_rope_tables`, rows gathered by position."""
+    angles = (jnp.arange(max_len, dtype=jnp.float32)[:, None]
+              * jnp.asarray(inv_freq, jnp.float32))
+    return jnp.cos(angles), jnp.sin(angles)

@@ -64,6 +64,7 @@ __all__ = [
     "prompt_page_hashes",
     "init_pools",
     "write_tokens",
+    "write_latent_tokens",
     "write_targets",
     "copy_pages",
     "export_pages",
@@ -105,7 +106,20 @@ class KVCacheConfig:
     one sequence's logical length at ``pages_per_seq * page_size``
     tokens.  ``kv_dtype=None`` stores pages in ``dtype``;
     ``jnp.int8`` stores quantized pages with per-``(token, kv_block)``
-    fp32 scales."""
+    fp32 scales.
+
+    ``kind="latent"`` is a pool whose per-token entry is NOT per head
+    (multi-head latent attention with a sparse indexer): ``latent_dim``
+    values shared by all heads (the normalised latent and the rotated
+    shared key side by side) plus an ``index_dim``-wide index key, per
+    layer.  ``num_heads`` / ``head_dim`` do not describe such an entry
+    and must be left at 1 / ``latent_dim``; pages, tables, the
+    allocator and admission are the same.  A latent row is stored
+    ``latent_row_dim`` wide, ``latent_dim`` rounded up to whole 128-lane
+    tiles (the tail is zero): the device tiles a row that way in any
+    case, and a pool whose last axis is NOT a multiple of 128 (576) is
+    re-laid out — copied whole, twice a step — around every scatter
+    into it (v5e compiler, 1.47 GB of temporaries for a 1.3 GB pool)."""
 
     num_layers: int
     num_heads: int
@@ -117,6 +131,9 @@ class KVCacheConfig:
     dtype: Any = jnp.bfloat16
     kv_dtype: Optional[Any] = None
     kv_block: int = 128
+    kind: str = "kv"
+    latent_dim: int = 0
+    index_dim: int = 0
 
     def __post_init__(self):
         if self.num_pages < 2:
@@ -129,10 +146,27 @@ class KVCacheConfig:
                 jnp.dtype(self.kv_dtype) != jnp.dtype(jnp.int8):
             raise ValueError(
                 f"kv_dtype must be None or int8, got {self.kv_dtype!r}")
+        if self.kind not in ("kv", "latent"):
+            raise ValueError(
+                f"kind must be 'kv' or 'latent', got {self.kind!r}")
+        if self.kind == "latent":
+            if self.latent_dim < 1 or self.index_dim < 1:
+                raise ValueError(
+                    "a latent pool needs latent_dim and index_dim >= 1")
+            if (self.num_heads, self.head_dim) != (1, self.latent_dim):
+                raise ValueError(
+                    "a latent entry is shared by all heads: pass "
+                    "num_heads=1, head_dim=latent_dim")
+            if self.quantized:
+                raise ValueError("latent pools are not quantized")
 
     @property
     def quantized(self) -> bool:
         return self.kv_dtype is not None
+
+    @property
+    def latent_row_dim(self) -> int:
+        return -(-self.latent_dim // 128) * 128
 
     @property
     def scale_blocks(self) -> int:
@@ -525,11 +559,14 @@ class PagedKVCache:
         ``num_pages`` / ``max_seqs`` / ``pages_per_seq`` are per-replica
         capacity, not page layout, so they may differ."""
         cfg = self.config
-        return (cfg.num_layers, cfg.num_heads, cfg.head_dim,
-                cfg.page_size, str(jnp.dtype(cfg.dtype)),
-                None if cfg.kv_dtype is None
-                else str(jnp.dtype(cfg.kv_dtype)),
-                cfg.kv_block)
+        key = (cfg.num_layers, cfg.num_heads, cfg.head_dim,
+               cfg.page_size, str(jnp.dtype(cfg.dtype)),
+               None if cfg.kv_dtype is None
+               else str(jnp.dtype(cfg.kv_dtype)),
+               cfg.kv_block)
+        if cfg.kind != "kv":
+            key += (cfg.kind, cfg.latent_dim, cfg.index_dim)
+        return key
 
     def device_tables(self) -> Tuple[jnp.ndarray, jnp.ndarray]:
         """(page_table, lengths) as device arrays — a few KB per step."""
@@ -546,8 +583,16 @@ def init_pools(config: KVCacheConfig) -> Dict[str, jnp.ndarray]:
     """Zeroed device pools: ``k``/``v`` of shape ``(num_layers,
     num_pages, num_heads, page_size, head_dim)`` (the decode kernel's
     pool layout with a leading layer axis the model's layer scan
-    slices), plus fp32 ``k_scales``/``v_scales`` when quantized."""
+    slices), plus fp32 ``k_scales``/``v_scales`` when quantized.
+
+    ``kind="latent"``: ``ckv`` of shape ``(num_layers, num_pages,
+    page_size, latent_row_dim)`` and ``kidx`` of ``(..., index_dim)`` — a
+    token's entry is one row, shared by all heads."""
     cfg = config
+    if cfg.kind == "latent":
+        lead = (cfg.num_layers, cfg.num_pages, cfg.page_size)
+        return {"ckv": jnp.zeros(lead + (cfg.latent_row_dim,), cfg.dtype),
+                "kidx": jnp.zeros(lead + (cfg.index_dim,), cfg.dtype)}
     shape = (cfg.num_layers, cfg.num_pages, cfg.num_heads,
              cfg.page_size, cfg.head_dim)
     dt = cfg.kv_dtype if cfg.quantized else cfg.dtype
@@ -790,4 +835,34 @@ def write_tokens(
             k_new.astype(out["k"].dtype))
         out["v"] = out["v"].at[pages, :, offsets, :].set(
             v_new.astype(out["v"].dtype))
+    return out
+
+
+def write_latent_tokens(
+    pools: Dict[str, jnp.ndarray],
+    layer,
+    ckv_new: jnp.ndarray,
+    kidx_new: jnp.ndarray,
+    pages: jnp.ndarray,
+    offsets: jnp.ndarray,
+) -> Dict[str, jnp.ndarray]:
+    """Scatter ``n`` new tokens into layer ``layer`` (a traced scalar
+    is fine) of a WHOLE latent pool dict, leading layer axis included.
+
+    ``ckv_new`` (n, latent_dim), zero-padded here to the pool's row
+    width, and ``kidx_new`` (n, index_dim) are the token rows, ``pages``/``offsets`` (n,) their physical targets
+    (:func:`write_targets`; idle or padded entries point at the null
+    page).  Unlike :func:`write_tokens` this takes and returns the
+    stacked pools: inside a layer scan that carries them, and a jit
+    that donates them, each write is one scatter INTO the buffer and no
+    layer's pool is ever sliced out, stacked back or copied."""
+    layer = jnp.asarray(layer, jnp.int32)
+    pages = pages.astype(jnp.int32)
+    offsets = offsets.astype(jnp.int32)
+    out = dict(pools)
+    pad = pools["ckv"].shape[-1] - ckv_new.shape[-1]
+    out["ckv"] = pools["ckv"].at[layer, pages, offsets].set(
+        jnp.pad(ckv_new, ((0, 0), (0, pad))).astype(pools["ckv"].dtype))
+    out["kidx"] = pools["kidx"].at[layer, pages, offsets].set(
+        kidx_new.astype(pools["kidx"].dtype))
     return out

@@ -144,6 +144,7 @@ def _import_state(pools, carry, staged, pages, slot, last, written,
     is most of the handoff's cost."""
     pools = import_pages(pools, staged, pages)
     carry = {
+        **carry,
         "tokens": carry["tokens"].at[slot].set(last),
         "lengths": carry["lengths"].at[slot].set(written),
         "steps_left": carry["steps_left"].at[slot].set(steps_left),
@@ -240,13 +241,18 @@ class HandoffPacket:
 
 
 def init_carry(max_seqs: int, key: Optional[jnp.ndarray] = None,
-               sharding: Optional[Any] = None) -> Dict[str, jnp.ndarray]:
+               sharding: Optional[Any] = None,
+               extras: Optional[Dict[str, Any]] = None
+               ) -> Dict[str, jnp.ndarray]:
     """The decode step's per-slot device state: all slots idle.
     ``sample_keys`` holds one PRNG key row per slot (overwritten at
     admission — from ``Request.seed`` when given).  ``sharding`` places
     it once where the compiled steps return it
     (``GPTDecodeFns.carry_sharding``): a carry that starts off the mesh
-    makes the second decode step compile again."""
+    makes the second decode step compile again.  ``extras`` are further
+    entries a decode step keeps in its carry beside the per-slot five
+    (``decode_fn.carry_extras``: their initial values): the batcher
+    threads them through untouched."""
     s = max_seqs
     base = jnp.asarray(
         key if key is not None else jax.random.PRNGKey(0), jnp.uint32)
@@ -256,6 +262,7 @@ def init_carry(max_seqs: int, key: Optional[jnp.ndarray] = None,
         "steps_left": jnp.zeros((s,), jnp.int32),
         "done": jnp.ones((s,), bool),
         "sample_keys": jnp.broadcast_to(base[None], (s,) + base.shape),
+        **(extras or {}),
     }
     return carry if sharding is None else jax.device_put(carry, sharding)
 
@@ -272,7 +279,10 @@ class ContinuousBatcher:
     -> (pools, carry)`` — one token for every live slot; must freeze
     slots whose ``done`` is set (null-page writes, unchanged token /
     length / budget) and maintain ``done |= sampled == eos or budget
-    exhausted``.
+    exhausted``.  A step may keep entries of its own in the carry beside
+    the per-slot five (``decode_fn.carry_extras`` gives their initial
+    values): they are threaded through untouched, and one named
+    ``counters`` is fetched with every harvest (``step_counters``).
 
     ``chunk_fn(pools, tokens (C,) i32, start, prompt_len, write_from,
     page_row, key) -> (pools, first_token, logits)`` — one
@@ -489,7 +499,12 @@ class ContinuousBatcher:
         self.logger = logger
         self.carry = init_carry(
             cache.config.max_seqs, key,
-            sharding=getattr(decode_fn, "carry_sharding", None))
+            sharding=getattr(decode_fn, "carry_sharding", None),
+            extras=getattr(decode_fn, "carry_extras", None))
+        #: host copy of ``carry["counters"]`` (a step's own running
+        #: counts, where its carry has that entry) as of the last
+        #: harvest: it comes over in the harvest's one transfer
+        self.step_counters: Optional[np.ndarray] = None
         self._base_key = (key if key is not None
                           else jax.random.PRNGKey(0))
         self._n_admits = 0
@@ -602,6 +617,7 @@ class ContinuousBatcher:
         budget_left = req.max_new_tokens - 1
         c = self.carry
         self.carry = {
+            **c,
             "tokens": c["tokens"].at[slot].set(first),
             "lengths": c["lengths"].at[slot].set(plen),
             "steps_left": c["steps_left"].at[slot].set(budget_left),
@@ -1097,8 +1113,9 @@ class ContinuousBatcher:
         firsts = {s: self._first_tok.pop(s) for s in list(self._first_tok)}
         stacked = jnp.stack(window) if window else None
         with host_span("serve.harvest", steps=steps, firsts=len(firsts)):
-            harvested, firsts_h, done_h = _device_get(
-                (stacked, firsts, self.carry["done"]))
+            harvested, firsts_h, done_h, self.step_counters = _device_get(
+                (stacked, firsts, self.carry["done"],
+                 self.carry.get("counters")))
         t_h = time.perf_counter()
         self.windows += 1
 

@@ -19,6 +19,12 @@ as **absent** from the reference snapshot.  TPU-native design:
   replicated router grads come back already summed across dp.
 
 Returns the Switch auxiliary load-balance loss alongside the output.
+
+:class:`HeldExpertsMLP` is the SERVING expert layer: no capacity, no
+dropped token, the DeepSeek-V3 router (sigmoid scores, bias-corrected
+group-limited choice), a shared expert, and an argument that says which
+experts this chip holds.  It is the same function in prefill chunks and
+in decode.
 """
 
 from __future__ import annotations
@@ -27,15 +33,17 @@ from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
+from apex_tpu.telemetry.spans import phase
 from apex_tpu.transformer.parallel_state import (
     DATA_PARALLEL_AXIS,
     TENSOR_PARALLEL_AXIS,
 )
 
-__all__ = ["MoEMLP"]
+__all__ = ["MoEMLP", "HeldExpertsMLP"]
 
 
 class MoEMLP:
@@ -222,31 +230,293 @@ class MoEMLP:
         return out.reshape(b, s, h), aux
 
     def decode(self, *args, **kwargs):
-        """Single-token serving decode through the expert layer —
-        NOT implemented; raises loudly rather than silently serving a
-        dense approximation.
+        """Serving through THIS layer kind is refused, loudly.
 
-        The training path above is built around fixed per-(expert,
-        source-rank) capacity and two ``lax.all_to_all`` hops sized for
-        full sequences; a decode step routes ONE token per slot, so
-        the same capacity math degenerates (cap rounds up to 1 and the
-        all_to_all moves mostly padding).  A real expert-parallel
-        decode wants: (a) slot-major top-k routing with no capacity
-        drops (a dropped token is a corrupted generation, not a
-        training regularizer), (b) expert weights resident per ep rank
-        with the token batch gathered to its experts — an all_to_all
-        over at most ``max_seqs`` rows, or replicated experts below
-        the memory crossover, and (c) the page-table/sampler contract
-        untouched (routing is per-token state-free, so the paged KV
-        pool and the per-slot key schedule need no changes).  That is
-        its own PR; until then the serving stack refuses MoE models at
-        decode_fns-build time via this error.
+        ``MoEMLP`` is the training layer: a fixed per-(expert,
+        source-rank) capacity that DROPS overflow tokens (a training
+        regulariser, a corrupted generation when serving) and two
+        ``all_to_all`` hops sized for whole sequences.  Which expert
+        layers serve:
+
+        - :class:`HeldExpertsMLP` (sigmoid scores, group-limited top-k,
+          shared expert, no capacity) serves prefill chunks and decode
+          on the chip that holds some of the experts; it is what
+          ``models/deepseek_v32.py`` builds its ``decode_fns`` from.
+        - ``MoEMLP`` (softmax top-k with capacity) does not, and
+          ``GPTModel.decode_fns`` calls this method to say so at build
+          time.  Serving it needs the same no-drop grouped computation
+          behind its own router, plus the token exchange between
+          expert-parallel ranks that neither layer has yet
+          (ROADMAP.md, "what the system still cannot run").
         """
         raise NotImplementedError(
-            "MoEMLP.decode: expert-parallel serving decode is not "
-            "implemented — the training path's capacity-bounded "
-            "all_to_all does not degenerate safely to one token per "
-            "slot (see the design note in MoEMLP.decode's docstring). "
-            "Serve a dense-MLP model, or distill the experts before "
-            "deployment."
+            "MoEMLP.decode: the capacity-bounded training layer drops "
+            "tokens and cannot serve.  The no-drop serving expert layer "
+            "is apex_tpu.transformer.moe.HeldExpertsMLP (used by "
+            "apex_tpu.models.deepseek_v32); GPTModel has no serving "
+            "path for its softmax/capacity expert layer."
         )
+
+
+class HeldExpertsMLP:
+    """No-drop expert layer for the chip that holds SOME of the experts.
+
+    The router scores all ``num_experts`` with the published rule
+    (DeepSeek-V3, ``noaux_tc``): ``s = sigmoid(x W_r)`` in fp32; the
+    choice runs on ``c = s + b`` (``b`` the load-balancing correction
+    bias) — a group's score is the sum of its two largest ``c``, the
+    ``topk_group`` best of ``n_group`` groups stay, the ``top_k``
+    largest ``c`` inside them are the chosen set ``T``; the weights
+    come from ``s``: ``g_e = routed_scaling_factor * s_e / (sum_{T} s +
+    1e-20)``, normalised over ALL of ``T`` whether held here or not.
+
+    ``held`` (a static tuple of expert ids, in the order of the stacked
+    expert weights) is an argument of :meth:`apply`: the layer computes
+    ``Shared(x) + sum_{e in T and held} g_e Expert_e(x)``.  Summed over
+    a partition of the experts, with the shared expert counted once,
+    the shares give the whole layer (tested).  There is no capacity and
+    no token is dropped; on one chip there is no exchange and nothing
+    stands in for the absent chips.
+
+    **Grouped computation.**  The (token, choice) pairs that landed on
+    held experts are sorted by expert and laid out in tiles of
+    ``tile_rows`` rows, each tile one expert's; a loop over the tiles
+    IN USE (a dynamic count) multiplies each by its expert's three
+    matrices, and every token then gathers and weights its own rows.  Work and
+    weight traffic follow the pairs that exist: an expert nobody chose
+    is not read, and there is no (n, E, capacity) dispatch mask.
+    """
+
+    #: what :meth:`apply` counts, in this order; the load of each held
+    #: expert (rows it was given) follows them
+    COUNTERS = ("choices", "choices_held", "experts_touched", "load_max")
+
+    def __init__(
+        self,
+        hidden_size: int,
+        ffn_hidden_size: int,
+        num_experts: int,
+        *,
+        top_k: int,
+        n_group: int = 1,
+        topk_group: int = 1,
+        routed_scaling_factor: float = 1.0,
+        n_shared_experts: int = 1,
+        params_dtype: Any = jnp.bfloat16,
+        init_std: Optional[float] = None,
+    ):
+        if num_experts % n_group:
+            raise ValueError(
+                f"num_experts ({num_experts}) must divide into n_group "
+                f"({n_group}) groups")
+        if not 1 <= topk_group <= n_group:
+            raise ValueError(
+                f"topk_group ({topk_group}) must be in [1, {n_group}]")
+        if top_k > topk_group * (num_experts // n_group):
+            raise ValueError(
+                f"top_k ({top_k}) exceeds the experts in {topk_group} "
+                f"groups of {num_experts // n_group}")
+        self.hidden_size = hidden_size
+        self.ffn_hidden_size = ffn_hidden_size
+        self.num_experts = num_experts
+        self.top_k = top_k
+        self.n_group = n_group
+        self.topk_group = topk_group
+        self.routed_scaling_factor = routed_scaling_factor
+        self.n_shared_experts = n_shared_experts
+        self.params_dtype = params_dtype
+        self.init_std = init_std
+
+    # ----------------------------------------------------------- params
+    def init(self, key, num_held: int) -> Dict[str, Any]:
+        """Seeded weights for ``num_held`` experts.  The correction bias
+        is NON-zero (uniform in +-0.1: it moves the choice without
+        deciding it) so that a test tells the choice scores ``c`` from
+        the weights' ``s``."""
+        h, f, E = self.hidden_size, self.ffn_hidden_size, self.num_experts
+        fs = f * self.n_shared_experts
+        ks = jax.random.split(key, 8)
+        std = lambda fan_in: self.init_std or fan_in ** -0.5
+        w = lambda k, shape, fan_in: (
+            std(fan_in) * jax.random.normal(k, shape, jnp.float32)
+        ).astype(self.params_dtype)
+        return {
+            "router": {
+                "weight": w(ks[0], (h, E), h),
+                "bias": jax.random.uniform(
+                    ks[1], (E,), jnp.float32, -0.1, 0.1),
+            },
+            "experts": {
+                "w_gate": w(ks[2], (num_held, h, f), h),
+                "w_up": w(ks[3], (num_held, h, f), h),
+                "w_down": w(ks[4], (num_held, f, h), f),
+            },
+            "shared": {
+                "w_gate": w(ks[5], (h, fs), h),
+                "w_up": w(ks[6], (h, fs), h),
+                "w_down": w(ks[7], (fs, h), fs),
+            },
+        }
+
+    def param_specs(self) -> Dict[str, Any]:
+        return {
+            "router": {"weight": P(), "bias": P()},
+            "experts": {"w_gate": P(), "w_up": P(), "w_down": P()},
+            "shared": {"w_gate": P(), "w_up": P(), "w_down": P()},
+        }
+
+    # ------------------------------------------------------------ route
+    def route(self, params: Dict[str, Any], x: jnp.ndarray
+              ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+        """``x`` (n, h) -> (chosen experts (n, top_k) int32, weights
+        ``g`` (n, top_k) fp32), over all ``num_experts``."""
+        E, G = self.num_experts, self.n_group
+        with phase("moe.route"):
+            # fp32 THROUGHOUT when the caller hands fp32 in (six bf16
+            # passes on a TPU, for num_experts outputs): a score rounded
+            # to bf16 flips a choice at the k-th place now and then, and
+            # a flipped expert is a visibly different token
+            s = jax.nn.sigmoid(jnp.matmul(
+                x, params["router"]["weight"].astype(x.dtype),
+                precision=(lax.Precision.HIGHEST
+                           if x.dtype == jnp.float32 else None),
+                preferred_element_type=jnp.float32))
+            c = s + params["router"]["bias"].astype(jnp.float32)
+            if G > 1:
+                group_score = jnp.sum(
+                    lax.top_k(c.reshape(-1, G, E // G), 2)[0], axis=-1)
+                kept = lax.top_k(group_score, self.topk_group)[1]
+                group_ok = jnp.any(
+                    kept[:, :, None] == jnp.arange(G)[None, None], axis=1)
+                c = jnp.where(jnp.repeat(group_ok, E // G, axis=1),
+                              c, -jnp.inf)
+            chosen = lax.top_k(c, self.top_k)[1]
+            weight = jnp.take_along_axis(s, chosen, axis=1)
+            g = self.routed_scaling_factor * weight / (
+                jnp.sum(weight, axis=-1, keepdims=True) + 1e-20)
+            return chosen.astype(jnp.int32), g
+
+    # ---------------------------------------------------------- experts
+    @staticmethod
+    def _swiglu(x, w_gate, w_up, w_down):
+        a = jnp.matmul(x, w_gate, preferred_element_type=jnp.float32)
+        b = jnp.matmul(x, w_up, preferred_element_type=jnp.float32)
+        return jnp.matmul((jax.nn.silu(a) * b).astype(x.dtype), w_down,
+                          preferred_element_type=jnp.float32)
+
+    def _experts(self, experts, x, chosen, g, held, token_valid,
+                 tile_rows: int, layer=None):
+        """Gathers and dynamic slices only, no scatter: rows find their
+        tokens through the sorted order, tokens find their rows through
+        a running count per expert."""
+        n, h = x.shape
+        k, nh = self.top_k, len(held)
+        T = tile_rows
+        lookup = np.full((self.num_experts,), nh, np.int32)
+        lookup[list(held)] = np.arange(nh, dtype=np.int32)
+        # one entry per (token, choice) pair; nh marks "not held here"
+        expert = jnp.where(token_valid[:, None],
+                           jnp.asarray(lookup)[chosen], nh).reshape(-1)
+        onehot = expert[:, None] == jnp.arange(nh, dtype=jnp.int32)[None]
+        sizes = jnp.sum(onehot, axis=0, dtype=jnp.int32)
+        tiles = -(-sizes // T)
+        row_end = jnp.cumsum(tiles) * T         # padded layout, per expert
+        row_start = row_end - tiles * T
+        src_start = jnp.cumsum(sizes) - sizes   # sorted layout
+        n_tiles = -(-(n * k) // T) + nh         # static bound
+        order = jnp.argsort(expert, stable=True)
+        # tile t belongs to the expert whose padded range holds it
+        tile_expert = jnp.minimum(jnp.searchsorted(
+            row_end, jnp.arange(n_tiles, dtype=jnp.int32) * T,
+            side="right"), nh - 1).astype(jnp.int32)
+
+        def weight(w, e):
+            # ONE slice out of the stack(s): a layer's experts are never
+            # cut out whole (1.4 GB a layer at the published widths)
+            if layer is None:
+                return lax.dynamic_index_in_dim(w, e, 0, False)
+            return lax.dynamic_slice(
+                w, (layer, e, 0, 0), (1, 1) + w.shape[2:])[0, 0]
+
+        def tile(t, buf):
+            e = tile_expert[t]
+            offset = t * T - row_start[e] + jnp.arange(T, dtype=jnp.int32)
+            pair = order[jnp.clip(src_start[e] + offset, 0, n * k - 1)]
+            rows = jnp.where((offset < sizes[e])[:, None], x[pair // k], 0)
+            out = self._swiglu(rows, *(
+                weight(experts[name], e)
+                for name in ("w_gate", "w_up", "w_down")))
+            return lax.dynamic_update_slice(
+                buf, out.astype(buf.dtype), (t * T, 0))
+
+        buf = lax.fori_loop(0, jnp.sum(tiles), tile,
+                            jnp.zeros((n_tiles * T, h), x.dtype))
+        # a pair's row: its expert's first row plus how many earlier
+        # pairs chose the same expert (the stable sort's order)
+        within = jnp.sum(jnp.where(
+            onehot, jnp.cumsum(onehot, axis=0, dtype=jnp.int32) - 1, 0),
+            axis=1)
+        live = expert < nh
+        row = jnp.where(live, row_start[jnp.minimum(expert, nh - 1)]
+                        + within, 0)
+        gate = jnp.where(live, g.reshape(-1), 0.0)
+        y = jnp.sum((buf[row].astype(jnp.float32) * gate[:, None]
+                     ).reshape(n, k, h), axis=1)
+        counters = jnp.concatenate([jnp.stack([
+            jnp.sum(token_valid).astype(jnp.float32) * k,
+            jnp.sum(sizes).astype(jnp.float32),
+            jnp.sum(sizes > 0).astype(jnp.float32),
+            jnp.max(sizes).astype(jnp.float32),
+        ]), sizes.astype(jnp.float32)])
+        return y, counters
+
+    def apply(
+        self,
+        params: Dict[str, Any],
+        x: jnp.ndarray,
+        held: Tuple[int, ...],
+        *,
+        token_valid: Optional[jnp.ndarray] = None,
+        tile_rows: Optional[int] = None,
+        expert_layer=None,
+    ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+        """``x`` (n, h) -> (output (n, h) in the weights' dtype, counters
+        fp32 (4 + len(held),): in the order of :attr:`COUNTERS` the
+        choices made by the valid tokens, those that landed on ``held``
+        experts, distinct held experts touched, the largest load among
+        them; then each held expert's load).
+
+        ``held`` names the experts whose weights ``params['experts']``
+        stacks, in that order.  ``token_valid`` (n,) marks padding rows
+        of a prefill chunk and idle decode slots: they route nothing and
+        count nothing.  ``tile_rows`` defaults to 128 where an expert
+        can expect that many rows, else 16.  With ``expert_layer`` (a traced scalar is fine) the leaves of
+        ``params['experts']`` keep a leading layer axis and that layer's
+        experts are used: a model that scans over its layers hands the
+        whole stack in, so that no layer's experts are sliced out of it.
+        The ROUTER reads ``x`` as it comes (fp32 in, fp32 scores); the
+        experts read it rounded to the weights' dtype.
+        """
+        n = x.shape[0]
+        held = tuple(int(e) for e in held)
+        stacked = params["experts"]["w_gate"].shape[
+            0 if expert_layer is None else 1]
+        if len(held) != stacked:
+            raise ValueError(
+                f"held names {len(held)} experts but the weights stack "
+                f"{stacked}")
+        if token_valid is None:
+            token_valid = jnp.ones((n,), bool)
+        if tile_rows is None:
+            expected = n * self.top_k // self.num_experts
+            tile_rows = 128 if expected >= 32 else 16
+        chosen, g = self.route(params, x)
+        x = x.astype(params["experts"]["w_gate"].dtype)
+        with phase("moe.experts"):
+            y, counters = self._experts(
+                params["experts"], x, chosen, g, held, token_valid,
+                tile_rows, expert_layer)
+        with phase("moe.shared"):
+            sh = params["shared"]
+            y = y + self._swiglu(x, sh["w_gate"], sh["w_up"], sh["w_down"])
+        return y.astype(x.dtype), counters

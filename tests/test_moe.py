@@ -316,13 +316,12 @@ def test_moe_aux_threads_through_pipeline():
 
 
 def test_moe_decode_raises_with_design_note():
-    """The serving decode path through an expert layer must refuse
-    LOUDLY (silent dense fallback would corrupt generations); the
-    error carries the expert-parallel design pointer, and every
-    gpt.py decode entry point routes through it."""
+    """The serving decode path through the CAPACITY expert layer must
+    refuse LOUDLY (silent dense fallback would corrupt generations); the
+    error names the layer kind that does serve (``HeldExpertsMLP``),
+    and every gpt.py decode entry point routes through it."""
     layer = MoEMLP(H, F, E)
-    with pytest.raises(NotImplementedError,
-                       match="expert-parallel serving decode"):
+    with pytest.raises(NotImplementedError, match="HeldExpertsMLP"):
         layer.decode()
 
     from apex_tpu.models import GPTConfig, GPTModel
@@ -338,5 +337,5 @@ def test_moe_decode_raises_with_design_note():
                          (model.prefill_chunk, 7),
                          (model.verify_step, 7)):
         with pytest.raises(NotImplementedError,
-                           match="expert-parallel serving decode"):
+                           match="HeldExpertsMLP"):
             entry(*([None] * nargs))
