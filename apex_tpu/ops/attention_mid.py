@@ -6,26 +6,44 @@ flagship actually trains in.  KERNELS_TPU.json measured the flash kernel
 at 10.2 TF/s fwd at s=1024 causal vs ~50 TF/s at s>=4096: with the
 measured-optimal 1024x1024 blocks the whole K/V sequence sits in ONE
 block, so the streamed-K/V design degenerates to one fused attention
-per (b, h) with no software pipelining to hide the VPU softmax chain
-between the two MXU dots — and causal costs the same wall time as full
-(0.843 vs 0.857 ms) because there are no blocks to skip.
+per (b, h) — and causal costs the same wall time as full (0.843 vs
+0.857 ms) because there are no blocks to skip.
 
-This kernel restores the pipeline at mid lengths by doing three things
-the flash kernel's shape degeneracy loses:
+This kernel does three things the flash kernel's shape degeneracy
+loses:
 
-- **k-blocks smaller than the sequence** (256/512 default): the kb grid
-  axis streams K/V through VMEM with Mosaic's revolving-buffer
-  (double-buffered) pipelining, and within a program the qk dot of
-  block kb+1 has no data dependence on the softmax chain of block kb,
-  so the MXU runs under the VPU instead of waiting for it;
+- **k-blocks smaller than the sequence**: the kb grid axis streams K/V
+  through VMEM with Mosaic's revolving-buffer (double-buffered)
+  pipelining;
 - **bh packing above s=512** (PR 1's ``block_bh`` trick lifted past the
-  short-kernel window): each program holds ``block_bh`` (batch*head)
-  tiles resident and issues their dots back-to-back from one unrolled
-  body, keeping the MXU fed when per-(b, h) work is small;
+  short-kernel window): each program holds several (batch*head) tiles
+  and issues their dots from one unrolled body, so one tile's dots can
+  run under another's softmax chain and a grid step's fixed cost is
+  shared;
 - **causal block-skipping that actually fires**: the per-q-block upper
-  bound on the kb loop (same logic the flash kernel carries) now has
-  num_k > 1 blocks to skip, so causal does ~half the work of full
-  instead of identical work.
+  bound on the kb loop now has num_k > 1 blocks to skip, and the K/V
+  index maps are clamped to it, so a skipped block is not copied
+  either.
+
+**The forward's schedule** (PR 29; ``_mid_fwd_kernel``).  What the
+v5e measured on the forward alone (``tools/fmha_fwd_ablation.py``,
+PERF.md section 6): it was not waiting for the MXU (bf16 operands
+instead of float32 ones changed nothing) nor for memory, but for the
+cross-lane reductions of the online softmax — with the row max and sum
+stubbed a call fell from 2.45 to 1.58 ms, with every other bookkeeping
+cost stubbed by under 0.2 ms.  So the scores are held TRANSPOSED,
+``(block_k, block_q)`` with keys on sublanes and queries on lanes: a
+row's max and sum are elementwise folds across vregs plus one
+8-sublane fold, the running max and sum are lane-dense ``(1,
+block_q)`` rows (two vregs, not a lane-broadcast ``(block_q, 128)``
+scratch), ``lse`` leaves in the layout its output has, and ``acc`` is
+``(d, block_q)``, transposed once a q block.  The forward has its own,
+wider blocks (``default_mid_fwd_blocks``: 512x512 where the extents
+allow), so that ``acc`` is rescaled once per 512 keys; bf16 operands
+go to the MXU as they are with float32 accumulation, the statistics
+stay float32; and where the head width is padded (64 -> 128 lanes,
+192 -> 256) V's first pad lane holds ones, so the row sum comes out of
+the PV product in that lane of ``acc`` and is not computed at all.
 
 The backward is ONE fused kernel emitting dq/dk/dv (and dbias) per the
 PR 1 contract — the flash split (dkv + dq kernels) exists to bound
@@ -119,6 +137,13 @@ FMHA_MID_BLOCK_ELEMS = 512 * 1024
 #: of the forward's block_bh.
 FMHA_MID_BWD_DQ_ELEMS = 512 * 1024
 
+#: What ``default_mid_fwd_blocks`` lets a forward grid step keep in VMEM
+#: by its own estimate, and the limit handed to Mosaic (the estimate
+#: does not see all of the compiler's temporaries; the default scoped
+#: limit of 16 MiB is too small for eight 512-row tiles of 128 lanes).
+FMHA_MID_FWD_VMEM_BUDGET = 20 * 1024 * 1024
+FMHA_MID_FWD_VMEM_LIMIT = 32 * 1024 * 1024
+
 #: Unroll bound, same rationale as the short kernel: the bh block is an
 #: unrolled python loop of 2-D MXU dots; 16 copies bounds code size.
 FMHA_MID_MAX_BLOCK_BH = 16
@@ -143,7 +168,8 @@ def mid_seq_threshold() -> int:
 
 
 def default_mid_blocks(sq_p: int, sk_p: int):
-    """(block_q, block_k) for padded sequence extents.
+    """The backward's (block_q, block_k) for padded sequence extents,
+    and the unit q and k/v are padded to.
 
     Prefers the 256x256 default; drops to 128 along an axis whose
     lane-rounded extent is not a 256 multiple (ragged mid lengths like
@@ -155,9 +181,50 @@ def default_mid_blocks(sq_p: int, sk_p: int):
 
 
 def default_mid_block_bh(block_q: int, block_k: int, bh: int) -> int:
-    """How many (batch*head) tiles one grid step packs (forward)."""
+    """How many (batch*head) tiles the bh axis is padded to a multiple
+    of; the backward packs a divisor of it (``_bwd_block_bh``), the
+    forward another (``default_mid_fwd_blocks``)."""
     by_area = max(1, FMHA_MID_BLOCK_ELEMS // (block_q * block_k))
     return max(1, min(by_area, FMHA_MID_MAX_BLOCK_BH, bh))
+
+
+def _fwd_vmem_bytes(bb, bq, bk, d_p, itemsize, bias_rows):
+    """What a forward grid step keeps in VMEM: the q/out/k/v (and
+    bias) blocks, each double-buffered, the float32 ``acc`` scratch,
+    and the score-sized float32 temporaries of two tiles in flight."""
+    blocks = 2 * bb * (2 * bq + 2 * bk) * d_p * itemsize
+    bias = 2 * bias_rows * bq * bk * 4
+    acc = bb * d_p * bq * 4
+    work = 2 * 4 * bq * bk * 4
+    return blocks + bias + acc + work
+
+
+def default_mid_fwd_blocks(sq_p: int, sk_p: int, d_p: int, itemsize: int,
+                           bias_batch: int, bh_unit: int):
+    """The forward's (block_q, block_k, block_bh) from what its input
+    shows: padded extents, padded head width, operand width, and the
+    bias that rides along (``_MidConfig.bias_batch``: 0 for none).
+
+    A wider block folds more vregs elementwise before each 8-sublane
+    fold and rescales ``acc`` less often a score, so the blocks are the
+    largest of 512/256/128 that divide the padded extents; the packing
+    is the largest divisor of ``bh_unit`` that the VMEM budget holds
+    (one tile's dots run under another's softmax chain, and a grid
+    step's fixed cost is shared: 8 tiles 0.918 ms a call, 4 tiles
+    0.953, 2 tiles 1.03 at ``bf16[256,1024,128]`` on the v5e)."""
+    def largest(n):
+        return next(b for b in (512, 256, 128) if n % b == 0)
+
+    def fits(bb):
+        bias_rows = bb if bias_batch == BIAS_PER_HEAD else min(bias_batch, 1)
+        return _fwd_vmem_bytes(bb, bq, bk, d_p, itemsize,
+                               bias_rows) <= FMHA_MID_FWD_VMEM_BUDGET
+
+    bq, bk = largest(sq_p), largest(sk_p)
+    bb = bh_unit
+    while bb > 1 and (bh_unit % bb or not fits(bb)):
+        bb -= 1
+    return bq, bk, bb
 
 
 def _bwd_block_bh(block_bh: int, sq_p: int, d_p: int) -> int:
@@ -176,10 +243,16 @@ class _MidConfig(NamedTuple):
     sm_scale: float
     causal: bool
     dropout_rate: float
+    # the backward's blocks; the wrapper pads q, k/v and bh to them
     block_q: int
     block_k: int
-    block_bh: int       # forward packing
+    block_bh: int        # bh padding unit (a multiple of both packings)
     block_bh_bwd: int    # divisor of block_bh, sized by dq residency
+    # the forward's own blocks (``default_mid_fwd_blocks``): divisors of
+    # the padded extents, and of block_bh
+    fwd_block_q: int
+    fwd_block_k: int
+    fwd_block_bh: int
     q_len: int           # unpadded
     kv_len: int          # unpadded
     heads: int           # heads per batch entry (per-batch bias maps)
@@ -192,6 +265,10 @@ class _MidConfig(NamedTuple):
     # whether the primal returns (out, lse) and the backward consumes a
     # real dlse cotangent (the ring-attention merge path)
     with_lse: bool = False
+    # the pad lane of V the wrapper filled with ones, so that the row
+    # sum comes out of the PV product in that lane of the accumulator;
+    # -1: V has no such lane and the sum is taken on the VPU
+    sum_lane: int = -1
 
 
 def _dot2(a, b, contract, cfg):
@@ -207,9 +284,26 @@ def _dot2(a, b, contract, cfg):
 # ---------------------------------------------------------------------------
 
 
+def _fwd_last_kb(cfg, num_k, j):
+    """The last k block a q block attends (the causal bound)."""
+    if not cfg.causal:
+        return num_k - 1
+    return jnp.minimum(
+        num_k - 1, ((j + 1) * cfg.fwd_block_q - 1) // cfg.fwd_block_k)
+
+
 def _mid_fwd_kernel(
     *refs, cfg: _MidConfig, num_k: int, has_bias, has_segs, has_dropout,
 ):
+    """One (bh tile, q block, k block) a grid step.
+
+    The scores are held TRANSPOSED, ``(block_k, block_q)``: keys on
+    sublanes, queries on lanes.  A row's max and sum are then
+    elementwise folds across vregs plus one 8-sublane fold (no
+    cross-lane reduction a row group), the running max and sum are
+    lane-dense ``(1, block_q)`` rows, and ``lse`` leaves in the layout
+    its output has.  ``acc`` is ``(d, block_q)`` and is transposed
+    once, when the q block is written."""
     (q_ref, k_ref, v_ref), rest = refs[:3], refs[3:]
     bias_ref = qseg_ref = kseg_ref = seed_ref = None
     if has_bias:
@@ -218,83 +312,93 @@ def _mid_fwd_kernel(
         (qseg_ref, kseg_ref), rest = rest[:2], rest[2:]
     if has_dropout:
         seed_ref, rest = rest[0], rest[1:]
-    o_ref, lse_ref, acc_ref, m_ref, l_ref = rest
+    o_ref, lse_ref, acc_ref, m_ref = rest[:4]
+    l_ref = rest[4] if cfg.sum_lane < 0 else None
 
     i, j, kb = pl.program_id(0), pl.program_id(1), pl.program_id(2)
-    block_q, block_k = cfg.block_q, cfg.block_k
-    if cfg.causal:
-        last_kb = jnp.minimum(num_k - 1, ((j + 1) * block_q - 1) // block_k)
-    else:
-        last_kb = num_k - 1
+    block_q, block_k = cfg.fwd_block_q, cfg.fwd_block_k
+    last_kb = _fwd_last_kb(cfg, num_k, j)
+    # bf16 operands go to the MXU as they are (float32 accumulation);
+    # anything else is computed in float32 as before
+    cdt = q_ref.dtype if q_ref.dtype == jnp.bfloat16 else jnp.float32
+    kv_padded = cfg.kv_len < num_k * block_k
+    # a masked weight is exp(-1e30 - m) == 0 by itself once its row has
+    # seen one live key, which a causal-only mask guarantees from block
+    # 0 on; any other mask can leave a row without one, so p is zeroed
+    zero_masked_p = has_segs or has_bias or kv_padded
 
     @pl.when(kb == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
         m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
+        if l_ref is not None:
+            l_ref[...] = jnp.zeros_like(l_ref)
 
     def _body(masked):
         if masked or has_dropout:
-            q_idx = j * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0
-            )
             k_idx = kb * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1
+                jnp.int32, (block_k, block_q), 0
             )
-        for bi in range(cfg.block_bh):
-            q = q_ref[bi].astype(jnp.float32) * cfg.sm_scale  # (bq, d)
-            s = _dot2(q, k_ref[bi].astype(jnp.float32),
-                      ((1,), (1,)), cfg)                      # (bq, bk)
+            q_idx = j * block_q + jax.lax.broadcasted_iota(
+                jnp.int32, (block_k, block_q), 1
+            )
+        for bi in range(cfg.fwd_block_bh):
+            q = (q_ref[bi].astype(jnp.float32) * cfg.sm_scale).astype(cdt)
+            s = _dot2(k_ref[bi].astype(cdt), q, ((1,), (1,)), cfg)  # (bk, bq)
             if has_bias:
                 s = s + bias_ref[
                     bi if cfg.bias_batch == BIAS_PER_HEAD else 0
-                ].astype(jnp.float32)
+                ].astype(jnp.float32).T
             if masked:
-                mask = k_idx < cfg.kv_len
+                live = []
+                if kv_padded:
+                    live.append(k_idx < cfg.kv_len)
                 if cfg.causal:
-                    mask = jnp.logical_and(mask, k_idx <= q_idx)
+                    live.append(k_idx <= q_idx)
                 if has_segs:
-                    mask = jnp.logical_and(
-                        mask,
-                        qseg_ref[bi, 0][:, None] == kseg_ref[bi, 0][None, :],
-                    )
+                    live.append(
+                        kseg_ref[bi, 0][:, None] == qseg_ref[bi, 0][None, :])
+                mask = functools.reduce(jnp.logical_and, live)
                 s = jnp.where(mask, s, _NEG_INF)
-            m_prev = m_ref[bi, :, 0:1]
-            l_prev = l_ref[bi, :, 0:1]
-            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+            m_prev = m_ref[bi]                                  # (1, bq)
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=0, keepdims=True))
             p = jnp.exp(s - m_new)
-            if masked:
+            if masked and zero_masked_p:
                 p = jnp.where(mask, p, 0.0)
             corr = jnp.exp(m_prev - m_new)
-            l_new = l_prev * corr + jnp.sum(p, axis=-1, keepdims=True)
+            if l_ref is not None:
+                l_ref[bi] = l_ref[bi] * corr + jnp.sum(
+                    p, axis=0, keepdims=True)
             if has_dropout:
                 keep = _keep_mask(
-                    seed_ref[0, 0], i * cfg.block_bh + bi, q_idx, k_idx,
+                    seed_ref[0, 0], i * cfg.fwd_block_bh + bi, q_idx, k_idx,
                     jnp.uint32(_keep_threshold(cfg.dropout_rate)),
                 )
-                p_acc = jnp.where(keep, p, 0.0) * (
+                p = jnp.where(keep, p, 0.0) * (
                     1.0 / (1.0 - cfg.dropout_rate))
-            else:
-                p_acc = p
             acc_ref[bi] = acc_ref[bi] * corr + _dot2(
-                p_acc, v_ref[bi].astype(jnp.float32), ((1,), (0,)), cfg
-            )
-            m_ref[bi] = jnp.broadcast_to(m_new, m_ref.shape[1:])
-            l_ref[bi] = jnp.broadcast_to(l_new, l_ref.shape[1:])
+                v_ref[bi].astype(cdt), p.astype(cdt), ((0,), (0,)), cfg
+            )                                                   # (d, bq)
+            m_ref[bi] = m_new
 
     conds = []
     if cfg.causal:
         conds.append(kb * block_k + (block_k - 1) > j * block_q)
-    if cfg.kv_len < num_k * block_k:                         # kv padding
+    if kv_padded:
         conds.append(kb == num_k - 1)
     _mask_specialized(kb <= last_kb, conds, has_segs, _body)
 
     @pl.when(kb == last_kb)
     def _finalize():
-        for bi in range(cfg.block_bh):
-            l = jnp.maximum(l_ref[bi, :, 0:1], 1e-30)
-            o_ref[bi] = (acc_ref[bi] / l).astype(o_ref.dtype)
-            lse_ref[bi, 0] = m_ref[bi, :, 0] + jnp.log(l[:, 0])
+        for bi in range(cfg.fwd_block_bh):
+            acc = acc_ref[bi]
+            if l_ref is None:
+                l = acc[cfg.sum_lane:cfg.sum_lane + 1, :]
+            else:
+                l = l_ref[bi]
+            l = jnp.maximum(l, 1e-30)
+            o_ref[bi] = (acc / l).T.astype(o_ref.dtype)
+            lse_ref[bi] = m_ref[bi] + jnp.log(l)
 
 
 # ---------------------------------------------------------------------------
@@ -445,66 +549,45 @@ def _mid_bwd_kernel(
 # ---------------------------------------------------------------------------
 
 
-def _bias_spec(cfg, bb, block_q, block_k, wire):
-    """Bias BlockSpec for a grid whose (q-block, k-block) coordinates are
-    produced by ``wire`` (identity for the fwd (i, j, kb) grid, a swap
-    for the bwd (i, kb, jq) grid)."""
-    heads = cfg.heads
-    if cfg.bias_batch == BIAS_PER_HEAD:
-        return pl.BlockSpec((bb, block_q, block_k),
-                            wire(lambda i, j, kb: (i, j, kb)),
-                            memory_space=pltpu.VMEM)
-    if cfg.bias_batch == BIAS_PER_BATCH:
-        # block_bh divides heads (wrapper invariant), so program i
-        # covers bh rows of exactly one batch entry
-        return pl.BlockSpec(
-            (1, block_q, block_k),
-            wire(lambda i, j, kb: ((i * bb) // heads, j, kb)),
-            memory_space=pltpu.VMEM)
-    return pl.BlockSpec((1, block_q, block_k),
-                        wire(lambda i, j, kb: (0, j, kb)),
-                        memory_space=pltpu.VMEM)
-
-
-def _in_specs(cfg, bb, d_p, has_bias, has_segs, has_dropout,
-              swap_grid=False):
-    """Input BlockSpecs for q/k/v (+bias/segs/seed).  Index maps are
-    written for the forward (i, jq, kb) grid; ``swap_grid`` rewires them
-    for the backward's (i, kb, jq) grid."""
-    block_q, block_k = cfg.block_q, cfg.block_k
-
-    def w(f):
-        if not swap_grid:
-            return f
-        return lambda i, kb, jq: f(i, jq, kb)
-
+def _bwd_in_specs(cfg, bb, d_p, has_bias, has_segs, has_dropout):
+    """The backward's BlockSpecs for q/k/v (+bias/segs/seed), on its
+    (i, kb, jq) grid."""
+    block_q, block_k, heads = cfg.block_q, cfg.block_k, cfg.heads
     specs = [
-        pl.BlockSpec((bb, block_q, d_p), w(lambda i, j, kb: (i, j, 0)),
+        pl.BlockSpec((bb, block_q, d_p), lambda i, kb, jq: (i, jq, 0),
                      memory_space=pltpu.VMEM),
-        pl.BlockSpec((bb, block_k, d_p), w(lambda i, j, kb: (i, kb, 0)),
+        pl.BlockSpec((bb, block_k, d_p), lambda i, kb, jq: (i, kb, 0),
                      memory_space=pltpu.VMEM),
-        pl.BlockSpec((bb, block_k, d_p), w(lambda i, j, kb: (i, kb, 0)),
+        pl.BlockSpec((bb, block_k, d_p), lambda i, kb, jq: (i, kb, 0),
                      memory_space=pltpu.VMEM),
     ]
     if has_bias:
-        specs.append(_bias_spec(cfg, bb, block_q, block_k, w))
+        specs.append(pl.BlockSpec(
+            (bb if cfg.bias_batch == BIAS_PER_HEAD else 1, block_q, block_k),
+            lambda i, kb, jq: (_bias_row(cfg, bb, i), jq, kb),
+            memory_space=pltpu.VMEM))
     if has_segs:
         # (bh, 1, s) layout: the middle singleton keeps the trailing
         # two block dims Mosaic-tileable, same trick as flash/short
         specs.append(pl.BlockSpec((bb, 1, block_q),
-                                  w(lambda i, j, kb: (i, 0, j))))
+                                  lambda i, kb, jq: (i, 0, jq)))
         specs.append(pl.BlockSpec((bb, 1, block_k),
-                                  w(lambda i, j, kb: (i, 0, kb))))
+                                  lambda i, kb, jq: (i, 0, kb)))
     if has_dropout:
-        specs.append(pl.BlockSpec((1, 1), w(lambda i, j, kb: (0, 0)),
+        specs.append(pl.BlockSpec((1, 1), lambda i, kb, jq: (0, 0),
                                   memory_space=pltpu.SMEM))
     return specs
 
 
-def _compiler_params():
-    return pltpu.CompilerParams(
-        dimension_semantics=("parallel", "parallel", "arbitrary")
-    )
+def _bias_row(cfg, bb, i):
+    """Leading block index of program ``i``'s bias."""
+    if cfg.bias_batch == BIAS_PER_HEAD:
+        return i
+    if cfg.bias_batch == BIAS_PER_BATCH:
+        # bb divides heads (wrapper invariant), so program i covers bh
+        # rows of exactly one batch entry
+        return (i * bb) // cfg.heads
+    return 0
 
 
 def _bwd_compiler_params():
@@ -517,42 +600,71 @@ def _bwd_compiler_params():
 def _mid_fwd_pallas(q, k, v, bias, qseg, kseg, seed, cfg: _MidConfig):
     bh_p, psq, d_p = q.shape
     psk = k.shape[1]
-    num_q, num_k = psq // cfg.block_q, psk // cfg.block_k
-    assert psk - cfg.kv_len < cfg.block_k and psq - cfg.q_len < cfg.block_q
+    bq, bk, bb = cfg.fwd_block_q, cfg.fwd_block_k, cfg.fwd_block_bh
+    assert psq % bq == 0 and psk % bk == 0 and bh_p % bb == 0
+    assert psk - cfg.kv_len < bk
+    num_k = psk // bk
     has_bias = bias is not None
     has_segs = qseg is not None
     has_dropout = cfg.dropout_rate > 0.0
-    bb = cfg.block_bh
+
+    def kv(j, kb):
+        # a block above the causal bound is not computed: its step keeps
+        # the block index of the last one that was, so nothing is copied
+        return jnp.minimum(kb, _fwd_last_kb(cfg, num_k, j))
+
+    in_specs = [
+        pl.BlockSpec((bb, bq, d_p), lambda i, j, kb: (i, j, 0),
+                     memory_space=pltpu.VMEM),
+        pl.BlockSpec((bb, bk, d_p), lambda i, j, kb: (i, kv(j, kb), 0),
+                     memory_space=pltpu.VMEM),
+        pl.BlockSpec((bb, bk, d_p), lambda i, j, kb: (i, kv(j, kb), 0),
+                     memory_space=pltpu.VMEM),
+    ]
     inputs = [q, k, v]
     if has_bias:
+        in_specs.append(pl.BlockSpec(
+            (bb if cfg.bias_batch == BIAS_PER_HEAD else 1, bq, bk),
+            lambda i, j, kb: (_bias_row(cfg, bb, i), j, kv(j, kb)),
+            memory_space=pltpu.VMEM))
         inputs.append(bias)
     if has_segs:
+        in_specs.append(pl.BlockSpec((bb, 1, bq),
+                                     lambda i, j, kb: (i, 0, j)))
+        in_specs.append(pl.BlockSpec((bb, 1, bk),
+                                     lambda i, j, kb: (i, 0, kv(j, kb))))
         inputs.extend([qseg, kseg])
     if has_dropout:
+        in_specs.append(pl.BlockSpec((1, 1), lambda i, j, kb: (0, 0),
+                                     memory_space=pltpu.SMEM))
         inputs.append(seed)
+    scratch_shapes = [
+        pltpu.VMEM((bb, d_p, bq), jnp.float32),    # acc, transposed
+        pltpu.VMEM((bb, 1, bq), jnp.float32),      # running max
+    ]
+    if cfg.sum_lane < 0:
+        scratch_shapes.append(pltpu.VMEM((bb, 1, bq), jnp.float32))
     out, lse = pl.pallas_call(
         functools.partial(
             _mid_fwd_kernel, cfg=cfg, num_k=num_k, has_bias=has_bias,
             has_segs=has_segs, has_dropout=has_dropout,
         ),
-        grid=(bh_p // bb, num_q, num_k),
-        in_specs=_in_specs(cfg, bb, d_p, has_bias, has_segs, has_dropout),
+        grid=(bh_p // bb, psq // bq, num_k),
+        in_specs=in_specs,
         out_specs=[
-            pl.BlockSpec((bb, cfg.block_q, d_p),
-                         lambda i, j, kb: (i, j, 0),
+            pl.BlockSpec((bb, bq, d_p), lambda i, j, kb: (i, j, 0),
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec((bb, 1, cfg.block_q), lambda i, j, kb: (i, 0, j)),
+            pl.BlockSpec((bb, 1, bq), lambda i, j, kb: (i, 0, j)),
         ],
         out_shape=[
             shape_struct((bh_p, psq, d_p), q.dtype, q, k, v),
             shape_struct((bh_p, 1, psq), jnp.float32, q, k, v),
         ],
-        scratch_shapes=[
-            pltpu.VMEM((bb, cfg.block_q, d_p), jnp.float32),
-            pltpu.VMEM((bb, cfg.block_q, _LANES), jnp.float32),
-            pltpu.VMEM((bb, cfg.block_q, _LANES), jnp.float32),
-        ],
-        compiler_params=_compiler_params(),
+        scratch_shapes=scratch_shapes,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=FMHA_MID_FWD_VMEM_LIMIT,
+        ),
         interpret=_interpret(),
         name=kernel_name("fmha_mid.fwd"),
     )(*inputs)
@@ -586,8 +698,7 @@ def _mid_bwd_pallas(q, k, v, bias, qseg, kseg, seed, out, lse, do, dlse,
     if cfg.with_lse:
         inputs.append(dlse.astype(jnp.float32)[:, None, :])
 
-    in_specs = _in_specs(cfg, bb, d_p, has_bias, has_segs, has_dropout,
-                         swap_grid=True)
+    in_specs = _bwd_in_specs(cfg, bb, d_p, has_bias, has_segs, has_dropout)
     in_specs.extend([
         pl.BlockSpec((bb, cfg.block_q, d_p), lambda i, kb, jq: (i, jq, 0),
                      memory_space=pltpu.VMEM),
@@ -857,6 +968,7 @@ def _fmha_mid_pallas(
     # score sublane/lane dims), then round up to the block sizes
     sq_l = sq + (-sq) % _LANES
     sk_l = sk + (-sk) % _LANES
+    explicit_blocks = block_q is not None or block_k is not None
     if block_q is None or block_k is None:
         dbq, dbk = default_mid_blocks(sq_l, sk_l)
         block_q = dbq if block_q is None else min(int(block_q), sq_l)
@@ -868,9 +980,20 @@ def _fmha_mid_pallas(
     pad_k = (-sk) % block_k
     pad_d = (-d) % _LANES
     d_p = d + pad_d
+    # where the head width is padded, V's first pad lane holds ones and
+    # the forward reads the row sum out of the PV product there; the
+    # backward never sees it (dO is zero in the pad lanes).  Dropout
+    # sums the undropped weights, so it keeps the VPU sum
+    sum_lane = d if pad_d and dropout_rate == 0.0 else -1
     if pad_d:
         padd = lambda x: jnp.pad(x, ((0, 0), (0, 0), (0, 0), (0, pad_d)))
-        q, k, v = padd(q), padd(k), padd(v)
+        q, k = padd(q), padd(k)
+        if sum_lane >= 0:
+            v = jnp.concatenate([
+                v, jnp.ones(v.shape[:-1] + (1,), v.dtype),
+                jnp.zeros(v.shape[:-1] + (pad_d - 1,), v.dtype)], axis=-1)
+        else:
+            v = padd(v)
 
     bh = b * h
     if block_bh is None:
@@ -940,14 +1063,23 @@ def _fmha_mid_pallas(
     if dropout_rate > 0.0:
         seed_arr = jnp.asarray(dropout_seed, jnp.uint32).reshape(1, 1)
 
+    # blocks the caller gave are both passes'; otherwise the forward
+    # sizes its own
+    fbq, fbk, fbb = default_mid_fwd_blocks(
+        sq + pad_q, sk + pad_k, d_p, q.dtype.itemsize, bias_batch, bb)
+    if explicit_blocks:
+        fbq, fbk = block_q, block_k
+    if explicit_blocks or block_bh is not None:
+        fbb = bb
     cfg = _MidConfig(
         sm_scale=scale, causal=causal, dropout_rate=float(dropout_rate),
         block_q=block_q, block_k=block_k, block_bh=bb,
         block_bh_bwd=_bwd_block_bh(bb, sq + pad_q, d_p),
+        fwd_block_q=fbq, fwd_block_k=fbk, fwd_block_bh=fbb,
         q_len=sq, kv_len=sk, heads=h, bias_batch=bias_batch,
         bias_grad=bool(bias_requires_grad),
         hi_precision=(q.dtype == jnp.float32),
-        with_lse=bool(return_lse),
+        with_lse=bool(return_lse), sum_lane=sum_lane,
     )
     res = _mid(qf, kf, vf, bias_flat, qseg, kseg, seed_arr, cfg)
     if return_lse:

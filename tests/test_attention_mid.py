@@ -19,11 +19,14 @@ import numpy as np
 import pytest
 
 from apex_tpu.ops import flash_attention, fmha_mid, mha_reference
+from apex_tpu.ops.attention import BIAS_PER_HEAD
 from apex_tpu.ops.attention_mid import (
     FMHA_MID_MAX_SEQ,
     _bwd_block_bh,
+    _xla_with_lse,
     default_mid_block_bh,
     default_mid_blocks,
+    default_mid_fwd_blocks,
     mid_seq_threshold,
 )
 from apex_tpu.ops.attention_short import FMHA_SHORT_MAX_SEQ
@@ -244,6 +247,72 @@ class TestMidParity:
             fmha_mid(q, q, q, implementation="short")
 
 
+class TestCellCallShapes:
+    """The benchmark cells' own calls of the mid band, scaled down in
+    batch only and in the cells' dtype (bf16): forward and gradients
+    against the XLA reference, with and without ``return_lse``."""
+
+    CELLS = {
+        # train-345m: bf16[256,1024,64->128], causal
+        "train-345m": dict(shape=(1, 2, 1024, 64), causal=True),
+        # train-1.3b-dp2tp2: bf16[32,2048,128], causal
+        "train-1.3b": dict(shape=(1, 1, 2048, 128), causal=True),
+        # gpt2 jit__prefill: one padded prompt of 960
+        "gpt2-prefill": dict(shape=(1, 2, 960, 64), causal=True),
+        # the latent chunk through mla_expanded: 192-wide keys, values
+        # zero from 128 on, one selection mask shared by the heads
+        "latent-chunk": dict(shape=(1, 1, 2048, 192), causal=False,
+                             mask=True),
+    }
+
+    @pytest.mark.parametrize("return_lse", [False, True])
+    @pytest.mark.parametrize("cell", list(CELLS))
+    def test_fwd_and_grads_match_reference(self, cell, return_lse):
+        spec = self.CELLS[cell]
+        q, k, v = (x.astype(jnp.bfloat16)
+                   for x in _qkv(jax.random.PRNGKey(29), spec["shape"]))
+        kw = dict(causal=spec["causal"])
+        if spec.get("mask"):
+            s = spec["shape"][2]
+            keep = jax.random.bernoulli(jax.random.PRNGKey(30), 0.5, (s, s))
+            keep = jnp.logical_or(keep, jnp.eye(s, dtype=bool))
+            kw["bias"] = jnp.where(keep, 0.0, -1e30)[None, None]
+            v = v.at[..., 128:].set(0)
+
+        def loss(impl):
+            def f(q, k, v):
+                if impl == "pallas":
+                    res = fmha_mid(q, k, v, implementation="pallas",
+                                   bias_requires_grad=False,
+                                   return_lse=return_lse, **kw)
+                elif return_lse:
+                    res = _xla_with_lse(q, k, v, kw["causal"], None,
+                                        kw.get("bias"), None, None, 0.0,
+                                        None)
+                else:
+                    res = mha_reference(q, k, v, **kw)
+                out, lse = res if return_lse else (res, None)
+                val = jnp.sum(out.astype(jnp.float32) ** 2)
+                if return_lse:
+                    val = val + jnp.sum(jnp.sin(lse))
+                return val, res
+            return f
+
+        (_, got), g_got = jax.value_and_grad(
+            loss("pallas"), argnums=(0, 1, 2), has_aux=True)(q, k, v)
+        (_, want), g_want = jax.value_and_grad(
+            loss("xla"), argnums=(0, 1, 2), has_aux=True)(q, k, v)
+        if return_lse:
+            np.testing.assert_allclose(got[1], want[1], atol=1e-2)
+            got, want = got[0], want[0]
+        np.testing.assert_allclose(
+            got.astype(jnp.float32), want.astype(jnp.float32), atol=3e-2)
+        for a, b in zip(g_got, g_want):
+            a, b = a.astype(jnp.float32), b.astype(jnp.float32)
+            assert float(jnp.max(jnp.abs(a - b))) <= 3e-2 * max(
+                1.0, float(jnp.max(jnp.abs(b))))
+
+
 def _impl_call(impl, q, k, v, **kw):
     if impl == "mid":
         return fmha_mid(q, k, v, implementation="pallas", **kw)
@@ -267,6 +336,35 @@ class TestBlockSizing:
         assert default_mid_block_bh(128, 128, 64) == 16   # unroll cap
         assert default_mid_block_bh(512, 512, 64) == 2
         assert default_mid_block_bh(256, 256, 3) == 3     # bh bound
+
+    def test_forward_blocks_widest_that_divide_and_fit(self):
+        # (sq_p, sk_p, d_p, operand bytes, bias_batch, bh unit)
+        fwd = default_mid_fwd_blocks
+        # the cells: train-345m, train-1.3b-dp2tp2, the latent chunk
+        # (256 lanes and a shared bias block halve the packing)
+        assert fwd(1024, 1024, 128, 2, 0, 8) == (512, 512, 8)
+        assert fwd(2048, 2048, 128, 2, 0, 8) == (512, 512, 8)
+        assert fwd(2048, 2048, 256, 2, 1, 8) == (512, 512, 4)
+        # ragged extents fall to the widest block that divides them
+        assert fwd(640, 640, 128, 2, 0, 16) == (128, 128, 16)
+        assert fwd(768, 1024, 128, 2, 0, 8) == (256, 512, 8)
+        # never wider than the extent
+        assert fwd(256, 128, 128, 2, 0, 16) == (256, 128, 16)
+        # a bias a head and float32 operands cost VMEM: fewer tiles
+        assert fwd(1024, 1024, 128, 2, BIAS_PER_HEAD, 8) == (512, 512, 2)
+        assert fwd(1024, 1024, 128, 4, 0, 8) == (512, 512, 4)
+        # the packing divides the unit bh is padded to
+        assert fwd(1024, 1024, 128, 2, 0, 3) == (512, 512, 3)
+        assert fwd(1024, 1024, 128, 4, 0, 6) == (512, 512, 3)
+
+    def test_forward_blocks_leave_the_backwards_alone(self):
+        # the backward still runs (256, 256, block_bh_bwd) at the
+        # cells' sizes, whatever the forward chose
+        assert default_mid_blocks(1024, 1024) == (256, 256)
+        assert _bwd_block_bh(default_mid_block_bh(256, 256, 256),
+                             1024, 128) == 4
+        assert _bwd_block_bh(default_mid_block_bh(256, 256, 32),
+                             2048, 128) == 2
 
     def test_bwd_block_bh_divides_and_fits(self):
         # dq scratch budget: bb * sq_p * d_p <= 512K elements

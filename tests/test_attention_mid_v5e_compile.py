@@ -1,0 +1,83 @@
+"""The fmha_mid kernels compile for a v5e at the benchmark cells' sizes.
+
+No chip is needed: the TPU compiler is installed with jax and compiles
+for a chip that is described, not attached — so what Mosaic refuses (a
+block that does not fit VMEM, a slice or transpose it cannot lay out)
+fails here and not on the chip.  Nothing runs, so this says nothing
+about results or times.
+"""
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from apex_tpu.ops import attention_mid
+from apex_tpu.ops import fmha_mid
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+# (b, h, sq, sk, d, dtype, causal, bias shape, extra keywords)
+CALLS = {
+    "train-345m": (16, 16, 1024, 1024, 64, jnp.bfloat16, True, None, {}),
+    "train-1.3b-dp2tp2": (4, 8, 2048, 2048, 128, jnp.bfloat16, True, None,
+                          {}),
+    "gpt2-prefill": (1, 16, 960, 960, 64, jnp.bfloat16, True, None, {}),
+    "latent-chunk": (1, 32, 2048, 2048, 192, jnp.bfloat16, False,
+                     (1, 1, 2048, 2048), {"bias_requires_grad": False}),
+    "ring-shard-lse": (2, 8, 1024, 1024, 128, jnp.bfloat16, True, None,
+                       {"return_lse": True}),
+    "float32-dropout": (2, 4, 640, 640, 64, jnp.float32, True, None,
+                        {"dropout_rate": 0.1, "dropout_seed": 3}),
+    "per-head-bias-grad": (2, 4, 1024, 1024, 64, jnp.bfloat16, False,
+                           (2, 4, 1024, 1024), {}),
+    "packed-varlen-segments": (2, 8, 1536, 1536, 64, jnp.bfloat16, True,
+                               None, {"segments": True}),
+    "cross-attention-ragged": (2, 8, 600, 1100, 80, jnp.bfloat16, False,
+                               None, {}),
+}
+
+
+@pytest.mark.parametrize("call", list(CALLS))
+def test_forward_and_backward_compile(one_chip, monkeypatch, call):
+    b, h, sq, sk, d, dtype, causal, bias_shape, kw = CALLS[call]
+    kw = dict(kw)
+    segments = kw.pop("segments", False)
+    # off the TPU the kernels would be built for the interpreter
+    monkeypatch.setattr(attention_mid, "_interpret", lambda: False)
+
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    args = [sds((b, h, sq, d), dtype), sds((b, h, sk, d), dtype),
+            sds((b, h, sk, d), dtype)]
+    if bias_shape is not None:
+        args.append(sds(bias_shape, jnp.float32))
+    if segments:
+        args.append(sds((b, sq), jnp.int32))
+
+    def loss(q, k, v, *rest):
+        segs = dict(q_segment_ids=rest[-1],
+                    kv_segment_ids=rest[-1]) if segments else {}
+        res = fmha_mid(q, k, v, causal=causal, implementation="pallas",
+                       bias=rest[0] if bias_shape else None, **segs, **kw)
+        out = res[0] if kw.get("return_lse") else res
+        val = jnp.sum(out.astype(jnp.float32))
+        if kw.get("return_lse"):
+            val = val + jnp.sum(res[1])
+        return val
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        *args).compile().as_text()
+    assert "tlm.kernel.fmha_mid.fwd" in text
+    assert "tlm.kernel.fmha_mid.bwd" in text

@@ -525,10 +525,14 @@ def validate_fmha_mid(smoke=False):
             # (block_q, block_k, block_bh) sweep: the shipped default
             # plus the plausible neighbours (the mid analog of the
             # flash block sweep / short block_bh sweep)
+            # (None, None, None) is the call dispatch makes: the kernel
+            # sizes the forward's and the backward's blocks itself; an
+            # explicit triple is used by both passes as given
             s_l = s + (-s) % 128
             dbq, dbk = default_mid_blocks(s_l, s_l)
             dbb = default_mid_block_bh(dbq, dbk, b * h)
-            cands = [(dbq, dbk, dbb), (dbq, dbk, 1)]
+            default = (None, None, None)
+            cands = [default, (dbq, dbk, dbb), (dbq, dbk, 1)]
             for bq, bk in [(128, 128), (256, 256), (256, 512),
                            (512, 256), (512, 512)]:
                 if bq > s_l or bk > s_l:
@@ -538,14 +542,15 @@ def validate_fmha_mid(smoke=False):
             best = None
             default_ms = None
             for bq, bk, bb in dict.fromkeys(cands):
-                key = f"{bq}x{bk}xbh{bb}"
+                key = ("default" if (bq, bk, bb) == default
+                       else f"{bq}x{bk}xbh{bb}")
                 try:
                     ms = _time(mid_fwd_t(bq, bk, bb), q, k, v)
                 except Exception as e:  # lowering failure = loud entry
                     sweep[key] = {"error": str(e)[:200]}
                     continue
                 sweep[key] = round(ms, 3)
-                if (bq, bk, bb) == (dbq, dbk, dbb):
+                if (bq, bk, bb) == default:
                     default_ms = ms
                 if best is None or ms < best[0]:
                     best = (ms, bq, bk, bb)
@@ -564,7 +569,7 @@ def validate_fmha_mid(smoke=False):
 
             # parity at the config dispatch actually ships (fall back
             # to the sweep winner only if the default failed to lower)
-            pq, pk, pb = (dbq, dbk, dbb) if default_ms is not None \
+            pq, pk, pb = default if default_ms is not None \
                 else (bq, bk, bb)
             out_m = jax.device_get(mid_fwd(pq, pk, pb)(q, k, v))
             out_x = jax.device_get(jax.jit(lambda q, k, v: flash_attention(
