@@ -722,10 +722,9 @@ class GPTModel:
         contiguous tp slice holds whole (q,k,v) triplets and the math
         is identical for every tp size (the reference relies on
         per-rank weight init for the same property,
-        apex/transformer/testing/standalone_gpt.py).  The ONE
-        projection split shared by training (:meth:`_layer`), prefill
-        (:meth:`prefill_forward`) and decode (:meth:`decode_step`), so
-        the cache can never hold a different K than training computed."""
+        apex/transformer/testing/standalone_gpt.py).  Called from
+        :meth:`_block` alone, so the cache can never hold a different K
+        than training computed."""
         c = self.config
         world = jax.lax.axis_size(self.axis_name)
         heads_local = c.num_attention_heads // world
@@ -740,10 +739,8 @@ class GPTModel:
         """The dense-MLP math on normed activations: SwiGLU
         (silu(gate(x)) * up(x) — both column-parallel on the same
         input, elementwise gate on the local shard) or fc1+gelu, then
-        the row-parallel fc2.  The ONE definition shared by training
-        (:meth:`_layer`) and decode (:meth:`decode_step`), for the same
-        reason as :meth:`_qkv_heads`: the serving path must not be able
-        to drift from the math the model trained with."""
+        the row-parallel fc2 (from :meth:`_block` alone, like
+        :meth:`_qkv_heads`)."""
         if self.fc_gate is not None:
             y = (jax.nn.silu(self._apply_linear(
                     self.fc_gate, lp["fc_gate"], y))
@@ -753,63 +750,28 @@ class GPTModel:
             y = jax.nn.gelu(y, approximate=True)
         return self._apply_linear(self.fc2, lp["fc2"], y)
 
-    def _layer(self, lp: Dict[str, Any], x: jnp.ndarray, key,
-               rope=None) -> jnp.ndarray:
-        """One transformer layer on the local shard. x: (b, s, h) replicated
-        over tp; lp: this layer's param shards; ``rope``: precomputed
-        (cos, sin) tables from :meth:`_rope_tables` (None for learned
-        positions)."""
+    def _block(self, lp: Dict[str, Any], x: jnp.ndarray, attend,
+               key=None):
+        """THE transformer layer on the local shard, written once:
+        norm -> :meth:`_qkv_heads` -> ``attend`` -> ``attn_proj`` ->
+        residual -> norm -> MLP (dense or expert-parallel MoE) ->
+        residual.  ``x``: (b, s, h) replicated over tp; ``lp``: this
+        layer's param shards.  ``attend(q, k, v) -> (attention output
+        (b, heads_local, s, d), extra)`` is the ONE thing the entry
+        points differ in — how the layer reads and writes its sequence
+        state: the dense one of :meth:`_layer` (training, monolithic
+        prefill) or the paged one of :meth:`_paged_rows` (every serving
+        step).  ``key`` (training only) turns the hidden dropouts on.
+        Returns ``(x_out, MoE aux loss — 0.0 for dense layers, extra)``."""
         c = self.config
-        world = jax.lax.axis_size(self.axis_name)
-        heads_local = c.num_attention_heads // world
-        b, s, h = x.shape
+        b, s, _ = x.shape
 
         # -- attention block ------------------------------------------
         residual = x
         y = self._norm(lp["ln1"], x).astype(c.compute_dtype)
         q, k, v = self._qkv_heads(lp, y)  # each (b, heads_local, s, d)
-        if rope is not None:
-            from apex_tpu.ops.rope import apply_rope_tables
-
-            q = apply_rope_tables(q, *rope)
-            k = apply_rope_tables(k, *rope)
-        if c.attention_dropout > 0.0 and key is not None:
-            # Megatron semantics: dropout on the softmax *probabilities*
-            # (reference: standalone_gpt.py attention_probs dropout), kept
-            # INSIDE the flash kernel via its counter-based hash (the role
-            # philox.h plays in the reference's fused MHA).  The seed is
-            # drawn after folding in mesh axes, so the attention / hidden
-            # dropout streams can never collide across ranks.
-            akey = model_parallel_key(
-                data_parallel_key(jax.random.fold_in(key, 0)), self.axis_name
-            )
-            seed = jax.random.bits(akey, dtype=jnp.uint32)
-            attn = flash_attention(
-                q, k, v, causal=True,
-                dropout_rate=c.attention_dropout, dropout_seed=seed,
-                implementation=c.attention_impl,
-            )
-        elif c.context_parallel:
-            from apex_tpu.ops.ring_attention import ring_attention
-
-            # config attention_impl threads into the per-shard inner
-            # attention.  "xla" maps to None: the inline ring walk IS
-            # the XLA implementation here, and unlike the lse-merge
-            # formulation it keeps the documented (s_local, block_k)
-            # score bound (the merge's "xla" mode materializes
-            # (s_local, s_local) per ring step — an A/B reference, not
-            # a production path)
-            attn = ring_attention(
-                q, k, v, causal=True,
-                attention_impl=(
-                    None if c.attention_impl == "xla" else c.attention_impl
-                ),
-            )
-        else:
-            attn = flash_attention(
-                q, k, v, causal=True, implementation=c.attention_impl
-            )
-        attn = jnp.moveaxis(attn, 1, 2).reshape(b, s, heads_local * c.head_dim)
+        attn, extra = attend(q, k, v)
+        attn = jnp.moveaxis(attn, 1, 2).reshape(b, s, -1)
         out = self._apply_linear(
             self.attn_proj, lp["attn_proj"], attn)  # psum inside
         if c.hidden_dropout > 0.0 and key is not None:
@@ -833,14 +795,73 @@ class GPTModel:
             hkey = data_parallel_key(jax.random.fold_in(key, 2))
             keep = jax.random.bernoulli(hkey, 1.0 - c.hidden_dropout, y.shape)
             y = jnp.where(keep, y / (1.0 - c.hidden_dropout), 0.0)
-        return residual + y.astype(residual.dtype), aux
+        return residual + y.astype(residual.dtype), aux, extra
+
+    def _layer(self, lp: Dict[str, Any], x: jnp.ndarray, key,
+               rope=None):
+        """One layer over a whole in-hand sequence (s_q == s_k):
+        :meth:`_block` under the DENSE ``attend`` — rotate q and k by
+        ``rope`` (precomputed (cos, sin) tables from
+        :meth:`_rope_tables`; None for learned positions), then causal
+        attention through the training ladder.  Returns ``(x_out, aux,
+        (k, v))``, the K/V attention-ready (:meth:`prefill_forward`
+        writes them into the cache)."""
+        c = self.config
+
+        def attend(q, k, v):
+            if rope is not None:
+                from apex_tpu.ops.rope import apply_rope_tables
+
+                q = apply_rope_tables(q, *rope)
+                k = apply_rope_tables(k, *rope)
+            if c.attention_dropout > 0.0 and key is not None:
+                # Megatron semantics: dropout on the softmax
+                # *probabilities* (reference: standalone_gpt.py
+                # attention_probs dropout), kept INSIDE the flash kernel
+                # via its counter-based hash (the role philox.h plays in
+                # the reference's fused MHA).  The seed is drawn after
+                # folding in mesh axes, so the attention / hidden dropout
+                # streams can never collide across ranks.
+                akey = model_parallel_key(
+                    data_parallel_key(jax.random.fold_in(key, 0)),
+                    self.axis_name)
+                seed = jax.random.bits(akey, dtype=jnp.uint32)
+                attn = flash_attention(
+                    q, k, v, causal=True,
+                    dropout_rate=c.attention_dropout, dropout_seed=seed,
+                    implementation=c.attention_impl,
+                )
+            elif c.context_parallel:
+                from apex_tpu.ops.ring_attention import ring_attention
+
+                # config attention_impl threads into the per-shard inner
+                # attention.  "xla" maps to None: the inline ring walk IS
+                # the XLA implementation here, and unlike the lse-merge
+                # formulation it keeps the documented (s_local, block_k)
+                # score bound (the merge's "xla" mode materializes
+                # (s_local, s_local) per ring step — an A/B reference,
+                # not a production path)
+                attn = ring_attention(
+                    q, k, v, causal=True,
+                    attention_impl=(
+                        None if c.attention_impl == "xla"
+                        else c.attention_impl
+                    ),
+                )
+            else:
+                attn = flash_attention(
+                    q, k, v, causal=True, implementation=c.attention_impl
+                )
+            return attn, (k, v)
+
+        return self._block(lp, x, attend, key)
 
     def _embed(self, params: Dict[str, Any], tokens: jnp.ndarray):
         """Token embedding + (learned-table) position add, in compute
         dtype — the one entry shared by the sequential and both pipeline
         paths so the position_embedding mode can't diverge between them.
         rope models add nothing here; their rotation happens on (q, k)
-        inside every layer (:meth:`_layer`)."""
+        inside every layer (:meth:`_layer`, :meth:`_paged_rows`)."""
         c = self.config
         x = self.embedding.apply(params["embedding"], tokens)
         if c.position_embedding == "learned":
@@ -865,12 +886,14 @@ class GPTModel:
 
     def _rope_tables(self, s: int):
         """(cos, sin) rotation tables for the local chunk's GLOBAL
-        positions, computed ONCE per forward — the layer scan closes
-        over them (a scan body cannot hoist the iota+trig, so computing
-        inside :meth:`_layer` would redo it num_layers times and again
-        in the remat backward)."""
+        positions (None for learned positions), computed ONCE per
+        forward — the layer scan closes over them (a scan body cannot
+        hoist the iota+trig, so computing inside :meth:`_layer` would
+        redo it num_layers times and again in the remat backward)."""
         from apex_tpu.ops.rope import rope_cos_sin
 
+        if self.config.position_embedding != "rope":
+            return None
         positions = self._chunk_offset(s) + jnp.arange(s, dtype=jnp.int32)
         return rope_cos_sin(positions, self.config.head_dim,
                             self.config.rope_base)
@@ -899,13 +922,12 @@ class GPTModel:
         x = self._embed(params, tokens)
 
         use_rng = rng is not None
-        rope = (self._rope_tables(s)
-                if c.position_embedding == "rope" else None)
+        rope = self._rope_tables(s)
 
         def body(carry, scanned):
             lp, key = scanned
-            out, aux = self._layer(lp, carry, key if use_rng else None,
-                                   rope=rope)
+            out, aux, _kv = self._layer(
+                lp, carry, key if use_rng else None, rope=rope)
             return out, aux
 
         if c.remat:
@@ -989,34 +1011,148 @@ class GPTModel:
         RoPE-rotated where the config says so, so a cached key is
         rotated exactly once and the decode kernel rotates only q.
 
-        The layer output comes from :meth:`_layer` itself (key=None —
-        the inference path) and the K/V are recomputed from the same
-        ``lp``/``x`` through :meth:`_qkv_heads`; XLA CSEs the duplicate
-        norm+projection, and sharing the primitives is what makes the
-        paged generation bit-comparable to the full-recompute
-        reference."""
+        Every layer is :meth:`_layer` itself (key=None — the inference
+        path), which hands back the K/V it attended over; sharing the
+        block is what makes the paged generation bit-comparable to the
+        full-recompute reference."""
         c = self.config
         if c.context_parallel:
             raise NotImplementedError(
                 "prefill_forward is the serving path — context-parallel "
                 "decode is not supported")
         x = self._embed(params, tokens)
-        rope = (self._rope_tables(tokens.shape[1])
-                if c.position_embedding == "rope" else None)
+        rope = self._rope_tables(tokens.shape[1])
 
         def body(x, lp):
-            out, _aux = self._layer(lp, x, None, rope=rope)
-            y = self._norm(lp["ln1"], x).astype(c.compute_dtype)
-            _, k, v = self._qkv_heads(lp, y)
-            if rope is not None:
-                from apex_tpu.ops.rope import apply_rope_tables
-
-                k = apply_rope_tables(k, *rope)
-            return out, (k, v)
+            out, _aux, kv = self._layer(lp, x, None, rope=rope)
+            return out, kv
 
         x, (ks, vs) = jax.lax.scan(body, x, params["layers"])
         x = self._norm(params["final_ln"], x.astype(jnp.float32))
         return x.astype(c.compute_dtype), ks, vs
+
+    def _paged_rows(
+        self,
+        params: Dict[str, Any],
+        tokens: jnp.ndarray,
+        logical: jnp.ndarray,
+        positions: jnp.ndarray,
+        writev: jnp.ndarray,
+        page_table: jnp.ndarray,
+        attend_len: jnp.ndarray,
+        pools: Dict[str, jnp.ndarray],
+        *,
+        ancestor=None,
+        logits_row=None,
+        quantized: bool = False,
+        kv_block: int = 128,
+        weight_dtype: Optional[str] = None,
+    ):
+        """THE paged walk — call inside shard_map.  ``R`` token rows for
+        each of ``B`` sequences through every layer against the paged
+        KV cache; :meth:`decode_step` (``B = S, R = 1``),
+        :meth:`prefill_chunk` (``B = 1, R = C``) and :meth:`verify_step`
+        (``B = S, R = k + 1``) are shape adapters over it.
+
+        ``tokens (B, R)`` embed at their LOGICAL positions ``logical``
+        (learned table or RoPE rows); their K/V land at PHYSICAL
+        ``positions`` of ``page_table (B, pages_per_seq)`` where
+        ``writev`` holds, on the null page elsewhere — each ``(B, R)``,
+        or ``(B,)`` at one row a sequence.  The two positions differ
+        only under a candidate tree, where siblings share a logical one.
+        Each layer is :meth:`_block` under the PAGED ``attend``: rotate
+        K at the rows' positions, write the rows into the layer's pool
+        slice FIRST (a row attends to itself and to the rows written
+        with it), then
+        :func:`~apex_tpu.ops.attention_decode.fmha_decode` over the
+        first ``attend_len (B,)`` cache positions, the q-side rotation
+        fused into the kernel — per-row causal with row ``i`` at
+        ``attend_len - R + i``, or under a tree's static ``ancestor``
+        matrix.  Shapes are fixed by ``(B, R)`` and the cache config
+        alone: no admission, retirement, chunk offset or acceptance
+        pattern recompiles a step built on this.
+
+        Returns ``(logits, new_pools, kv)``: vocab-parallel logits
+        ``(B, R, vocab/tp)`` — of row ``logits_row`` (a scalar index)
+        alone, ``(B, 1, vocab/tp)``, when it is given — and, under a
+        tree (its caller moves the accepted path's rows), the per-layer
+        attention-ready K/V rows ``(L, B, h_local, R, d)``; None
+        otherwise."""
+        from apex_tpu.ops.attention_decode import fmha_decode
+        from apex_tpu.serving.kv_cache import write_targets, write_tokens
+
+        c = self.config
+        self._check_weight_dtype(params, weight_dtype)
+        B, R = tokens.shape
+        page_size = pools["k"].shape[3]
+
+        def per_row(table, pos):
+            # table rows at ``pos``, (B, R, width): where the caller
+            # gave one position a sequence, the row axis goes in
+            rows = jnp.take(table, pos, axis=0)
+            return jax.lax.expand_dims(rows, range(rows.ndim - 1, 2))
+
+        x = self.embedding.apply(params["embedding"], tokens)
+        if c.position_embedding == "learned":
+            pos = jnp.clip(logical, 0, c.max_position_embeddings - 1)
+            x = x + per_row(params["pos_embedding"], pos).astype(x.dtype)
+        x = x.astype(c.compute_dtype)
+
+        rope_cs = None
+        if c.position_embedding == "rope":
+            from apex_tpu.ops.rope import apply_rope_tables, rope_table
+
+            # (B, R, d/2): the rows' rotations, gathered from the cached
+            # full table (ops/rope.py) instead of re-running the trig
+            # ladder on dynamic positions every step — the table covers
+            # the cache's whole logical extent and its rows are
+            # bit-identical to direct computation (pinned in
+            # tests/test_rope.py), so prefill, decode and verify
+            # rotations cannot drift.  Closed over by the layer scan
+            # (same hoisting argument as _rope_tables).
+            max_len = page_table.shape[1] * page_size
+            cos_t, sin_t = rope_table(max_len, c.head_dim,
+                                      base=c.rope_base)
+            pos = jnp.clip(logical, 0, max_len - 1)
+            rope_cs = (per_row(cos_t, pos), per_row(sin_t, pos))
+
+        wp, wo = write_targets(page_table, positions, writev, page_size)
+        wp, wo = wp.reshape(-1), wo.reshape(-1)
+        decode_impl = "xla" if c.attention_impl == "xla" else None
+
+        def token_rows(t):
+            # (B, hl, R, d) -> (B*R, hl, d), row-major to match wp/wo
+            return jnp.moveaxis(t, 1, 2).reshape(B * R, -1, t.shape[-1])
+
+        def body(x, scanned):
+            lp, pool_l = scanned
+
+            def attend(q, k, v):
+                if rope_cs is not None:
+                    k = apply_rope_tables(
+                        k, rope_cs[0][:, None], rope_cs[1][:, None])
+                new_pool = write_tokens(
+                    pool_l, token_rows(k), token_rows(v), wp, wo,
+                    quantized=quantized, kv_block=kv_block)
+                attn = fmha_decode(
+                    q, new_pool["k"], new_pool["v"], page_table,
+                    attend_len, causal=True,
+                    k_scales=new_pool.get("k_scales"),
+                    v_scales=new_pool.get("v_scales"), kv_block=kv_block,
+                    rope=rope_cs, implementation=decode_impl,
+                    ancestor=ancestor)
+                return attn, (new_pool,
+                              (k, v) if ancestor is not None else None)
+
+            x, _aux, extra = self._block(lp, x, attend)
+            return x, extra
+
+        x, (new_pools, kv) = jax.lax.scan(
+            body, x, (params["layers"], pools))
+        x = self._norm(params["final_ln"], x.astype(jnp.float32))
+        if logits_row is not None:
+            x = jnp.take(x, logits_row, axis=1)[:, None]
+        return self.logits(params, x.astype(c.compute_dtype)), new_pools, kv
 
     def prefill_chunk(
         self,
@@ -1035,111 +1171,42 @@ class GPTModel:
         """ONE fixed-size prompt-ingestion chunk for a single serving
         slot — the Sarathi-style alternative to :meth:`prefill_forward`
         that lets the scheduler interleave prompt work with decode
-        steps.  ``tokens (1, C)`` are prompt ids at global positions
-        ``start .. start + C`` (rows at or past ``prompt_len`` are
-        padding); each layer writes the chunk's K/V into the slot's
-        pages (positions below ``write_from`` — a prefix-cache hit's
-        already-shared region — are masked to the null page, never
-        recomputed onto shared pages) and attends over the cache
-        INCLUDING its own just-written pages through
-        :func:`~apex_tpu.ops.attention_decode.fmha_decode`'s small-s_q
-        path, per-row causal at position ``start + i``.  Shapes are
-        fixed by ``C``/``pages_per_seq`` alone — any chunk count, start
-        offset or hit pattern reuses ONE compilation.
+        steps: :meth:`_paged_rows` at ``B = 1, R = C``.  ``tokens (1,
+        C)`` are prompt ids at global positions ``start .. start + C``
+        (rows at or past ``prompt_len`` are padding); each layer writes
+        the chunk's K/V into the slot's pages (positions below
+        ``write_from`` — a prefix-cache hit's already-shared region —
+        are masked to the null page, never recomputed onto shared
+        pages) and attends over the cache INCLUDING its own
+        just-written pages, per-row causal at position ``start + i``.
+        Chunk boundaries are absolute and attention reads K/V from the
+        POOLS, so a hit admission that skips fully-matched chunks gives
+        BIT-identical logits to a cold one (docs/serving.md).
 
         Returns ``(logits (vocab/tp,), new_pools)`` — the logits of the
         LAST VALID prompt row (position ``prompt_len - 1``, clipped into
         this chunk); the caller samples the first generated token from
-        the chunk that contains it and ignores the rest.
-
-        Numerics: chunk boundaries are absolute (chunk ``k`` always
-        covers ``[k*C, (k+1)*C)``) and attention reads K/V from the
-        POOLS, so a hit admission that skips fully-matched chunks
-        produces BIT-identical logits to a cold admission of the same
-        prompt — the skipped region's pages hold the same bits either
-        way (``_dryrun_chunked_prefill`` gates this)."""
-        from apex_tpu.ops.attention_decode import fmha_decode
-        from apex_tpu.serving.kv_cache import write_targets, write_tokens
-
-        c = self.config
+        the chunk that contains it and ignores the rest."""
         if self.moe is not None:
             self.moe.decode()    # raises: expert-parallel decode note
-        self._check_weight_dtype(params, weight_dtype)
         C = tokens.shape[-1]
-        tokens = tokens.reshape(1, C)
-        page_size = pools["k"].shape[3]
         start = jnp.asarray(start, jnp.int32)
         prompt_len = jnp.asarray(prompt_len, jnp.int32)
         write_from = jnp.asarray(write_from, jnp.int32)
-        positions = start + jnp.arange(C, dtype=jnp.int32)
-        valid = positions < prompt_len
-        writev = valid & (positions >= write_from)
-
-        x = self.embedding.apply(params["embedding"], tokens)
-        if c.position_embedding == "learned":
-            pos = jnp.clip(positions, 0, c.max_position_embeddings - 1)
-            x = x + jnp.take(
-                params["pos_embedding"], pos, axis=0
-            )[None].astype(x.dtype)
-        x = x.astype(c.compute_dtype)
-
-        rope_cs = None
-        if c.position_embedding == "rope":
-            from apex_tpu.ops.rope import rope_table
-
-            # same cached-table gather as decode_step: chunk rows come
-            # from the bit-identical full table, so prefill and decode
-            # rotations cannot drift
-            max_len = page_row.shape[0] * page_size
-            cos_t, sin_t = rope_table(max_len, c.head_dim,
-                                      base=c.rope_base)
-            pos = jnp.clip(positions, 0, max_len - 1)
-            rope_cs = (jnp.take(cos_t, pos, axis=0)[None],
-                       jnp.take(sin_t, pos, axis=0)[None])  # (1, C, d/2)
-
+        positions = (start + jnp.arange(C, dtype=jnp.int32))[None]
+        writev = (positions < prompt_len) & (positions >= write_from)
         # the chunk attends over start + C cache positions: padding
         # rows past prompt_len see (and produce) garbage, but a valid
         # row's causal mask stops at its own position, which its own
-        # just-written page covers — write-before-attend per layer
+        # just-written page covers
         attend = jnp.reshape(start + C, (1,)).astype(jnp.int32)
-        wp, wo = write_targets(page_row, positions, writev, page_size)
-        decode_impl = "xla" if c.attention_impl == "xla" else None
-
-        def body(x, scanned):
-            lp, pool_l = scanned
-            residual = x
-            y = self._norm(lp["ln1"], x).astype(c.compute_dtype)
-            q, k, v = self._qkv_heads(lp, y)      # (1, hl, C, d)
-            if rope_cs is not None:
-                from apex_tpu.ops.rope import apply_rope_tables
-
-                k = apply_rope_tables(
-                    k, rope_cs[0][:, None], rope_cs[1][:, None])
-            pool_l = write_tokens(
-                pool_l, jnp.moveaxis(k[0], 1, 0),
-                jnp.moveaxis(v[0], 1, 0), wp, wo,
-                quantized=quantized, kv_block=kv_block)
-            attn = fmha_decode(
-                q, pool_l["k"], pool_l["v"], page_row[None], attend,
-                causal=True, k_scales=pool_l.get("k_scales"),
-                v_scales=pool_l.get("v_scales"), kv_block=kv_block,
-                rope=rope_cs, implementation=decode_impl)
-            attn = jnp.moveaxis(attn, 1, 2).reshape(1, C, -1)
-            out = self._apply_linear(self.attn_proj, lp["attn_proj"],
-                                     attn)
-            x = residual + out.astype(residual.dtype)
-            residual = x
-            y = self._norm(lp["ln2"], x).astype(c.compute_dtype)
-            y = self._dense_mlp(lp, y)
-            return residual + y.astype(residual.dtype), pool_l
-
-        x, new_pools = jax.lax.scan(body, x, (params["layers"], pools))
-        x = self._norm(params["final_ln"], x.astype(jnp.float32))
-        last_row = jnp.clip(prompt_len - 1 - start, 0, C - 1)
-        last = jnp.take(x[0], last_row, axis=0)          # (h,)
-        logits = self.logits(
-            params, last[None, None].astype(c.compute_dtype))[0, 0]
-        return logits, new_pools
+        logits, new_pools, _ = self._paged_rows(
+            params, tokens.reshape(1, C), positions, positions, writev,
+            page_row[None], attend, pools,
+            logits_row=jnp.clip(prompt_len - 1 - start, 0, C - 1),
+            quantized=quantized, kv_block=kv_block,
+            weight_dtype=weight_dtype)
+        return logits[0, 0], new_pools
 
     def decode_step(
         self,
@@ -1159,84 +1226,20 @@ class GPTModel:
         (each sitting at 0-based ``positions[s]``), ``active (S,)``
         masks live slots (idle slots compute garbage and write to the
         null page).  Every layer writes its new K/V into its pool slice
-        (write-before-attend: the token attends to itself) and runs
-        :func:`~apex_tpu.ops.attention_decode.fmha_decode` against the
-        paged cache, with the q-side RoPE rotation fused into the
-        kernel.  Returns ``(logits (S, vocab/tp), new_pools)`` — the
+        (write-before-attend: the token attends to itself) and attends
+        over the paged cache: :meth:`_paged_rows` at ``B = S, R = 1``.
+        Returns ``(logits (S, vocab/tp), new_pools)`` — the
         shapes never change, so the serving driver's admissions and
         retirements cannot recompile this."""
-        from apex_tpu.ops.attention_decode import fmha_decode
-        from apex_tpu.serving.kv_cache import write_targets, write_tokens
-
-        c = self.config
         if self.moe is not None:
             self.moe.decode()    # raises: expert-parallel decode note
-        self._check_weight_dtype(params, weight_dtype)
-        S = tokens.shape[0]
-        page_size = pools["k"].shape[3]
         positions = positions.astype(jnp.int32)
-
-        x = self.embedding.apply(params["embedding"], tokens[:, None])
-        if c.position_embedding == "learned":
-            pos = jnp.clip(positions, 0, c.max_position_embeddings - 1)
-            x = x + jnp.take(
-                params["pos_embedding"], pos, axis=0
-            )[:, None, :].astype(x.dtype)
-        x = x.astype(c.compute_dtype)
-
-        rope_cs = None
-        if c.position_embedding == "rope":
-            from apex_tpu.ops.rope import rope_table
-
-            # (S, 1, d/2): this step's per-slot rotation rows, gathered
-            # from the cached full table (ops/rope.py) instead of
-            # re-running the trig ladder on dynamic positions every
-            # step — the table covers the cache's whole logical extent
-            # and its rows are bit-identical to direct computation
-            # (pinned in tests/test_rope.py), so prefill and decode
-            # rotations cannot drift.  Closed over by the layer scan
-            # (same hoisting argument as _rope_tables).
-            cos_t, sin_t = rope_table(
-                page_table.shape[1] * page_size, c.head_dim,
-                base=c.rope_base)
-            rope_cs = (jnp.take(cos_t, positions, axis=0)[:, None],
-                       jnp.take(sin_t, positions, axis=0)[:, None])
-
         attend = jnp.where(active, positions + 1, 0).astype(jnp.int32)
-        wp, wo = write_targets(page_table, positions, active, page_size)
-        decode_impl = "xla" if c.attention_impl == "xla" else None
-
-        def body(x, scanned):
-            lp, pool_l = scanned
-            residual = x
-            y = self._norm(lp["ln1"], x).astype(c.compute_dtype)
-            q, k, v = self._qkv_heads(lp, y)      # (S, hl, 1, d)
-            if rope_cs is not None:
-                from apex_tpu.ops.rope import apply_rope_tables
-
-                k = apply_rope_tables(
-                    k, rope_cs[0][:, None], rope_cs[1][:, None])
-            pool_l = write_tokens(
-                pool_l, k[:, :, 0], v[:, :, 0], wp, wo,
-                quantized=quantized, kv_block=kv_block)
-            attn = fmha_decode(
-                q, pool_l["k"], pool_l["v"], page_table, attend,
-                causal=True, k_scales=pool_l.get("k_scales"),
-                v_scales=pool_l.get("v_scales"), kv_block=kv_block,
-                rope=rope_cs, implementation=decode_impl)
-            attn = jnp.moveaxis(attn, 1, 2).reshape(S, 1, -1)
-            out = self._apply_linear(self.attn_proj, lp["attn_proj"],
-                                     attn)
-            x = residual + out.astype(residual.dtype)
-            residual = x
-            y = self._norm(lp["ln2"], x).astype(c.compute_dtype)
-            y = self._dense_mlp(lp, y)
-            return residual + y.astype(residual.dtype), pool_l
-
-        x, new_pools = jax.lax.scan(body, x, (params["layers"], pools))
-        x = self._norm(params["final_ln"], x.astype(jnp.float32))
-        logits = self.logits(params, x.astype(c.compute_dtype))[:, 0]
-        return logits, new_pools
+        logits, new_pools, _ = self._paged_rows(
+            params, tokens[:, None], positions, positions, active,
+            page_table, attend, pools, quantized=quantized,
+            kv_block=kv_block, weight_dtype=weight_dtype)
+        return logits[:, 0], new_pools
 
     def verify_step(
         self,
@@ -1255,59 +1258,41 @@ class GPTModel:
     ):
         """ONE speculative verify step: :meth:`decode_step` widened to
         ``R = k + 1`` token rows per slot, ONE weight stream for all of
-        them.  ``tokens (S, R)`` is each slot's current token followed
-        by its k draft tokens, sitting at absolute positions
-        ``lengths[s] .. lengths[s] + R - 1``; ``valid (S, R)`` masks
-        the real rows (row 0 plus the slot's actual draft length —
-        shapes stay fixed at R for every acceptance pattern, padding
-        rows write to the null page).  Each layer writes the rows' K/V
-        into the slot's pages first (the :meth:`prefill_chunk`
-        write-before-attend pattern) and attends through
-        :func:`~apex_tpu.ops.attention_decode.fmha_decode`'s small-s_q
-        path, per-row causal at ``lengths + i`` — row i sees the
-        committed cache plus draft rows 0..i, exactly the
-        autoregressive prefix.  Returns ``(logits (S, R, vocab/tp),
-        new_pools)``: row j's logits predict the token AFTER j
-        committed drafts, so the caller can accept a draft prefix and
-        take its correction/bonus token from the same pass.
+        them (:meth:`_paged_rows` at ``B = S, R = k + 1``).  ``tokens
+        (S, R)`` is each slot's current token followed by its k draft
+        tokens, sitting at absolute positions ``lengths[s] ..
+        lengths[s] + R - 1``; ``valid (S, R)`` masks the real rows (row
+        0 plus the slot's actual draft length — shapes stay fixed at R
+        for every acceptance pattern, padding rows write to the null
+        page).  Row i sees the committed cache plus draft rows 0..i,
+        exactly the autoregressive prefix.  Returns ``(logits (S, R,
+        vocab/tp), new_pools)``: row j's logits predict the token AFTER
+        j committed drafts, so the caller can accept a draft prefix and
+        take its correction/bonus token from the same pass.  Rejection
+        needs no cleanup: the caller advances ``lengths`` by the
+        accepted count, the kernel never attends past a slot's length,
+        and the next step's write range covers the stale rows.
 
         ``tree`` (a static ``parents`` tuple of length R,
-        ``apex_tpu.serving.speculate``) switches the R rows from one
-        chain to a candidate TREE verified in the same single weight
-        stream: row r embeds at its LOGICAL position ``lengths +
-        depth(r)`` (RoPE / learned-pos — siblings share a position)
-        while its K/V lands at the collision-free PHYSICAL slot
-        ``lengths + r``, and attention runs under the tree's static
-        ancestor matrix (``fmha_decode(ancestor=...)``) so each row
-        sees the committed cache plus exactly its root-to-node path.
-        Returns ``(logits, new_pools, (ks, vs))`` — the per-layer
-        post-RoPE K/V rows ``(L, S, h_local, R, d)`` stashed from the
-        scan, so the caller can rewrite the ACCEPTED path's rows to
-        their depth positions (the pass-2 commit) from the original
-        full-precision values (re-quantizing a dequantized page would
-        not be bit-stable).
-
-        Rejection needs no cleanup here: the caller simply advances
-        ``lengths`` by the accepted count, the kernel never attends
-        past a slot's length, and the next step's write range covers
-        the stale rows.  Draft rows that would land past the slot's
-        logical page extent are masked to the null page (a clamped
-        gather would otherwise wrap them into the LAST real page, over
-        committed data) — the serving driver additionally caps draft
-        length under the slot's remaining budget so live rows never
-        overrun."""
-        from apex_tpu.ops.attention_decode import fmha_decode
-        from apex_tpu.serving.kv_cache import write_targets, write_tokens
-
-        c = self.config
+        ``apex_tpu.serving.speculate``) makes the R rows a candidate
+        TREE: row r embeds at its LOGICAL position ``lengths +
+        depth(r)`` while its K/V lands at the collision-free PHYSICAL
+        slot ``lengths + r``, and each row sees the committed cache
+        plus exactly its root-to-node path.  Returns ``(logits,
+        new_pools, (ks, vs))`` — the per-layer post-RoPE K/V rows ``(L,
+        S, h_local, R, d)``, so the caller can rewrite the ACCEPTED
+        path's rows to their depth positions (the pass-2 commit) from
+        the original full-precision values (re-quantizing a dequantized
+        page would not be bit-stable)."""
         if self.moe is not None:
             self.moe.decode()    # raises: expert-parallel decode note
-        self._check_weight_dtype(params, weight_dtype)
-        S, R = tokens.shape
-        page_size = pools["k"].shape[3]
+        R = tokens.shape[1]
         lengths = lengths.astype(jnp.int32)
         positions = lengths[:, None] + jnp.arange(R, dtype=jnp.int32)[None]
-        max_len = page_table.shape[1] * page_size
+        # rows past the slot's logical page extent go to the null page:
+        # a clamped gather would wrap them into the LAST real page, over
+        # committed data (the driver also caps drafts under the budget)
+        max_len = page_table.shape[1] * pools["k"].shape[3]
         writev = valid & active[:, None] & (positions < max_len)
 
         ancestor = None
@@ -1330,78 +1315,19 @@ class GPTModel:
             # write target
             logical = lengths[:, None] + depths[None]
 
-        x = self.embedding.apply(params["embedding"], tokens)
-        if c.position_embedding == "learned":
-            pos = jnp.clip(logical, 0, c.max_position_embeddings - 1)
-            x = x + jnp.take(
-                params["pos_embedding"], pos, axis=0).astype(x.dtype)
-        x = x.astype(c.compute_dtype)
-
-        rope_cs = None
-        if c.position_embedding == "rope":
-            from apex_tpu.ops.rope import rope_table
-
-            # (S, R, d/2): per-row rotation gathered from the same
-            # cached full table as decode_step/prefill_chunk, so the
-            # verify rows rotate bit-identically to the one-token path
-            cos_t, sin_t = rope_table(max_len, c.head_dim,
-                                      base=c.rope_base)
-            pos = jnp.clip(logical, 0, max_len - 1)
-            rope_cs = (jnp.take(cos_t, pos, axis=0),
-                       jnp.take(sin_t, pos, axis=0))
-
         # the kernel's per-row causal mask sits at lengths - R + i
         # relative to attend = lengths + R, i.e. row i attends through
         # position lengths + i — write-before-attend covers it (the
         # ancestor mask replaces the in-window triangle with the
         # tree's visibility, over the same window)
         attend = jnp.where(active, lengths + R, 0).astype(jnp.int32)
-        wp, wo = write_targets(page_table, positions, writev, page_size)
-        decode_impl = "xla" if c.attention_impl == "xla" else None
-
-        def body(x, scanned):
-            lp, pool_l = scanned
-            residual = x
-            y = self._norm(lp["ln1"], x).astype(c.compute_dtype)
-            q, k, v = self._qkv_heads(lp, y)      # (S, hl, R, d)
-            if rope_cs is not None:
-                from apex_tpu.ops.rope import apply_rope_tables
-
-                k = apply_rope_tables(
-                    k, rope_cs[0][:, None], rope_cs[1][:, None])
-            # (S, hl, R, d) -> (S*R, hl, d) token rows, row-major to
-            # match wp/wo.reshape(-1)
-            pool_l = write_tokens(
-                pool_l,
-                jnp.moveaxis(k, 1, 2).reshape(S * R, -1, k.shape[-1]),
-                jnp.moveaxis(v, 1, 2).reshape(S * R, -1, v.shape[-1]),
-                wp.reshape(-1), wo.reshape(-1),
-                quantized=quantized, kv_block=kv_block)
-            attn = fmha_decode(
-                q, pool_l["k"], pool_l["v"], page_table, attend,
-                causal=True, k_scales=pool_l.get("k_scales"),
-                v_scales=pool_l.get("v_scales"), kv_block=kv_block,
-                rope=rope_cs, implementation=decode_impl,
-                ancestor=ancestor)
-            attn = jnp.moveaxis(attn, 1, 2).reshape(S, R, -1)
-            out = self._apply_linear(self.attn_proj, lp["attn_proj"],
-                                     attn)
-            x = residual + out.astype(residual.dtype)
-            residual = x
-            y = self._norm(lp["ln2"], x).astype(c.compute_dtype)
-            if tree is not None:
-                return (residual + self._dense_mlp(lp, y).astype(
-                    residual.dtype), (pool_l, k, v))
-            y = self._dense_mlp(lp, y)
-            return residual + y.astype(residual.dtype), pool_l
-
-        x, scanned_out = jax.lax.scan(body, x, (params["layers"], pools))
-        x = self._norm(params["final_ln"], x.astype(jnp.float32))
-        logits = self.logits(params, x.astype(c.compute_dtype))
+        logits, new_pools, kv = self._paged_rows(
+            params, tokens, logical, positions, writev, page_table,
+            attend, pools, ancestor=ancestor, quantized=quantized,
+            kv_block=kv_block, weight_dtype=weight_dtype)
         if tree is not None:
-            new_pools, ks, vs = scanned_out
-            return logits, new_pools, (ks, vs)
-        return logits, scanned_out
+            return logits, new_pools, kv
+        return logits, new_pools
 
     def decode_fns(
         self,
@@ -2275,14 +2201,11 @@ class GPTModel:
         ``{"h": hidden, "aux": scalar}`` for MoE models (the aux-loss
         accumulator rides the ppermute ring with its microbatch), plain
         hidden otherwise."""
-
-        c = self.config
         s = (x["h"] if self.moe is not None else x).shape[1]
-        rope = (self._rope_tables(s)
-                if c.position_embedding == "rope" else None)
+        rope = self._rope_tables(s)
 
         def body(h, lp):
-            out, aux = self._layer(lp, h, None, rope=rope)
+            out, aux, _kv = self._layer(lp, h, None, rope=rope)
             return out, aux
 
         if self.moe is not None:

@@ -401,14 +401,33 @@ class TestSpeculativeServing:
         assert ng_b.cache.allocator.num_free == npages - 1
         assert nl_b.cache.allocator.num_free == npages - 1
 
+    @pytest.mark.parametrize("position", ["learned", "rope"])
+    @pytest.mark.parametrize(
+        "case", ["zero_drafts", "decode_is_one_row", "chunk_is_a_chain"])
     def test_verify_step_with_zero_drafts_matches_decode_step(
-            self, spec_setup):
-        """Row 0 of a draft-free verify step IS the plain decode step:
-        same logits (argmax-identical, numerically tight), same
-        committed semantics."""
+            self, spec_setup, case, position):
+        """The three adapters of ``GPTModel._paged_rows`` agree where
+        their shapes meet.  ``zero_drafts``: row 0 of a draft-free
+        verify step IS the plain decode step (argmax-identical,
+        numerically tight).  ``decode_is_one_row``: ``decode_step`` is
+        ``verify_step`` at ``R = 1``; ``chunk_is_a_chain``:
+        ``prefill_chunk`` on ``(1, C)`` rows is ``verify_step`` for one
+        slot with ``R = C`` chain rows on the same pools — the same
+        pool bits and the same logits, to the last bit (the chunk's
+        LM head runs on its one row, the chain's on all C: the same
+        hidden row through another matmul shape, so a ulp there)."""
         from jax.sharding import PartitionSpec as P
 
+        from apex_tpu.models import GPTConfig, GPTModel
+
         mesh, model, params, prompts, maxp = spec_setup
+        if position == "rope":
+            model = GPTModel(GPTConfig(
+                vocab_size=64, num_layers=2, hidden_size=32,
+                num_attention_heads=4, max_position_embeddings=64,
+                compute_dtype=jnp.float32, remat=False,
+                attention_impl="xla", position_embedding="rope"))
+            params = model.init(jax.random.PRNGKey(0))
         # a LIVE cache state (retired tables alias the null-page sink,
         # which the two paths fill with different scratch): admit two
         # slots and prefill their prompts explicitly
@@ -434,32 +453,61 @@ class TestSpeculativeServing:
                 jax.random.PRNGKey(slot))
             firsts.append(int(jax.device_get(first)))
         pt = jnp.asarray(cache.page_table)
+        toks = jnp.asarray(firsts, jnp.int32)
+        lens = jnp.asarray([len(prompts[0]), len(prompts[1])],
+                           jnp.int32)
+        C = PAGE
+        start = 2 * C       # slot 1's third chunk of 4, other tokens
+        chunk = jnp.asarray(
+            [[t + 1 for t in (prompts[1] + [0] * C)[start:start + C]]],
+            jnp.int32) % 64
 
         def both(p, pools, toks, lens, pt):
             active = jnp.ones((S,), bool)
-            l1, _ = model.decode_step(p, toks, lens, active, pt, pools)
+            if case == "chunk_is_a_chain":
+                # rows at or past the prompt's end are padding in both
+                l1, p1 = model.prefill_chunk(
+                    p, chunk, start, lens[1], 0, pt[1], pools)
+                valid = (start + jnp.arange(C) < lens[1])[None]
+                l2, p2 = model.verify_step(
+                    p, chunk, jnp.full((1,), start), active[:1], valid,
+                    pt[1:], pools)
+                return l1, l2[0, lens[1] - 1 - start], p1, p2
+            l1, p1 = model.decode_step(p, toks, lens, active, pt, pools)
+            R = 1 if case == "decode_is_one_row" else K + 1
             rows = jnp.concatenate(
-                [toks[:, None], jnp.zeros((S, K), jnp.int32)], axis=1)
-            valid = jnp.broadcast_to(
-                jnp.arange(K + 1)[None] <= 0, (S, K + 1))
-            l2, _ = model.verify_step(p, rows, lens, active, valid,
-                                      pt, pools)
-            return l1, l2[:, 0]
+                [toks[:, None], jnp.zeros((S, R - 1), jnp.int32)], axis=1)
+            valid = jnp.broadcast_to(jnp.arange(R)[None] <= 0, (S, R))
+            l2, p2 = model.verify_step(p, rows, lens, active, valid,
+                                       pt, pools)
+            return l1, l2[:, 0], p1, p2
 
         specs = model.param_specs()
         # the serving layout: head-sharded pools, vocab-parallel logits
         pool_specs = jax.tree.map(
             lambda _: P(None, None, "tp", None, None), pools)
+        logit_spec = P("tp") if case == "chunk_is_a_chain" \
+            else P(None, "tp")
         run = jax.jit(jax.shard_map(
             both, mesh=mesh,
             in_specs=(specs, pool_specs, P(), P(), P()),
-            out_specs=(P(None, "tp"), P(None, "tp"))))
-        toks = jnp.asarray(firsts, jnp.int32)
-        lens = jnp.asarray([len(prompts[0]), len(prompts[1])],
-                           jnp.int32)
-        l1, l2 = jax.device_get(run(params, pools, toks, lens, pt))
-        np.testing.assert_allclose(l1, l2, rtol=0, atol=1e-5)
+            out_specs=(logit_spec, logit_spec, pool_specs, pool_specs)))
+        l1, l2, p1, p2 = jax.device_get(
+            run(params, pools, toks, lens, pt))
         assert np.array_equal(np.argmax(l1, -1), np.argmax(l2, -1))
+        if case == "zero_drafts":
+            # four rows against one: another attention shape
+            np.testing.assert_allclose(l1, l2, rtol=0, atol=1e-5)
+            return
+        if case == "chunk_is_a_chain":
+            np.testing.assert_allclose(l1, l2, rtol=0, atol=1e-6)
+        else:
+            np.testing.assert_array_equal(l1, l2)
+        for name in p1:
+            # page 0 is the null page, the sink of masked rows
+            np.testing.assert_array_equal(p1[name][:, 1:], p2[name][:, 1:])
+            assert not np.array_equal(p1[name][:, 1:],
+                                      jax.device_get(pools[name])[:, 1:])
 
     def test_spec_telemetry_reaches_metrics_report(
             self, spec_setup, tmp_path):
