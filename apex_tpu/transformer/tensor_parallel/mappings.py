@@ -34,6 +34,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 from jax._src.lax import parallel as _lax_parallel
+from jax.ad_checkpoint import checkpoint_name
 
 from apex_tpu.transformer.parallel_state import TENSOR_PARALLEL_AXIS
 
@@ -43,7 +44,17 @@ __all__ = [
     "scatter_to_tensor_model_parallel_region",
     "gather_from_tensor_model_parallel_region",
     "all_gather_invariant",
+    "TP_REDUCED_NAME",
 ]
+
+#: ``checkpoint_name`` tag of a row-parallel layer's output AFTER its sum
+#: over tp.  The dots policies keep the dot's output, which is the
+#: partial sum, and ``psum`` is not a dot: the backward would all-reduce
+#: the same bytes a second time to rebuild what the sum fed.  A remat
+#: policy that saves this name (``tensor_parallel.random.
+#: CHECKPOINT_POLICIES``) keeps the reduced tensor instead; under any
+#: other policy, and outside ``jax.checkpoint``, the tag is the identity.
+TP_REDUCED_NAME = "tp_reduced"
 
 
 def all_gather_invariant(x, axis_name, *, axis: int = 0, tiled: bool = False):
@@ -69,8 +80,9 @@ def copy_to_tensor_model_parallel_region(x, axis_name=TENSOR_PARALLEL_AXIS):
 
 def reduce_from_tensor_model_parallel_region(x, axis_name=TENSOR_PARALLEL_AXIS):
     """All-reduce forward, identity backward
-    (reference: apex/transformer/tensor_parallel/mappings.py:96-110)."""
-    return jax.lax.psum(x, axis_name)
+    (reference: apex/transformer/tensor_parallel/mappings.py:96-110).
+    The sum carries :data:`TP_REDUCED_NAME`, so remat keeps it."""
+    return checkpoint_name(jax.lax.psum(x, axis_name), TP_REDUCED_NAME)
 
 
 def scatter_to_tensor_model_parallel_region(x, axis_name=TENSOR_PARALLEL_AXIS):

@@ -31,6 +31,7 @@ from apex_tpu.transformer.parallel_state import (
     DATA_PARALLEL_AXIS,
     TENSOR_PARALLEL_AXIS,
 )
+from apex_tpu.transformer.tensor_parallel.mappings import TP_REDUCED_NAME
 
 __all__ = ["model_parallel_key", "data_parallel_key", "checkpoint", "CHECKPOINT_POLICIES"]
 
@@ -60,15 +61,18 @@ CHECKPOINT_POLICIES = {
     ),
     "everything_saveable": jax.checkpoint_policies.everything_saveable,
     # the models' default: the dots policy above, plus the two residuals
-    # the attention kernels' forward rules name (out, lse).  A Mosaic
-    # call is not a dot, so without this the backward runs the forward
-    # kernel a second time; the XLA attention path carries no tag and
-    # compiles as under the dots policy alone
+    # the attention kernels' forward rules name (out, lse) and a
+    # row-parallel layer's output after its tp sum.  A Mosaic call is
+    # not a dot, so without the first the backward runs the forward
+    # kernel a second time; neither is psum, so without the second it
+    # all-reduces the dot's partial sum a second time.  The XLA
+    # attention path carries no tag and compiles as under the dots
+    # policy alone
     "dots_with_no_batch_dims_and_attention_saveable": (
         jax.checkpoint_policies.save_from_both_policies(
             jax.checkpoint_policies.dots_with_no_batch_dims_saveable,
             jax.checkpoint_policies.save_only_these_names(
-                *ATTENTION_RESIDUAL_NAMES),
+                *ATTENTION_RESIDUAL_NAMES, TP_REDUCED_NAME),
         )
     ),
 }

@@ -101,6 +101,31 @@ def file_batches(path, n_batches, global_batch, seq, vocab):
     return pool
 
 
+def dp_mean_where_varying(grads, specs):
+    """The dp gradient sum happens ONCE: ``pmean(g, "dp")`` for the
+    leaves whose gradient still varies over dp, nothing for the rest.
+
+    ``model.loss`` averages over dp inside the differentiated function,
+    and the weights come in typed dp-invariant, so jax's own transposes
+    have already summed every replicated leaf's gradient over dp (in
+    the backward loop, layer by layer) and typed it dp-invariant.  A
+    pmean of an invariant value is NOT free: jax ``pvary``s it and XLA
+    all-reduces (g + g) / 2 — the whole gradient tree over the wire a
+    second time.  What is left to average is a gradient taken with
+    respect to weights cast dp-varying (the ``parallel.Reducer`` idiom:
+    local gradients, reduced later); its type says so.  The step's
+    ``out_specs`` refuse a dp-varying parameter at trace time, so a
+    wrong skip cannot pass silently.  dp-SHARDED leaves (MoE experts
+    riding dp as ep) are already final via the all_to_all transpose and
+    must NOT be averaged elementwise across unrelated experts."""
+    return jax.tree.map(
+        lambda g, sp: (jax.lax.pmean(g, "dp")
+                       if "dp" in jax.typeof(g).vma
+                       and "dp" not in parallel_state.spec_axis_names(sp)
+                       else g),
+        grads, specs)
+
+
 def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--tp", type=int, default=1)
@@ -421,26 +446,12 @@ def main(argv=None):
                     loss = model.loss(p, tokens, targets)
                     return mp.scale_loss(amp_state, loss), loss
 
+                # model.loss has averaged over dp: loss is dp-invariant
                 grads, loss = jax.grad(loss_fn, has_aux=True)(weights)
-                loss = jax.lax.pmean(loss, "dp")
         if not pp_path and not any_zero and not hier:
-            # spec-aware dp sync: replicated leaves pmean (a no-op
-            # re-establishing invariance — model.loss's internal
-            # pmean already made their grads globally complete);
-            # dp-SHARDED leaves (MoE experts riding dp as ep) are
-            # already final via the all_to_all transpose and must
-            # NOT be averaged elementwise across unrelated experts.
             # ZeRO skips this: its reduce-scatter is the reduction
-            from apex_tpu.transformer.parallel_state import (
-                spec_axis_names,
-            )
-
             with phase("grad_sync"):
-                grads = jax.tree.map(
-                    lambda g, sp: (g if "dp" in spec_axis_names(sp)
-                                   else jax.lax.pmean(g, "dp")),
-                    grads, specs,
-                )
+                grads = dp_mean_where_varying(grads, specs)
         if hier:
             # the dummy "dp" axis made every model-internal dp reduce a
             # no-op: the data-axis loss mean happens here instead

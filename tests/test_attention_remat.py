@@ -19,6 +19,13 @@ XLA off the TPU):
   ``ring_attention``'s ``return_lse=True``;
 - the XLA attention path carries no tag and compiles to the same text
   under both policies.
+
+Since PR 31 the default policy keeps a third name, a row-parallel
+layer's output after its tp sum (``mappings.TP_REDUCED_NAME``), so the
+model-level comparisons above are made against the default policy
+without its attention half (``DOTS_AND_TP_SUM``), and at tp 2 the
+gradient's jaxpr holds one tp activation sum fewer a layer body than
+under the plain dots policy.
 """
 
 import re
@@ -26,6 +33,8 @@ import re
 import jax
 import jax.numpy as jnp
 import pytest
+from jax._src.ad_checkpoint import saved_residuals
+from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import PartitionSpec as P
 
 from apex_tpu.models import BertConfig, GPTConfig, GPTModel, T5Config
@@ -34,6 +43,7 @@ from apex_tpu.ops.common import ATTENTION_RESIDUAL_NAMES
 from apex_tpu.ops.ring_attention import ring_attention
 from apex_tpu.telemetry.spans import kernel_name
 from apex_tpu.transformer import parallel_state
+from apex_tpu.transformer.tensor_parallel.mappings import TP_REDUCED_NAME
 from apex_tpu.transformer.tensor_parallel.random import (
     CHECKPOINT_POLICIES,
     checkpoint,
@@ -42,6 +52,10 @@ from apex_tpu.transformer.tensor_parallel.random import (
 KEPT = "dots_with_no_batch_dims_and_attention_saveable"
 DOTS = "dots_with_no_batch_dims_saveable"
 NOTHING = "nothing_saveable"
+# the default policy without its attention half
+DOTS_AND_TP_SUM = jax.checkpoint_policies.save_from_both_policies(
+    jax.checkpoint_policies.dots_with_no_batch_dims_saveable,
+    jax.checkpoint_policies.save_only_these_names(TP_REDUCED_NAME))
 # rung -> (``implementation=`` of flash_attention, kernel name, backward
 # kernel calls)
 KERNELS = {"mid": ("mid", "fmha_mid", 1), "flash": ("pallas", "fmha_flash", 2),
@@ -230,7 +244,7 @@ def test_gpt_train_step_holds_the_forward_kernel(mesh, policy, fwd_calls):
 def test_gpt_loss_and_grads_bit_identical_to_the_dots_policy(mesh, dropout):
     drop = dict(attention_dropout=dropout, hidden_dropout=dropout)
     step, args = _gpt_step(mesh, **drop)
-    plain, _ = _gpt_step(mesh, remat_policy=DOTS, **drop)
+    plain, _ = _gpt_step(mesh, remat_policy=DOTS_AND_TP_SUM, **drop)
     kept = jax.jit(step)(*args)
     assert _same_bits(kept, jax.jit(plain)(*args))
     assert all(bool(jnp.any(g != 0)) for g in jax.tree.leaves(kept[1]))
@@ -240,12 +254,48 @@ def test_xla_attention_compiles_the_same_under_both_policies(mesh):
     """No tag on the XLA path: the named-residual half of the policy
     finds nothing to keep, and the program is the dots policy's."""
     texts = []
-    for policy in (KEPT, DOTS):
+    for policy in (KEPT, DOTS_AND_TP_SUM):
         step, args = _gpt_step(mesh, attention_impl="xla",
                                remat_policy=policy)
         texts.append(jax.jit(step).lower(*args).compile().as_text())
     assert texts[0] == texts[1]
     assert "rematted_computation" in texts[0]
+
+
+def _tp_activation_sums(jaxpr, shape) -> int:
+    """``psum`` equations over tp, at any depth, whose result has
+    ``shape`` (a scan's body is counted once)."""
+    n = 0
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name.startswith("psum"):
+            n += ("tp" in eqn.params["axes"]
+                  and eqn.outvars[0].aval.shape == shape)
+        n += sum(_tp_activation_sums(sub, shape)
+                 for sub in jax.core.jaxprs_in_params(eqn.params))
+    return n
+
+
+def test_tp_sum_is_a_kept_residual_at_tp2():
+    """The default policy keeps ``TP_REDUCED_NAME``: the backward body
+    no longer redoes the forward's ``attn_proj`` sum (the dots policy
+    keeps the dot's output, which is the PARTIAL sum)."""
+    if parallel_state.model_parallel_is_initialized():
+        parallel_state.destroy_model_parallel()
+    mesh = parallel_state.initialize_model_parallel(
+        tensor_model_parallel_size_=2, devices=jax.devices()[:2])
+    try:
+        counts = {}
+        for name, policy in (("kept", KEPT), ("dots", DOTS)):
+            step, args = _gpt_step(mesh, attention_impl="xla",
+                                   remat_policy=policy)
+            counts[name] = _tp_activation_sums(
+                jax.make_jaxpr(step)(*args).jaxpr, (2, 16, 32))
+        # the embedding's sum, two a forward body, the transposes of
+        # the two column-parallel inputs a backward body and of the
+        # head's; the dots policy redoes attn_proj's besides
+        assert counts == {"kept": 6, "dots": 7}
+    finally:
+        parallel_state.destroy_model_parallel()
 
 
 def test_policy_table_adds_one_entry_and_the_models_default_to_it():
@@ -256,5 +306,14 @@ def test_policy_table_adds_one_entry_and_the_models_default_to_it():
         assert CHECKPOINT_POLICIES[name] is getattr(   # limit relies on it
             jax.checkpoint_policies, name)
     assert ATTENTION_RESIDUAL_NAMES == ("fmha_out", "fmha_lse")
+    assert TP_REDUCED_NAME == "tp_reduced"
+
+    def residuals(name):   # the argument, and the named value if kept
+        return len(saved_residuals(jax.checkpoint(
+            lambda x: jnp.sin(checkpoint_name(jnp.sin(x), name)),
+            policy=CHECKPOINT_POLICIES[KEPT]), jnp.ones(4)))
+
+    assert [residuals(name) for name in (
+        *ATTENTION_RESIDUAL_NAMES, TP_REDUCED_NAME, "another")] == [2, 2, 2, 1]
     assert (GPTConfig().remat_policy == BertConfig().remat_policy
             == T5Config(vocab_size=8).remat_policy == KEPT)

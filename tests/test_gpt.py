@@ -388,7 +388,8 @@ def test_measured_optimal_defaults_pinned():
     evidence."""
     cfg = GPTConfig()
     assert cfg.remat is True
-    # PR 27: the dots policy plus the attention kernels' (out, lse)
+    # PR 27: the dots policy plus the attention kernels' (out, lse);
+    # PR 31: and a row-parallel layer's output after its tp sum
     assert cfg.remat_policy == (
         "dots_with_no_batch_dims_and_attention_saveable")
     assert cfg.fused_ce is None  # auto by logits size (PROFILE_r05)

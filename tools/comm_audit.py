@@ -143,6 +143,7 @@ _OP_RE = re.compile(
 #: param-gather all-gather apart from a gradient-sync one — same op,
 #: same axis, different phase.
 _PHASE_RE = re.compile(r"tlm\.(\w+)")
+_OP_NAME_RE = re.compile(r'op_name="([^"]*)"')
 
 
 def parse_collectives(hlo_text: str):
@@ -150,8 +151,11 @@ def parse_collectives(hlo_text: str):
     the op kind, result/operand payload bytes, replica groups and —
     when the op carries a ``tlm.<phase>`` named scope in its metadata
     — the step phase (``param_gather`` for ZeRO-3 weight gathers,
-    ``grad_sync`` for gradient reduces).  ``-done`` halves of async
-    pairs are skipped (the ``-start`` op carries the payload)."""
+    ``grad_sync`` for gradient reduces), beside the whole ``op_name``
+    (the chain of jax scopes: a loop body, a transpose, a remat say so
+    there) and the result's ``(dtype, dims)`` shapes, one per tuple
+    element.  ``-done`` halves of async pairs are skipped (the
+    ``-start`` op carries the payload)."""
     out = []
     for line in hlo_text.splitlines():
         m = _OP_RE.search(line)
@@ -162,10 +166,9 @@ def parse_collectives(hlo_text: str):
         op = m.group(2)
         pm = _PHASE_RE.search(line)
         phase = pm.group(1) if pm else None
-        result_bytes = sum(
-            _shape_bytes(d, s)
-            for d, s in _SHAPE_RE.findall(m.group(1))
-        )
+        result_shapes = _SHAPE_RE.findall(m.group(1))
+        result_bytes = sum(_shape_bytes(d, s) for d, s in result_shapes)
+        nm = _OP_NAME_RE.search(line)
         # operands end at the call's closing paren; attributes
         # (replica_groups, to_apply, metadata) follow it
         operand_bytes = sum(
@@ -189,6 +192,10 @@ def parse_collectives(hlo_text: str):
         out.append({
             "op": op,
             "phase": phase,
+            "op_name": nm.group(1) if nm else None,
+            "result_shapes": [
+                (d, tuple(int(x) for x in s.split(",") if x.strip()))
+                for d, s in result_shapes],
             "result_bytes": result_bytes,
             "operand_bytes": operand_bytes,
             "replica_groups": groups,
