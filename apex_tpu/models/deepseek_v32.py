@@ -593,7 +593,7 @@ class DeepSeekV32Model:
         prefill_chunk)`` programs): a chunk reads and scores only the
         pages that can hold its context."""
         from apex_tpu.serving.kv_cache import init_pools
-        from apex_tpu.serving.sampling import sample
+        from apex_tpu.serving.sampling import advance_slots, sample
 
         c, cfg = self.config, cache_config
         self._check_cache(cfg, prefill_chunk)
@@ -631,28 +631,14 @@ class DeepSeekV32Model:
             logits, pools, stats, (idx, chosen) = self.decode_step(
                 params, pools, carry["tokens"], positions, active,
                 page_table, page_size=page, table=table)
-            if temperature == 0.0:
-                sampled = sample(logits, None, 0.0)
-            else:
-                # per-slot draw, the context length folded into the
-                # slot's key: GPTModel.decode_fns' key schedule
-                subs = jax.vmap(jax.random.fold_in)(
-                    carry["sample_keys"], jnp.where(active, positions + 1, 0))
-                sampled = jax.vmap(lambda l, k: sample(
-                    l[None], k, temperature, top_k, top_p)[0])(logits, subs)
-            ai = active.astype(jnp.int32)
-            tokens = jnp.where(active, sampled, carry["tokens"])
-            steps_left = carry["steps_left"] - ai
-            eos_hit = ((tokens == eos_id) if eos_id is not None
-                       else jnp.zeros_like(active))
-            done = carry["done"] | (active & (eos_hit | (steps_left <= 0)))
             counted = jnp.stack([
                 jnp.float32(1), *stats,
-                jnp.sum(ai).astype(jnp.float32) * c.num_hidden_layers])
+                jnp.sum(active.astype(jnp.int32)).astype(jnp.float32)
+                * c.num_hidden_layers])
             return pools, {
-                "tokens": tokens, "lengths": positions + ai,
-                "steps_left": steps_left, "done": done,
-                "sample_keys": carry["sample_keys"],
+                **advance_slots(carry, logits, active,
+                                temperature=temperature, top_k=top_k,
+                                top_p=top_p, eos_id=eos_id),
                 "counters": carry["counters"] + counted,
                 "last_logits": logits, "last_selected": idx,
                 "last_selected_valid": chosen}

@@ -1428,7 +1428,9 @@ class GPTModel:
         from apex_tpu.serving.kv_cache import (
             init_pools, write_targets, write_tokens,
         )
-        from apex_tpu.serving.sampling import sample, spec_accept
+        from apex_tpu.serving.sampling import (
+            advance_slots, sample, spec_accept,
+        )
         from apex_tpu.transformer import parallel_state
 
         c = self.config
@@ -1603,33 +1605,9 @@ class GPTModel:
                 page_table, pools, quantized=cfg.quantized,
                 kv_block=cfg.kv_block, weight_dtype=wd_active)
             logits = _full_logits(logits)
-            if temperature == 0.0:
-                sampled = sample(logits, None, 0.0)
-            else:
-                # per-slot draw: fold the slot's context length into
-                # ITS key, so a seeded request samples the same stream
-                # in any slot at any admission order
-                ctx = jnp.where(active, carry["lengths"] + 1, 0)
-                subs = jax.vmap(jax.random.fold_in)(
-                    carry["sample_keys"], ctx)
-                sampled = jax.vmap(
-                    lambda l, k: sample(l[None], k, temperature,
-                                        top_k, top_p)[0]
-                )(logits, subs)
-            ai = active.astype(jnp.int32)
-            tokens = jnp.where(active, sampled, carry["tokens"])
-            steps_left = carry["steps_left"] - ai
-            eos_hit = ((tokens == eos_id) if eos_id is not None
-                       else jnp.zeros_like(active))
-            done = carry["done"] | (
-                active & (eos_hit | (steps_left <= 0)))
-            return pools, {
-                "tokens": tokens,
-                "lengths": carry["lengths"] + ai,
-                "steps_left": steps_left,
-                "done": done,
-                "sample_keys": carry["sample_keys"],
-            }
+            return pools, advance_slots(
+                carry, logits, active, temperature=temperature, top_k=top_k,
+                top_p=top_p, eos_id=eos_id)
 
         @phase("decode")
         def _spec(params, pools, carry, page_table, drafts, draft_len):

@@ -111,6 +111,9 @@ class _DecodeConfig(NamedTuple):
     has_scales: bool
     has_rope: bool
     ancestor: Optional[tuple] = None  # (sq, sq) static tree mask rows
+    group: int = 1          # query heads a K/V head serves
+    has_first: bool = False  # per-sequence first position, ring table
+    table_pages: int = 0    # the page table's width (has_first only)
 
 
 # ---------------------------------------------------------------------------
@@ -138,6 +141,7 @@ def paged_attention_reference(
     v_scales: Optional[jnp.ndarray] = None,
     kv_block: int = _LANES,
     ancestor: Optional[tuple] = None,
+    first: Optional[jnp.ndarray] = None,
 ) -> jnp.ndarray:
     """Plain-XLA paged decode attention — the correctness reference.
 
@@ -155,10 +159,18 @@ def paged_attention_reference(
     iff ``ancestor[i][j]`` — tree speculation's per-branch visibility.
     The committed prefix (positions ``< lengths[b] - sq``) stays fully
     visible to every row.
+
+    The pool may hold FEWER heads than ``q`` has (grouped-query
+    attention): query head ``i`` reads K/V head ``i // (h / h_kv)``, and
+    K/V are not repeated.  ``first (b,)`` masks cache positions below it
+    and makes the table a RING: position ``p`` lives in column ``(p //
+    page_size) % width``, each column holding the newest page written
+    to it (see :func:`fmha_decode`).
     """
     b, h, sq, d = q.shape
     num_pages = page_table.shape[1]
     page_size = k_pages.shape[2]
+    h_kv = k_pages.shape[1]
     scale = (1.0 / d**0.5) if sm_scale is None else float(sm_scale)
 
     def gather(pages, scales):
@@ -167,10 +179,13 @@ def paged_attention_reference(
             s = jnp.take(scales, page_table, axis=0)
             x = _dequant_pages(x, s, kv_block)
         x = jnp.moveaxis(x, 2, 1)
-        return x.reshape(b, h, num_pages * page_size, d)
+        return x.reshape(b, h_kv, num_pages * page_size, d)
 
     k = gather(k_pages, k_scales)
     v = gather(v_pages, v_scales)
+    if h_kv != h or first is not None:
+        return _grouped_window_reference(
+            q, k, v, lengths, first, causal, scale, page_size)
     s = jnp.einsum(
         "bhqd,bhkd->bhqk", q.astype(jnp.float32), k.astype(jnp.float32),
         preferred_element_type=jnp.float32,
@@ -199,6 +214,44 @@ def paged_attention_reference(
     return out.astype(q.dtype)
 
 
+def _grouped_window_reference(q, k, v, lengths, first, causal, scale,
+                              page_size):
+    """The reference for grouped heads and/or a ring table: ``k``/``v``
+    ``(b, h_kv, columns * page_size, d)`` in TABLE order.  With
+    ``first`` column ``c`` holds the newest logical page ``<=`` the
+    sequence's last one that is congruent to ``c``."""
+    b, h, sq, d = q.shape
+    h_kv, S = k.shape[1], k.shape[2]
+    col = jnp.arange(S, dtype=jnp.int32) // page_size
+    off = jnp.arange(S, dtype=jnp.int32) % page_size
+    ln = lengths.astype(jnp.int32)[:, None]
+    if first is None:
+        k_pos = jnp.broadcast_to(col * page_size + off, (b, S))
+        lo = jnp.zeros((b, 1), jnp.int32)
+    else:
+        width = S // page_size
+        last = (jnp.maximum(ln, 1) - 1) // page_size
+        page = last - (last - col[None]) % width
+        k_pos = page * page_size + off[None]
+        lo = first.astype(jnp.int32)[:, None]
+    k_pos = k_pos[:, None, None, None]                   # (b,1,1,1,S)
+    lo, ln = lo[:, None, None, None], ln[:, None, None, None]
+    s = jnp.einsum(
+        "bkgqd,bksd->bkgqs",
+        q.reshape(b, h_kv, h // h_kv, sq, d).astype(jnp.float32),
+        k.astype(jnp.float32), preferred_element_type=jnp.float32) * scale
+    if causal:
+        q_pos = ln - sq + jnp.arange(sq)[None, None, None, :, None]
+        mask = (k_pos <= q_pos) & (k_pos >= lo)
+    else:
+        mask = (k_pos < ln) & (k_pos >= lo)
+    s = jnp.where(mask, s, _NEG_INF)
+    p = jnp.where(mask, jax.nn.softmax(s, axis=-1), 0.0)
+    out = jnp.einsum("bkgqs,bksd->bkgqd", p, v.astype(jnp.float32),
+                     preferred_element_type=jnp.float32)
+    return out.reshape(b, h, sq, d).astype(q.dtype)
+
+
 # ---------------------------------------------------------------------------
 # Pallas kernel
 # ---------------------------------------------------------------------------
@@ -207,6 +260,7 @@ def paged_attention_reference(
 def _decode_kernel(*refs, cfg: _DecodeConfig):
     pt_ref, len_ref = refs[:2]
     rest = list(refs[2:])
+    first_ref = rest.pop(0) if cfg.has_first else None
     q_ref = rest.pop(0)
     qrot_ref = cos_ref = sin_ref = None
     if cfg.has_rope:
@@ -218,10 +272,20 @@ def _decode_kernel(*refs, cfg: _DecodeConfig):
     o_ref, acc_ref, m_ref, l_ref = rest
 
     b, hb, p = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    step = p
     sq, ps = cfg.sq, cfg.page_size
     ln = len_ref[b]
+    if cfg.has_first:
+        # the walk starts at the page that holds the first position a
+        # query may see: grid step ``step`` is LOGICAL page first // ps
+        # + step (the index maps turn it into a ring column)
+        first = first_ref[b]
+        p = first // ps + step
+    # a K/V head's rows are its ``group`` query heads' sq rows each
+    rows = sq * cfg.group
+    native = cfg.group > 1 or cfg.has_first
 
-    @pl.when(p == 0)
+    @pl.when(step == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
         m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
@@ -246,8 +310,15 @@ def _decode_kernel(*refs, cfg: _DecodeConfig):
                       + qrot_ref[0, hi].astype(jnp.float32)
                       * sin_ref[0, hi].astype(jnp.float32))
             qh = qh * cfg.sm_scale
-            kh = k_ref[0, hi].astype(jnp.float32)            # (ps, d)
-            vh = v_ref[0, hi].astype(jnp.float32)
+            if native:
+                # grouped / windowed walks hand the MXU the pages as
+                # they are stored (fp32 accumulation): no per-page
+                # widening pass on the VPU under a 2-FLOPs-a-byte stream
+                kh, vh = k_ref[0, hi], v_ref[0, hi]
+                qh = qh.astype(kh.dtype)
+            else:
+                kh = k_ref[0, hi].astype(jnp.float32)        # (ps, d)
+                vh = v_ref[0, hi].astype(jnp.float32)
             if cfg.has_scales:
                 kh = kh * jnp.repeat(
                     ks_ref[0, hi], cfg.kv_block, axis=1)[:, :d]
@@ -281,13 +352,20 @@ def _decode_kernel(*refs, cfg: _DecodeConfig):
                 mask = (fresh < 0) | (
                     (fresh >= 0) & (fresh < sq) & tree)
             elif cfg.causal:
-                q_pos = ln - sq + jax.lax.broadcasted_iota(
-                    jnp.int32, s.shape, 0)
+                if cfg.group > 1:
+                    # rows are (query head, token)
+                    q_pos = ln - sq + jax.lax.broadcasted_iota(
+                        jnp.int32, s.shape, 0) % sq
+                else:
+                    q_pos = ln - sq + jax.lax.broadcasted_iota(
+                        jnp.int32, s.shape, 0)
                 mask = k_pos <= q_pos
             else:
                 mask = k_pos < ln
+            if cfg.has_first:
+                mask = mask & (k_pos >= first)
             s = jnp.where(mask, s, _NEG_INF)
-            r0, r1 = hi * sq, (hi + 1) * sq
+            r0, r1 = hi * rows, (hi + 1) * rows
             m_prev = m_ref[r0:r1, 0:1]
             l_prev = l_ref[r0:r1, 0:1]
             m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
@@ -295,13 +373,14 @@ def _decode_kernel(*refs, cfg: _DecodeConfig):
             corr = jnp.exp(m_prev - m_new)
             l_new = l_prev * corr + jnp.sum(pexp, axis=-1, keepdims=True)
             acc_ref[r0:r1] = acc_ref[r0:r1] * corr + jax.lax.dot_general(
-                pexp, vh, (((1,), (0,)), ((), ())),
+                pexp.astype(vh.dtype) if native else pexp, vh,
+                (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32,
             )
-            m_ref[r0:r1] = jnp.broadcast_to(m_new, (sq, m_ref.shape[1]))
-            l_ref[r0:r1] = jnp.broadcast_to(l_new, (sq, l_ref.shape[1]))
+            m_ref[r0:r1] = jnp.broadcast_to(m_new, (rows, m_ref.shape[1]))
+            l_ref[r0:r1] = jnp.broadcast_to(l_new, (rows, l_ref.shape[1]))
 
-    @pl.when(p == cfg.num_pages - 1)
+    @pl.when(step == cfg.num_pages - 1)
     def _finalize():
         # the softmax-normalization tail, fused (the operation-fusion
         # paper's point: this divide never round-trips through HBM).
@@ -313,18 +392,28 @@ def _decode_kernel(*refs, cfg: _DecodeConfig):
 
 
 def _decode_pallas(q, q_rot, cos, sin, k_pages, v_pages, k_scales,
-                   v_scales, page_table, lengths, cfg: _DecodeConfig):
+                   v_scales, page_table, lengths, cfg: _DecodeConfig,
+                   first=None):
+    """``q`` (and the rope planes) come as ``(b, h_kv, group * sq, d)``:
+    a K/V head's query heads are further ROWS of its program."""
     b, h, sq, d = q.shape
     ps = cfg.page_size
     nb = k_scales.shape[-1] if cfg.has_scales else 0
     bh = cfg.block_h
     n_hb = h // bh
 
-    def qmap(bb, hb, p, pt, ln):
+    def qmap(bb, hb, p, *scalars):
         return (bb, hb, 0, 0)
 
-    def kvmap(bb, hb, p, pt, ln):
-        return (pt[bb, p], hb, 0, 0)
+    def kvmap(bb, hb, p, pt, ln, *fs):
+        if not cfg.has_first:
+            return (pt[bb, p], hb, 0, 0)
+        # logical page first // ps + p, held back at the sequence's last
+        # page (steps past it repeat that block: no further fetch), in
+        # the ring column it lives in
+        last = (jnp.maximum(ln[bb], 1) - 1) // ps
+        page = jnp.minimum(fs[0][bb] // ps + p, last)
+        return (pt[bb, page % cfg.table_pages], hb, 0, 0)
 
     in_specs = [pl.BlockSpec((1, bh, sq, d), qmap)]
     inputs = [q]
@@ -343,8 +432,11 @@ def _decode_pallas(q, q_rot, cos, sin, k_pages, v_pages, k_scales,
         ]
         inputs += [k_scales, v_scales]
 
+    scalars = [page_table.astype(jnp.int32), lengths.astype(jnp.int32)]
+    if cfg.has_first:
+        scalars.append(first.astype(jnp.int32))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=len(scalars),
         grid=(b, n_hb, cfg.num_pages),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, bh, sq, d), qmap),
@@ -364,7 +456,7 @@ def _decode_pallas(q, q_rot, cos, sin, k_pages, v_pages, k_scales,
         ),
         interpret=_interpret(),
         name=kernel_name("paged_decode"),
-    )(page_table.astype(jnp.int32), lengths.astype(jnp.int32), *inputs)
+    )(*scalars, *inputs)
 
 
 # ---------------------------------------------------------------------------
@@ -423,6 +515,9 @@ def fmha_decode(
     block_h: Optional[int] = None,
     implementation: Optional[str] = None,
     ancestor: Optional[tuple] = None,
+    num_kv_heads: Optional[int] = None,
+    first: Optional[jnp.ndarray] = None,
+    max_pages: Optional[int] = None,
 ) -> jnp.ndarray:
     """Decode attention: ``q (b, h, sq, d)`` against a paged KV cache.
 
@@ -453,6 +548,24 @@ def fmha_decode(
     ``ancestor[i][j]`` — several speculative branches verified against
     one committed prefix in one cache pass.  Requires ``causal=True``
     (the committed prefix stays fully visible either way).
+
+    ``num_kv_heads`` (grouped-query attention): the pool holds that many
+    heads and query head ``i`` reads K/V head ``i // (h /
+    num_kv_heads)``.  A K/V head's ``h / num_kv_heads`` query heads ride
+    its program as further query ROWS, so one page fetch serves them all
+    and K/V are never repeated.  ``None`` is ``h``: one K/V head a query
+    head, today's kernel.
+
+    ``first (b,)``: the first cache position a sequence's queries may
+    see (a window layer: ``lengths - window``, floored at 0).  Positions
+    below it are masked, and the page walk STARTS at the page holding
+    it: pages wholly before it are never fetched.  With ``first`` the
+    table is a RING of its width: the token at position ``p`` lives in
+    column ``(p // page_size) % width`` (a table that holds every
+    logical page is the ring that never wraps).  ``max_pages`` (static)
+    bounds the pages a walk can span (``window // page_size + 1`` for an
+    unaligned window) and is the grid's extent; default the table's
+    width.
     """
     if (k_scales is None) != (v_scales is None):
         raise ValueError("int8 pages need BOTH k_scales and v_scales")
@@ -463,10 +576,21 @@ def fmha_decode(
             f"scales passed with {k_pages.dtype} pages — scales belong "
             "to int8 pools only (stale scales would silently rescale "
             "full-precision K/V)")
-    if q.shape[1] != k_pages.shape[1]:
+    h_kv = q.shape[1] if num_kv_heads is None else int(num_kv_heads)
+    if h_kv != k_pages.shape[1] or q.shape[1] % h_kv:
         raise ValueError(
-            f"q heads {q.shape[1]} != pool heads {k_pages.shape[1]}"
+            f"q heads {q.shape[1]} (over {h_kv} K/V heads) != pool heads "
+            f"{k_pages.shape[1]}"
         )
+    group = q.shape[1] // h_kv
+    if (group > 1 or first is not None) and (
+            ancestor is not None or k_scales is not None):
+        raise ValueError(
+            "grouped heads and a first position are built for plain "
+            "pages and the causal mask (no int8 scales, no tree mask)")
+    if first is not None and first.shape != (q.shape[0],):
+        raise ValueError(
+            f"first must be (batch,) = ({q.shape[0]},), got {first.shape}")
     if q.shape[-1] != k_pages.shape[-1]:
         raise ValueError(
             f"q head_dim {q.shape[-1]} != pool head_dim "
@@ -478,8 +602,8 @@ def fmha_decode(
             f"{page_table.shape} for batch {q.shape[0]}"
         )
     b, h, sq, d = q.shape
-    if block_h is not None and h % int(block_h):
-        raise ValueError(f"block_h {block_h} must divide heads {h}")
+    if block_h is not None and h_kv % int(block_h):
+        raise ValueError(f"block_h {block_h} must divide heads {h_kv}")
     if rope is not None and rope[0].shape != (b, sq, d // 2):
         raise ValueError(
             f"rope tables must be (b, sq, d/2) = ({b}, {sq}, {d // 2}), "
@@ -535,37 +659,47 @@ def fmha_decode(
         return paged_attention_reference(
             qq, k_pages, v_pages, page_table, lengths, causal=causal,
             sm_scale=scale, k_scales=k_scales, v_scales=v_scales,
-            kv_block=kv_block, ancestor=ancestor,
+            kv_block=kv_block, ancestor=ancestor, first=first,
         )
 
     def _pallas_path():
-        bh = _pick_block_h(h, sq) if block_h is None else int(block_h)
-        if h % bh:
-            raise ValueError(f"block_h {bh} must divide heads {h}")
-        if bh * sq > FMHA_DECODE_MAX_ROWS:
+        rows = group * sq
+        bh = _pick_block_h(h_kv, rows) if block_h is None else int(block_h)
+        if h_kv % bh:
+            raise ValueError(f"block_h {bh} must divide heads {h_kv}")
+        if bh * rows > FMHA_DECODE_MAX_ROWS:
             # the per-program fp32 scratch is (block_h*sq) rows — past
             # the budget even block_h=1 cannot honor it, and lowering
             # failures at serve time are opaque.  Decode s_q is "1 or
             # a small chunk" by design; bigger tiles belong to the
             # training ladder (or implementation="xla").
             raise ValueError(
-                f"block_h*sq = {bh}*{sq} exceeds the decode kernel's "
+                f"block_h*sq = {bh}*{rows} exceeds the decode kernel's "
                 f"per-program row budget (FMHA_DECODE_MAX_ROWS="
                 f"{FMHA_DECODE_MAX_ROWS}); chunk the query (sq <= "
                 f"{FMHA_DECODE_MAX_ROWS}) or use implementation='xla'")
+        width = page_table.shape[1]
         cfg = _DecodeConfig(
             sm_scale=scale, causal=causal, sq=sq, block_h=bh,
-            page_size=k_pages.shape[2], num_pages=page_table.shape[1],
+            page_size=k_pages.shape[2],
+            num_pages=(width if first is None or max_pages is None
+                       else min(width, int(max_pages))),
             kv_block=int(kv_block), has_scales=k_scales is not None,
-            has_rope=rope is not None, ancestor=ancestor,
+            has_rope=rope is not None, ancestor=ancestor, group=group,
+            has_first=first is not None,
+            table_pages=width if first is not None else 0,
         )
-        q_rot = cos = sin = None
+        planes = [q]
         if rope is not None:
-            q_rot, cos, sin = _rope_operands(q, rope)
-        return _decode_pallas(
-            q, q_rot, cos, sin, k_pages, v_pages, k_scales, v_scales,
-            page_table, lengths, cfg,
+            planes += _rope_operands(q, rope)
+        if group > 1:
+            # (b, h, sq, d) -> (b, h_kv, group * sq, d): a plain reshape
+            planes = [t.reshape(b, h_kv, rows, d) for t in planes]
+        out = _decode_pallas(
+            *(planes + [None] * (4 - len(planes))), k_pages, v_pages,
+            k_scales, v_scales, page_table, lengths, cfg, first=first,
         )
+        return out.reshape(b, h, sq, d) if group > 1 else out
 
     return run_kernel(
         "fmha_decode", _pallas_path, _xla_path, impl

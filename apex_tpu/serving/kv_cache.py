@@ -56,6 +56,7 @@ import numpy as np
 
 __all__ = [
     "KVCacheConfig",
+    "PageClass",
     "CacheOutOfPages",
     "AdmitResult",
     "PageAllocator",
@@ -65,6 +66,8 @@ __all__ = [
     "init_pools",
     "write_tokens",
     "write_latent_tokens",
+    "write_class_rows",
+    "write_class_pages",
     "write_targets",
     "copy_pages",
     "export_pages",
@@ -97,8 +100,52 @@ def prompt_page_hashes(prompt_tokens, page_size: int) -> List[bytes]:
 
 
 @dataclasses.dataclass(frozen=True)
+class PageClass:
+    """The state ONE KIND of layer keeps: which of the model's layers
+    (``layers``, in the order of the class's pool axis), what a token's
+    entry is (``num_heads`` x ``head_dim`` keys and values, or for
+    ``kind="latent"`` one ``latent_dim`` row and an ``index_dim`` key),
+    how many physical pages the class's pool has (page 0 reserved) and
+    how many a slot may hold (``pages_per_seq``).
+
+    ``window > 0`` makes the class a RING a slot owns: the layers attend
+    to the last ``window`` positions only, the token at position ``p``
+    lives in the slot's table column ``(p // page_size) %
+    pages_per_seq``, and a page is overwritten once the slot has moved a
+    whole ring past it.  A slot therefore holds at most ``pages_per_seq``
+    pages of the class whatever its context, and its table row never
+    changes after admission (docs/serving.md says why a ring and not
+    pages handed back).  ``pages_per_seq * page_size`` has to cover the
+    window, the longest run of tokens written before they are read (a
+    prefill chunk) and one page of slack for an unaligned window."""
+
+    name: str
+    layers: Tuple[int, ...]
+    num_pages: int
+    pages_per_seq: int
+    num_heads: int
+    head_dim: int
+    window: int = 0
+    kind: str = "kv"
+    latent_dim: int = 0
+    index_dim: int = 0
+
+    def pages_for(self, n_tokens: int, page_size: int) -> int:
+        """Pages a sequence of ``n_tokens`` holds of this class."""
+        n = -(-n_tokens // page_size)
+        return min(n, self.pages_per_seq) if self.window else n
+
+
+@dataclasses.dataclass(frozen=True)
 class KVCacheConfig:
     """Shape and dtype of one paged cache.
+
+    A cache is a tuple of :class:`PageClass` (``page_classes``): each
+    class has its own pool, allocator and page-table columns, one slot
+    numbering and one ``page_size`` serve them all.  The flat fields
+    below describe the ONE-class cache (every layer keeps the same entry
+    for the whole context: ``kind="kv"`` and ``kind="latent"``);
+    :meth:`of_classes` builds a cache of several.
 
     ``num_pages`` counts PHYSICAL pool pages (page 0 is the reserved
     null page, so ``num_pages - 1`` are allocatable).  ``max_seqs`` is
@@ -134,8 +181,32 @@ class KVCacheConfig:
     kind: str = "kv"
     latent_dim: int = 0
     index_dim: int = 0
+    classes: Tuple[PageClass, ...] = ()
+
+    @classmethod
+    def of_classes(cls, classes, *, page_size: int, max_seqs: int,
+                   dtype: Any = jnp.bfloat16) -> "KVCacheConfig":
+        """A cache of several page classes.  The slot's token bound
+        (``max_len``) is that of its classes that keep the whole
+        context; the flat fields take the first class's entry shape and
+        the totals."""
+        classes = tuple(classes)
+        whole = [c.pages_per_seq for c in classes if not c.window]
+        if not whole:
+            raise ValueError(
+                "a cache of window classes only has no token bound: "
+                "give it one class with window=0")
+        first = classes[0]
+        return cls(
+            num_layers=sum(len(c.layers) for c in classes),
+            num_heads=first.num_heads, head_dim=first.head_dim,
+            num_pages=sum(c.num_pages for c in classes),
+            page_size=page_size, max_seqs=max_seqs,
+            pages_per_seq=min(whole), dtype=dtype, classes=classes)
 
     def __post_init__(self):
+        if self.classes:
+            self._check_classes()
         if self.num_pages < 2:
             raise ValueError(
                 "num_pages must be >= 2 (page 0 is the reserved null "
@@ -159,6 +230,58 @@ class KVCacheConfig:
                     "num_heads=1, head_dim=latent_dim")
             if self.quantized:
                 raise ValueError("latent pools are not quantized")
+
+    def _check_classes(self):
+        names = [c.name for c in self.classes]
+        layers = sorted(l for c in self.classes for l in c.layers)
+        if len(set(names)) != len(names) or any("." in n for n in names):
+            raise ValueError(f"page classes need distinct plain names: "
+                             f"{names}")
+        if layers != list(range(len(layers))) \
+                or len(layers) != self.num_layers:
+            raise ValueError(
+                f"page classes must hold each of the {self.num_layers} "
+                f"layers once, got {layers}")
+        if self.quantized or self.kind != "kv":
+            raise ValueError(
+                "a cache of page classes stores plain K/V entries")
+        for c in self.classes:
+            if c.kind != "kv":
+                raise ValueError(
+                    f"class {c.name!r}: a latent class beside others is "
+                    "not built (its pools have other keys)")
+            if c.num_pages < 2 or c.pages_per_seq < 1:
+                raise ValueError(f"class {c.name!r}: needs >= 2 pages "
+                                 "and >= 1 page a slot")
+            if c.window and c.pages_per_seq * self.page_size \
+                    < c.window + self.page_size:
+                raise ValueError(
+                    f"class {c.name!r}: a ring of {c.pages_per_seq} "
+                    f"pages cannot hold a window of {c.window} tokens "
+                    "and one page of slack")
+
+    @property
+    def page_classes(self) -> Tuple[PageClass, ...]:
+        """The cache's classes; a flat config is its one class."""
+        if self.classes:
+            return self.classes
+        return (PageClass(
+            name=self.kind, layers=tuple(range(self.num_layers)),
+            num_pages=self.num_pages, pages_per_seq=self.pages_per_seq,
+            num_heads=self.num_heads, head_dim=self.head_dim,
+            kind=self.kind, latent_dim=self.latent_dim,
+            index_dim=self.index_dim),)
+
+    @property
+    def table_columns(self) -> Tuple[Tuple[int, int], ...]:
+        """Each class's ``[lo, hi)`` columns of the page table (the
+        classes' rows side by side, so that ONE int32 table ships to the
+        device a step, as before)."""
+        out, lo = [], 0
+        for c in self.page_classes:
+            out.append((lo, lo + c.pages_per_seq))
+            lo += c.pages_per_seq
+        return tuple(out)
 
     @property
     def quantized(self) -> bool:
@@ -310,11 +433,23 @@ class PagedKVCache:
 
     def __init__(self, config: KVCacheConfig):
         self.config = config
-        self.allocator = PageAllocator(config.num_pages)
+        classes = config.page_classes
+        #: one allocator a page class (physical page ids are a class's
+        #: own: they index ITS pool); ``allocator`` is the first one's,
+        #: the whole cache's where there is one class
+        self.allocators = [PageAllocator(c.num_pages) for c in classes]
+        self.allocator = self.allocators[0]
         self.page_table = np.zeros(
-            (config.max_seqs, config.pages_per_seq), np.int32)
+            (config.max_seqs, config.table_columns[-1][1]), np.int32)
         self.lengths = np.zeros((config.max_seqs,), np.int32)
-        self._slot_pages: Dict[int, List[int]] = {}
+        # per class: slot -> the pages it holds, in table order
+        self._class_pages: List[Dict[int, List[int]]] = [
+            {} for _ in classes]
+        self._slot_pages = self._class_pages[0]
+        #: per window class, pages that retired slots had OVERWRITTEN in
+        #: their ring (a page is written again once the slot is a whole
+        #: ring past it): what the class did not have to hold
+        self.overwritten_pages = {c.name: 0 for c in classes if c.window}
         # cumulative page hash -> {"page", "parent" hash, "children"}
         self._prefix: Dict[bytes, Dict[str, Any]] = {}
         # slot -> pages the slot references WITHOUT owning a table-row
@@ -479,13 +614,28 @@ class PagedKVCache:
         so no later admission or eviction can recycle it out from
         under a pending copy."""
         cfg = self.config
+        classes = cfg.page_classes
         if slot in self._slot_pages:
             raise ValueError(f"slot {slot} is already admitted")
         if total_tokens > cfg.max_len:
             raise ValueError(
                 f"sequence of {total_tokens} tokens exceeds the slot "
                 f"bound {cfg.max_len} (pages_per_seq * page_size)")
-        n_pages = cfg.tokens_to_pages(total_tokens)
+        if prompt_tokens is not None and (
+                len(classes) > 1 or classes[0].window):
+            raise ValueError(
+                "the prefix index shares whole-context pages of ONE "
+                "class: a hit over a window class would also need that "
+                "class's last `window` tokens (ROADMAP, R queue)")
+        need = [c.pages_for(total_tokens, cfg.page_size) for c in classes]
+        for c, n, alloc in zip(classes[1:], need[1:], self.allocators[1:]):
+            # all-or-nothing over the classes: nothing is allocated
+            # below unless every further class has its pages too
+            if n > alloc.num_free:
+                raise CacheOutOfPages(
+                    f"class {c.name!r} needs {n} pages, {alloc.num_free} "
+                    f"free (pool {alloc.num_pages}, 1 reserved)")
+        n_pages = need[0]
 
         matched_pages: List[int] = []
         matched_tokens, cow_src, hashes = 0, None, None
@@ -528,8 +678,12 @@ class PagedKVCache:
             # for as long as the slot exists
             self._extra_refs[slot] = [cow_src]
         self._slot_pages[slot] = pages
-        row = np.zeros((cfg.pages_per_seq,), np.int32)
+        row = np.zeros((self.page_table.shape[1],), np.int32)
         row[: len(pages)] = pages
+        for i, (lo, _) in enumerate(cfg.table_columns[1:], 1):
+            more = self.allocators[i].alloc(need[i])
+            self._class_pages[i][slot] = more
+            row[lo: lo + len(more)] = more
         self.page_table[slot] = row
         self.lengths[slot] = 0
         return AdmitResult(
@@ -543,14 +697,25 @@ class PagedKVCache:
         hold stay allocated) and null its table row (so a stale read
         through the old row hits the null page, never another
         request's data)."""
-        pages = self._slot_pages.pop(slot)
-        self.allocator.free(pages)
+        cfg = self.config
+        for c, alloc, held in zip(cfg.page_classes, self.allocators,
+                                  self._class_pages):
+            alloc.free(held.pop(slot))
+            if c.window:
+                self.overwritten_pages[c.name] += max(
+                    cfg.tokens_to_pages(int(self.lengths[slot]))
+                    - c.pages_per_seq, 0)
         self.allocator.free(self._extra_refs.pop(slot, []))
         self.page_table[slot] = 0
         self.lengths[slot] = 0
 
     def active_slots(self) -> List[int]:
         return sorted(self._slot_pages)
+
+    def pages_in_use(self) -> Dict[str, int]:
+        """Allocated pages of each class, by its name."""
+        return {c.name: a.num_pages - 1 - a.num_free
+                for c, a in zip(self.config.page_classes, self.allocators)}
 
     def compat_key(self) -> Tuple:
         """The cache-config family two pools must share for pages to
@@ -566,6 +731,11 @@ class PagedKVCache:
                cfg.kv_block)
         if cfg.kind != "kv":
             key += (cfg.kind, cfg.latent_dim, cfg.index_dim)
+        for c in cfg.classes:
+            # a class's pages move only into the same class: its layers,
+            # its entry, and for a ring its size (a position's column)
+            key += ((c.name, c.layers, c.num_heads, c.head_dim, c.window,
+                     c.pages_per_seq if c.window else 0),)
         return key
 
     def device_tables(self) -> Tuple[jnp.ndarray, jnp.ndarray]:
@@ -589,6 +759,13 @@ def init_pools(config: KVCacheConfig) -> Dict[str, jnp.ndarray]:
     page_size, latent_row_dim)`` and ``kidx`` of ``(..., index_dim)`` — a
     token's entry is one row, shared by all heads."""
     cfg = config
+    if cfg.classes:
+        # a class's pool under its own name: "<class>.k" / "<class>.v",
+        # (its layers, ITS pages, heads, page_size, head_dim)
+        return {f"{c.name}.{kv}": jnp.zeros(
+            (len(c.layers), c.num_pages, c.num_heads, cfg.page_size,
+             c.head_dim), cfg.dtype)
+            for c in cfg.classes for kv in ("k", "v")}
     if cfg.kind == "latent":
         lead = (cfg.num_layers, cfg.num_pages, cfg.page_size)
         return {"ckv": jnp.zeros(lead + (cfg.latent_row_dim,), cfg.dtype),
@@ -743,8 +920,12 @@ def write_targets(
     positions: jnp.ndarray,
     valid: jnp.ndarray,
     page_size: int,
+    ring: int = 0,
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """Physical ``(pages, offsets)`` for token ``positions``.
+    """Physical ``(pages, offsets)`` for token ``positions``.  With
+    ``ring`` the table (a window class's columns, ``ring`` wide) is
+    walked modulo its width: position ``p`` lives in column ``(p //
+    page_size) % ring``.
 
     ``page_table`` is one slot's row ``(pages_per_seq,)`` (prefill:
     ``positions`` are the prompt's ``(n,)`` token indices) or the full
@@ -761,6 +942,8 @@ def write_targets(
     row can never clamp INTO a live slot's committed pages)."""
     positions = positions.astype(jnp.int32)
     idx = positions // page_size
+    if ring:
+        idx = idx % ring
     if page_table.ndim == 1:
         phys = jnp.take(page_table, idx)
     elif idx.ndim == 1:
@@ -866,3 +1049,51 @@ def write_latent_tokens(
     out["kidx"] = pools["kidx"].at[layer, pages, offsets].set(
         kidx_new.astype(pools["kidx"].dtype))
     return out
+
+
+def write_class_rows(
+    pool: jnp.ndarray,
+    base,
+    new: jnp.ndarray,
+    pages: jnp.ndarray,
+    offsets: jnp.ndarray,
+) -> jnp.ndarray:
+    """One token a slot into ONE stacked pool of a page class
+    (``(layers, pages, heads, page_size, d)``; :func:`init_pools` with
+    ``classes``): row ``new[i]`` (heads, d) goes to page ``base +
+    pages[i]``, offset ``offsets[i]``, where ``base`` is the layer's
+    first page in the pool seen as one run of pages (``layer_in_class *
+    num_pages``) — no layer's pool is sliced out of the stack.
+
+    The slot's current page is read, the one row replaced and the page
+    written back: a gather and a scatter along the page axis alone.  A
+    scatter indexed at ``[page, :, offset, :]`` makes the v5e compiler
+    re-lay the WHOLE pool out around it and back for the decode kernel
+    (four copies of a 2 GB pool a step); 24 pages a layer are 6 MB.
+    Idle slots all target the class's null page: last writer wins."""
+    flat = pool.reshape((-1,) + pool.shape[2:])
+    idx = base + pages.astype(jnp.int32)
+    here = (jnp.arange(pool.shape[3], dtype=jnp.int32)[None]
+            == offsets.astype(jnp.int32)[:, None])[:, None, :, None]
+    page = jnp.where(here, new.astype(pool.dtype)[:, :, None, :], flat[idx])
+    return flat.at[idx].set(page).reshape(pool.shape)
+
+
+def write_class_pages(
+    pool: jnp.ndarray,
+    base,
+    new: jnp.ndarray,
+    pages: jnp.ndarray,
+) -> jnp.ndarray:
+    """WHOLE pages into one stacked pool of a page class: ``new``
+    (n * page_size, heads, d) are ``n`` pages' tokens in order (a
+    prefill chunk, page-aligned), ``pages`` (n,) their physical targets
+    (the null page for a page that holds no real token).  One scatter
+    along the page axis.  Rows of a page past the prompt's end are
+    written too: they sit at positions no query sees before a decode
+    step has written them."""
+    flat = pool.reshape((-1,) + pool.shape[2:])
+    h, ps, d = pool.shape[2:]
+    blocks = jnp.moveaxis(new.astype(pool.dtype).reshape(-1, ps, h, d), 2, 1)
+    return flat.at[base + pages.astype(jnp.int32)].set(blocks).reshape(
+        pool.shape)

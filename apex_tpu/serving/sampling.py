@@ -24,7 +24,8 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-__all__ = ["greedy", "sample", "spec_accept", "spec_accept_tree"]
+__all__ = ["greedy", "sample", "advance_slots", "spec_accept",
+           "spec_accept_tree"]
 
 _NEG_INF = -1e30
 
@@ -93,6 +94,43 @@ def sample(
     g = jax.random.gumbel(key, x.shape, jnp.float32)
     # floored entries sit at -1e30; a Gumbel draw cannot bridge that
     return jnp.argmax(x + g, axis=-1).astype(jnp.int32)
+
+
+def advance_slots(carry, logits: jnp.ndarray, active: jnp.ndarray, *,
+                  temperature: float = 0.0, top_k: Optional[int] = None,
+                  top_p: Optional[float] = None,
+                  eos_id: Optional[int] = None):
+    """The tail of EVERY model's one-token decode step: sample a token
+    for each live slot from ``logits (slots, vocab)`` and advance the
+    batcher's five per-slot carry entries (``tokens``, ``lengths``,
+    ``steps_left``, ``done``, ``sample_keys``; returned as a dict of
+    just those).  ``active`` is ``~carry["done"]``, which the step has
+    already (it masked its cache writes with it); a slot that is not
+    active is frozen: token, length and budget unchanged.  Sampled slots draw from their own key with
+    the context length AFTER this token folded in — the key schedule the
+    prefill steps share, so a seeded request samples the same stream in
+    any slot at any admission order."""
+    if temperature == 0.0:
+        sampled = sample(logits, None, 0.0)
+    else:
+        ctx = jnp.where(active, carry["lengths"] + 1, 0)
+        subs = jax.vmap(jax.random.fold_in)(carry["sample_keys"], ctx)
+        sampled = jax.vmap(
+            lambda l, k: sample(l[None], k, temperature, top_k, top_p)[0]
+        )(logits, subs)
+    ai = active.astype(jnp.int32)
+    tokens = jnp.where(active, sampled, carry["tokens"])
+    steps_left = carry["steps_left"] - ai
+    eos_hit = ((tokens == eos_id) if eos_id is not None
+               else jnp.zeros_like(active))
+    done = carry["done"] | (active & (eos_hit | (steps_left <= 0)))
+    return {
+        "tokens": tokens,
+        "lengths": carry["lengths"] + ai,
+        "steps_left": steps_left,
+        "done": done,
+        "sample_keys": carry["sample_keys"],
+    }
 
 
 def spec_accept(

@@ -379,6 +379,13 @@ class ContinuousBatcher:
                 "prefix_cache requires chunked prefill (the monolithic "
                 "prefill recomputes every position and cannot skip "
                 "matched chunks)")
+        if prefix_cache and any(c.window
+                                for c in cache.config.page_classes):
+            raise ValueError(
+                "prefix_cache over a cache with a window class is not "
+                "built: a hit would also need the window layers' last "
+                "`window` tokens, which the prefix index does not keep "
+                "(ROADMAP, R queue)")
         if (spec_fn is None) != (speculate_k is None):
             raise ValueError(
                 "speculative decoding needs BOTH spec_fn and "
@@ -1272,6 +1279,10 @@ class ContinuousBatcher:
             return None
         req = m["req"]
         cfg = self.cache.config
+        if cfg.classes:
+            raise ValueError(
+                "handoff stages ONE class's pages: a cache of several "
+                "page classes cannot export a request yet")
         # host length mirror == positions written on device:
         # prompt + committed - 1 (the newest token's K/V lands on the
         # next decode step — the destination runs that step instead)
@@ -1423,7 +1434,7 @@ class ContinuousBatcher:
         :class:`Request`; admitted entries are popped, backpressured
         ones stay."""
         with host_span("serve.pump", turn=self.turns, queued=len(queue),
-                       live_slots=self.live_slots):
+                       live_slots=self.live_slots) as span:
             self.turns += 1
             self._admit(queue)
             if not self._meta and not self._prefilling:
@@ -1434,6 +1445,14 @@ class ContinuousBatcher:
                         f"pool holds: {queue[0].uid!r})")
                 return False
             self._decode_window()
+            if self.cache.config.classes:
+                # per page class: pages held now, and pages retired
+                # slots had overwritten in their rings so far
+                span.set_metadata(
+                    **{f"pages_in_use_{k}": v for k, v in
+                       self.cache.pages_in_use().items()},
+                    **{f"pages_overwritten_{k}": v for k, v in
+                       self.cache.overwritten_pages.items()})
         return bool(self._meta or self._prefilling or queue)
 
     # --------------------------------------------------------------- run

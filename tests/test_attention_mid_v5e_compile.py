@@ -81,3 +81,46 @@ def test_forward_and_backward_compile(one_chip, monkeypatch, call):
         *args).compile().as_text()
     assert "tlm.kernel.fmha_mid.fwd" in text
     assert "tlm.kernel.fmha_mid.bwd" in text
+
+
+# The paged decode kernel at the serving cells' sizes (in THIS file: one
+# process may hold the TPU's library).  (slots, query heads, K/V heads,
+# head_dim, table width, window or 0)
+DECODE_CALLS = {
+    "gpt2-345m-decode": (32, 16, 16, 64, 16, 0),
+    "trinity-full-layer": (24, 48, 8, 128, 200, 0),
+    "trinity-window-layer": (24, 48, 8, 128, 81, 4096),
+}
+
+
+@pytest.mark.parametrize("call", list(DECODE_CALLS))
+def test_paged_decode_compiles(one_chip, monkeypatch, call):
+    """Grouped heads as further query rows, the walk from a per-slot
+    first position round a ring and the fused rotation lay out for
+    Mosaic at the cell's widths."""
+    from apex_tpu.ops import attention_decode
+
+    b, hq, hkv, d, width, window = DECODE_CALLS[call]
+    page = 64
+    monkeypatch.setattr(attention_decode, "_interpret", lambda: False)
+
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    pool = sds((1 + b * width, hkv, page, d), jnp.bfloat16)
+    rope = sds((b, 1, d // 2), jnp.float32)
+
+    def step(q, k, v, table, lengths, cos, sin):
+        return attention_decode.fmha_decode(
+            q, k, v, table, lengths, rope=(cos, sin),
+            num_kv_heads=hkv if hkv != hq else None,
+            first=jnp.maximum(lengths - window, 0) if window else None,
+            max_pages=window // page + 1 if window else None,
+            implementation="pallas")
+
+    text = jax.jit(step).lower(
+        sds((b, hq, 1, d), jnp.bfloat16), pool, pool,
+        sds((b, width), jnp.int32), sds((b,), jnp.int32), rope, rope,
+    ).compile().as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert "tlm.kernel.paged_decode" in text
