@@ -24,16 +24,35 @@ through a small int32 table.  The kernel consumes that layout directly:
   ``page_size`` consecutive tokens of ONE sequence for ALL heads, so a
   single page DMA feeds every head's dot (the per-head trailing
   ``(page_size, d)`` tile is Mosaic-native);
-- **scalar-prefetch page walk** — the grid is ``(b, h_blocks,
-  num_logical_pages)`` and the k/v index maps read the page table from
-  SMEM (``pltpu.PrefetchScalarGridSpec``), so the data-dependent gather
-  is a DMA address computation, never a materialized ``take``;
+- **scalar-prefetch page walk, several pages a grid step** — the grid
+  is ``(b, h_blocks, steps)`` and a step covers ``P`` consecutive
+  logical pages of the sequence; the page table is read from SMEM
+  (``pltpu.PrefetchScalarGridSpec``), so the data-dependent gather is a
+  DMA address computation, never a materialized ``take``.  A grid step
+  costs ~0.3-0.9 us whatever it fetches, so at ONE 64-key page a step
+  the walk is bound by the grid, not by HBM (PERF.md section 6, PR 32
+  and PR 34).  The pools stay in HBM and the kernel copies a step's
+  LIVE pages itself into ONE ``(P, block_h, page_size, d)`` VMEM tile
+  (the next live step's copies in flight under this step's work, across
+  sequences too; pages past a sequence's length are neither fetched nor
+  worked on), so a head does one ``(rows, d) x (d, P * page_size)``
+  product, one online-softmax update and one ``(rows, P * page_size) x
+  (P * page_size, d)`` product a step.  ``P`` is computed from the
+  shapes (:func:`_pages_per_step`: up to ``FMHA_DECODE_STEP_KEYS`` keys,
+  at least ``FMHA_DECODE_MIN_STEPS`` steps a table, within
+  ``FMHA_DECODE_TILE_BYTES``); the traced body and its lowering are the
+  same size at every ``P`` (the copies are loops over the step's pages,
+  not ``P`` copies of anything).  Two kinds of pool walk ONE page a
+  step, handed to the same body by the pipeline's block specs as
+  before: heads narrower than 128 lanes (gpt2's 64: Mosaic cuts a
+  copy's source out of an HBM array only along whole lane tiles) and
+  int8 pools (their scale planes are narrower still);
 - **head packing** (PR 1/PR 5's ``block_bh`` trick at decode shapes):
   all of a sequence's heads (grouped ``block_h`` at a time) ride one
-  program and one page fetch, their tiny per-head dots issued
-  back-to-back from one unrolled body so the pipeline never drains
-  between (b, h) pairs — the s_q=1 grid that would otherwise idle the
-  VPU stays saturated;
+  program and one tile, the per-head body a loop over ``block_h``
+  traced ONCE (the query is prepared, the step's mask built and the
+  tile fetched for all of them together) -- the s_q=1 grid that would
+  otherwise idle the VPU stays saturated;
 - **ONE kernel for fp32/bf16 and int8 pages**: int8 pools carry per
   ``(token, kv_block)`` fp32 scales (``ops/quantization.py``'s
   row-block machinery) and the kernel dequantizes each page in VMEM
@@ -45,9 +64,13 @@ through a small int32 table.  The kernel consumes that layout directly:
   work is pure elementwise multiply-add under the page stream; K is
   rotated once at cache-write time and never again);
 - **partially-filled pages**: per-sequence ``lengths`` mask the tail
-  page exactly, and logical pages past a sequence's length are skipped
-  (``pl.when``) — unallocated table entries point at physical page 0,
-  so the skipped DMA is always addressable.
+  page exactly, and steps past a sequence's length are skipped
+  (``pl.when``).  A tile's places past the last live page keep what an
+  earlier step left there: their scores are masked by position, and the
+  V tiles start as zeros so that a masked weight's zero never meets a
+  non-finite row.  On the one-page path a step past the length repeats
+  the last page's block (no further fetch); unallocated table entries
+  point at physical page 0, so every formable address is valid.
 
 Dispatch: serving callers hold a page table and call :func:`fmha_decode`
 directly; ``flash_attention(implementation="decode")`` routes contiguous
@@ -86,16 +109,34 @@ _LANES = 128
 
 #: How many heads one grid program packs (the decode analog of the
 #: short/mid kernels' block_bh): each program holds block_h heads' q
-#: resident and unrolls their per-page dots back-to-back over one page
-#: DMA.  16 matches FMHA_SHORT_MAX_BLOCK_BH's measured code-size bound.
+#: resident and runs their per-step dots back-to-back over one tile of
+#: pages.  16 matches FMHA_SHORT_MAX_BLOCK_BH's measured code-size bound.
 FMHA_DECODE_BLOCK_H = 16
 
 #: VMEM-residency bound on the per-program query rows (block_h * sq):
-#: the acc/m/l scratch buffers are (block_h*sq, d|128) fp32, so at the
-#: chunked-prefill sq's (64/256) the s_q=1 head packing must shrink —
-#: 512 rows keeps the three buffers under ~1 MB at d=128 while leaving
-#: the s_q=1 default (block_h=16) untouched.
+#: the prepared q and the acc scratch are (block_h, sq, d) (m and l one
+#: lane-padded column a row), so at the chunked-prefill sq's (64/256)
+#: the s_q=1 head packing must shrink — 512 rows keeps them under ~1 MB
+#: at d=128 while leaving the s_q=1 default (block_h=16) untouched.
 FMHA_DECODE_MAX_ROWS = 512
+
+#: Keys one grid step of the page walk covers at most.  A grid step
+#: costs ~0.3-0.9 us whatever it fetches, so a step of ONE 64-key page
+#: is bound by the grid and not by HBM; at 512 keys (8 pages of 64) the
+#: walk reaches the knee (docs/attention.md "Pages a grid step").
+FMHA_DECODE_STEP_KEYS = 512
+
+#: Steps a table is cut into at least, however short it is: a slot's
+#: last step computes over all its places, live or not, so the fewer
+#: pages a batch's slots hold of a short table (gpt2's 16) the smaller
+#: the step that wastes least.
+FMHA_DECODE_MIN_STEPS = 8
+
+#: VMEM the K and V page tiles may take: 2 operands x 2 tiles (one read,
+#: one in flight) x pages x block_h x page_size x d x itemsize, beside the residents the row budget above bounds (q, its
+#: rope planes, the output block, the prepared q, acc, m, l: under 3 MB
+#: at 512 rows of d=128).  Half of the 16 MB a v5e kernel gets.
+FMHA_DECODE_TILE_BYTES = 8 * 2**20
 
 
 class _DecodeConfig(NamedTuple):
@@ -114,6 +155,8 @@ class _DecodeConfig(NamedTuple):
     group: int = 1          # query heads a K/V head serves
     has_first: bool = False  # per-sequence first position, ring table
     table_pages: int = 0    # the page table's width (has_first only)
+    pages: int = 1          # logical pages one grid step covers
+    copies: bool = False    # the kernel copies a step's pages itself
 
 
 # ---------------------------------------------------------------------------
@@ -257,6 +300,81 @@ def _grouped_window_reference(q, k, v, lengths, first, causal, scale,
 # ---------------------------------------------------------------------------
 
 
+def _walk(cfg, len_ref, first_ref, bb):
+    """Slot ``bb``'s walk: the logical page it starts at and how many
+    pages from there hold something a query may see."""
+    ln = len_ref[bb]
+    start = first_ref[bb] // cfg.page_size if cfg.has_first else 0
+    last = (jnp.maximum(ln, 1) - 1) // cfg.page_size
+    return start, jnp.where(ln > 0, jnp.maximum(last - start + 1, 0), 0)
+
+
+def _stream_tiles(cfg, walk, at, grid, pt_ref, pools, tiles, sem, state):
+    """The copies of a step's pages (``cfg.copies``) of program
+    ``at = (b, hb, step)`` in ``grid``: wait for this step's tile, with
+    the next live step's in flight under it.  Returns the tile to read.
+    ``state`` (SMEM): [the tile the next live step reads, whether its
+    copies are already in flight]."""
+    (b, hb, step), (n_b, n_hb) = at, grid[:2]
+    P, bh = cfg.pages, cfg.block_h
+
+    def page_copies(hh, page, buf, j):
+        # pool page ``page``'s block_h heads -> place j of tile buf
+        return [pltpu.make_async_copy(
+            pool.at[page, pl.ds(hh * bh, bh)], tile.at[buf, j],
+            sem.at[i, buf])
+            for i, (pool, tile) in enumerate(zip(pools, tiles))]
+
+    def live_pages(bb, st):
+        start, live = walk(bb)
+        return start + st * P, jnp.clip(live - st * P, 0, P)
+
+    def fetch(bb, hh, st, buf):
+        # a step's live pages only: a table entry past them is never
+        # read, and a dead place keeps what an earlier step left there
+        first_page, n = live_pages(bb, st)
+
+        def one(j, carry):
+            page = first_page + j
+            if cfg.has_first:
+                page = page % cfg.table_pages       # the ring's column
+            for copy in page_copies(hh, pt_ref[bb, page], buf, j):
+                copy.start()
+            return carry
+
+        jax.lax.fori_loop(0, n, one, 0)
+
+    cur = state[0]
+
+    @pl.when(state[1] == 0)
+    def _():
+        fetch(b, hb, step, cur)
+
+    # next: this walk's next step, or the first step of the next program
+    # (the next head block, then the next slot) if that one has any
+    more = live_pages(b, step + 1)[1] > 0
+    wrap = hb + 1 == n_hb
+    nb = jnp.where(more | ~wrap, b, jnp.minimum(b + 1, n_b - 1))
+    nh = jnp.where(more, hb, jnp.where(wrap, 0, hb + 1))
+    ns = jnp.where(more, step + 1, 0)
+    go = more | ((~wrap | (b + 1 < n_b)) & (live_pages(nb, 0)[1] > 0))
+
+    @pl.when(go)
+    def _():
+        fetch(nb, nh, ns, 1 - cur)
+
+    state[0] = 1 - cur
+    state[1] = go.astype(jnp.int32)
+
+    def wait(j, carry):
+        for copy in page_copies(hb, 0, cur, j):
+            copy.wait()
+        return carry
+
+    jax.lax.fori_loop(0, live_pages(b, step)[1], wait, 0)
+    return cur
+
+
 def _decode_kernel(*refs, cfg: _DecodeConfig):
     pt_ref, len_ref = refs[:2]
     rest = list(refs[2:])
@@ -265,60 +383,107 @@ def _decode_kernel(*refs, cfg: _DecodeConfig):
     qrot_ref = cos_ref = sin_ref = None
     if cfg.has_rope:
         qrot_ref, cos_ref, sin_ref = rest.pop(0), rest.pop(0), rest.pop(0)
+    # one page's block from the pipeline, or (copies) the pool in HBM
     k_ref, v_ref = rest.pop(0), rest.pop(0)
     ks_ref = vs_ref = None
     if cfg.has_scales:
         ks_ref, vs_ref = rest.pop(0), rest.pop(0)
-    o_ref, acc_ref, m_ref, l_ref = rest
+    o_ref, qs_ref, acc_ref, m_ref, l_ref = rest[:5]
 
-    b, hb, p = pl.program_id(0), pl.program_id(1), pl.program_id(2)
-    step = p
-    sq, ps = cfg.sq, cfg.page_size
-    ln = len_ref[b]
-    if cfg.has_first:
-        # the walk starts at the page that holds the first position a
-        # query may see: grid step ``step`` is LOGICAL page first // ps
-        # + step (the index maps turn it into a ring column)
-        first = first_ref[b]
-        p = first // ps + step
+    at = b, hb, step = tuple(pl.program_id(i) for i in range(3))
+    grid = tuple(pl.num_programs(i) for i in range(3))
+    sq, ps, P = cfg.sq, cfg.page_size, cfg.pages
     # a K/V head's rows are its ``group`` query heads' sq rows each
     rows = sq * cfg.group
     native = cfg.group > 1 or cfg.has_first
+    walk = functools.partial(_walk, cfg, len_ref, first_ref)
+    start, live = walk(b)
+    ln = len_ref[b]
+
+    if cfg.copies:
+        k_tile, v_tile, sem, state = rest[5:]
+
+        @pl.when((b == 0) & (hb == 0) & (step == 0))
+        def _first_program():
+            # a dead place's scores are masked whatever it holds; its V
+            # rows have to be finite for the masked weights' zeros
+            state[0] = 0
+            state[1] = 0
+            v_tile[...] = jnp.zeros_like(v_tile)
 
     @pl.when(step == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
         m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
+        # the query once a walk, not once a page: q*cos +
+        # rotate_half(q)*sin (the rotation's FLOPs run in-kernel; the
+        # half-swap data shuffle happened once in the wrapper, XLA fuses
+        # it into the q projection epilogue), the softmax scale, and the
+        # score product's operand type
+        qh = q_ref[0].astype(jnp.float32)                # (bh, rows, d)
+        if cfg.has_rope:
+            qh = (qh * cos_ref[0].astype(jnp.float32)
+                  + qrot_ref[0].astype(jnp.float32)
+                  * sin_ref[0].astype(jnp.float32))
+        qs_ref[...] = (qh * cfg.sm_scale).astype(qs_ref.dtype)
 
-    # logical pages at or past this sequence's length hold nothing this
-    # query may attend to — skip their compute entirely (the decode
-    # analog of the mid kernel's causal block-skip; with variable
-    # lengths in a batch the grid covers the longest sequence and short
-    # ones skip the difference)
-    @pl.when(p * ps < ln)
+    # steps past the slot's last live page do no work and fetch nothing
+    # (with variable lengths in a batch the grid covers the longest
+    # walk and short ones skip the difference)
+    @pl.when(step * P < live)
     def _body():
         d = q_ref.shape[-1]
-        for hi in range(cfg.block_h):
-            qh = q_ref[0, hi].astype(jnp.float32)            # (sq, d)
-            if cfg.has_rope:
-                # q*cos + rotate_half(q)*sin: the rotation's FLOPs run
-                # in-kernel under the page stream; the half-swap data
-                # shuffle happened once in the wrapper (XLA fuses it
-                # into the q projection epilogue)
-                qh = (qh * cos_ref[0, hi].astype(jnp.float32)
-                      + qrot_ref[0, hi].astype(jnp.float32)
-                      * sin_ref[0, hi].astype(jnp.float32))
-            qh = qh * cfg.sm_scale
-            if native:
-                # grouped / windowed walks hand the MXU the pages as
-                # they are stored (fp32 accumulation): no per-page
-                # widening pass on the VPU under a 2-FLOPs-a-byte stream
-                kh, vh = k_ref[0, hi], v_ref[0, hi]
-                qh = qh.astype(kh.dtype)
-            else:
-                kh = k_ref[0, hi].astype(jnp.float32)        # (ps, d)
-                vh = v_ref[0, hi].astype(jnp.float32)
+        if cfg.copies:
+            cur = _stream_tiles(cfg, walk, at, grid, pt_ref, (k_ref, v_ref),
+                                (k_tile, v_tile), sem, state)
+            # the step's pages as ONE (P * ps, d) tile a head
+            page_rows = lambda hi: (
+                k_tile[cur, :, hi].reshape(P * ps, d),
+                v_tile[cur, :, hi].reshape(P * ps, d))
+        else:
+            page_rows = lambda hi: (k_ref[0, hi], v_ref[0, hi])
+
+        # the step's keys are P consecutive logical pages: one mask for
+        # every head
+        shape = (rows, P * ps)
+        k_pos = (start + step * P) * ps + jax.lax.broadcasted_iota(
+            jnp.int32, shape, 1)
+        if cfg.ancestor is not None:
+            # tree verify: the last sq cache slots are the candidate
+            # rows; row i sees fresh slot j iff the STATIC ancestor
+            # matrix says so, plus the whole committed prefix.  Each
+            # row's allowed-column set is packed into an int32 bitmask
+            # selected by row iota (Pallas kernels cannot capture
+            # constant arrays), so the mask is sq scalar selects + one
+            # variable shift.
+            fresh = k_pos - (ln - sq)
+            row = jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+            bits = jnp.zeros_like(row)
+            for i in range(sq):
+                rb = sum(int(cfg.ancestor[i][j]) << j for j in range(sq))
+                bits = jnp.where(row == i, rb, bits)
+            fr = jnp.clip(fresh, 0, sq - 1)
+            tree = (jnp.right_shift(bits, fr) & 1) == 1
+            mask = (fresh < 0) | ((fresh >= 0) & (fresh < sq) & tree)
+        elif cfg.causal:
+            q_row = jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+            if cfg.group > 1:
+                q_row = q_row % sq          # rows are (query head, token)
+            mask = k_pos <= ln - sq + q_row
+        else:
+            mask = k_pos < ln
+        if cfg.has_first:
+            mask = mask & (k_pos >= first_ref[b])
+
+        def head(hi, carry):
+            qh = qs_ref[hi]                                  # (rows, d)
+            kh, vh = page_rows(hi)
+            if not native:
+                # (grouped / windowed walks hand the MXU the pages as
+                # they are stored, fp32 accumulation: no widening pass
+                # on the VPU under a 2-FLOPs-a-byte stream)
+                kh, vh = kh.astype(jnp.float32), vh.astype(jnp.float32)
             if cfg.has_scales:
                 kh = kh * jnp.repeat(
                     ks_ref[0, hi], cfg.kv_block, axis=1)[:, :d]
@@ -327,67 +492,33 @@ def _decode_kernel(*refs, cfg: _DecodeConfig):
             s = jax.lax.dot_general(
                 qh, kh, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32,
-            )                                                 # (sq, ps)
-            k_pos = p * ps + jax.lax.broadcasted_iota(
-                jnp.int32, s.shape, 1)
-            if cfg.ancestor is not None:
-                # tree verify: the last sq cache slots are the
-                # candidate rows; row i sees fresh slot j iff the
-                # STATIC ancestor matrix says so, plus the whole
-                # committed prefix.  Each row's allowed-column set is
-                # packed into an int32 bitmask selected by row iota
-                # (Pallas kernels cannot capture constant arrays), so
-                # the mask is sq scalar selects + one variable shift —
-                # VPU work that hides under the page DMA.
-                fresh = k_pos - (ln - sq)
-                row = jax.lax.broadcasted_iota(
-                    jnp.int32, s.shape, 0)
-                bits = jnp.zeros_like(row)
-                for i in range(sq):
-                    rb = sum(int(cfg.ancestor[i][j]) << j
-                             for j in range(sq))
-                    bits = jnp.where(row == i, rb, bits)
-                fr = jnp.clip(fresh, 0, sq - 1)
-                tree = (jnp.right_shift(bits, fr) & 1) == 1
-                mask = (fresh < 0) | (
-                    (fresh >= 0) & (fresh < sq) & tree)
-            elif cfg.causal:
-                if cfg.group > 1:
-                    # rows are (query head, token)
-                    q_pos = ln - sq + jax.lax.broadcasted_iota(
-                        jnp.int32, s.shape, 0) % sq
-                else:
-                    q_pos = ln - sq + jax.lax.broadcasted_iota(
-                        jnp.int32, s.shape, 0)
-                mask = k_pos <= q_pos
-            else:
-                mask = k_pos < ln
-            if cfg.has_first:
-                mask = mask & (k_pos >= first)
+            )                                            # (rows, P * ps)
             s = jnp.where(mask, s, _NEG_INF)
-            r0, r1 = hi * rows, (hi + 1) * rows
-            m_prev = m_ref[r0:r1, 0:1]
-            l_prev = l_ref[r0:r1, 0:1]
+            m_prev, l_prev = m_ref[hi], l_ref[hi]            # (rows, 1)
             m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
             pexp = jnp.where(mask, jnp.exp(s - m_new), 0.0)
             corr = jnp.exp(m_prev - m_new)
-            l_new = l_prev * corr + jnp.sum(pexp, axis=-1, keepdims=True)
-            acc_ref[r0:r1] = acc_ref[r0:r1] * corr + jax.lax.dot_general(
+            l_ref[hi] = l_prev * corr + jnp.sum(pexp, axis=-1, keepdims=True)
+            m_ref[hi] = m_new
+            acc_ref[hi] = acc_ref[hi] * corr + jax.lax.dot_general(
                 pexp.astype(vh.dtype) if native else pexp, vh,
                 (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32,
             )
-            m_ref[r0:r1] = jnp.broadcast_to(m_new, (rows, m_ref.shape[1]))
-            l_ref[r0:r1] = jnp.broadcast_to(l_new, (rows, l_ref.shape[1]))
+            return carry
 
-    @pl.when(step == cfg.num_pages - 1)
+        # one traced body; unrolled when lowered, so that Mosaic's
+        # scheduler overlaps the heads (rolled, Trinity's walks took
+        # 1.3x as long: tools/paged_decode_ablation.py, heads_rolled)
+        jax.lax.fori_loop(0, cfg.block_h, head, 0, unroll=True)
+
+    @pl.when(step == grid[2] - 1)
     def _finalize():
         # the softmax-normalization tail, fused (the operation-fusion
         # paper's point: this divide never round-trips through HBM).
         # A zero-length sequence (an idle serving slot) clamps l and
         # writes garbage the caller masks.
-        ll = jnp.maximum(l_ref[:, 0:1], 1e-30)
-        o_ref[0] = (acc_ref[...] / ll).reshape(o_ref.shape[1:]).astype(
+        o_ref[0] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)).astype(
             o_ref.dtype)
 
 
@@ -396,40 +527,50 @@ def _decode_pallas(q, q_rot, cos, sin, k_pages, v_pages, k_scales,
                    first=None):
     """``q`` (and the rope planes) come as ``(b, h_kv, group * sq, d)``:
     a K/V head's query heads are further ROWS of its program."""
-    b, h, sq, d = q.shape
-    ps = cfg.page_size
+    b, h, rows, d = q.shape
+    ps, P, bh = cfg.page_size, cfg.pages, cfg.block_h
     nb = k_scales.shape[-1] if cfg.has_scales else 0
-    bh = cfg.block_h
-    n_hb = h // bh
+    native = cfg.group > 1 or cfg.has_first
 
     def qmap(bb, hb, p, *scalars):
         return (bb, hb, 0, 0)
 
     def kvmap(bb, hb, p, pt, ln, *fs):
-        if not cfg.has_first:
-            return (pt[bb, p], hb, 0, 0)
-        # logical page first // ps + p, held back at the sequence's last
-        # page (steps past it repeat that block: no further fetch), in
-        # the ring column it lives in
-        last = (jnp.maximum(ln[bb], 1) - 1) // ps
-        page = jnp.minimum(fs[0][bb] // ps + p, last)
-        return (pt[bb, page % cfg.table_pages], hb, 0, 0)
+        # one page a step: logical page (first // ps +) p, held back at
+        # the sequence's last page (steps past it repeat that block: no
+        # further fetch), in the ring column it lives in
+        start = fs[0][bb] // ps if cfg.has_first else 0
+        page = jnp.minimum(start + p, (jnp.maximum(ln[bb], 1) - 1) // ps)
+        if cfg.has_first:
+            page = page % cfg.table_pages
+        return (pt[bb, page], hb, 0, 0)
 
-    in_specs = [pl.BlockSpec((1, bh, sq, d), qmap)]
+    in_specs = [pl.BlockSpec((1, bh, rows, d), qmap)]
     inputs = [q]
     if cfg.has_rope:
-        in_specs += [pl.BlockSpec((1, bh, sq, d), qmap)] * 3
+        in_specs += [pl.BlockSpec((1, bh, rows, d), qmap)] * 3
         inputs += [q_rot, cos, sin]
-    in_specs += [
-        pl.BlockSpec((1, bh, ps, d), kvmap),
-        pl.BlockSpec((1, bh, ps, d), kvmap),
+    scratch = [
+        pltpu.VMEM((bh, rows, d), k_pages.dtype if native else jnp.float32),
+        pltpu.VMEM((bh, rows, d), jnp.float32),
+        pltpu.VMEM((bh, rows, 1), jnp.float32),
+        pltpu.VMEM((bh, rows, 1), jnp.float32),
     ]
+    if cfg.copies:
+        # the pools stay in HBM: the kernel copies a step's live pages
+        # into one of two tiles itself
+        in_specs += [pl.BlockSpec(memory_space=pl.ANY)] * 2
+        scratch += [
+            pltpu.VMEM((2, P, bh, ps, d), k_pages.dtype),
+            pltpu.VMEM((2, P, bh, ps, d), v_pages.dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),
+            pltpu.SMEM((2,), jnp.int32),
+        ]
+    else:
+        in_specs += [pl.BlockSpec((1, bh, ps, d), kvmap)] * 2
     inputs += [k_pages, v_pages]
     if cfg.has_scales:
-        in_specs += [
-            pl.BlockSpec((1, bh, ps, nb), kvmap),
-            pl.BlockSpec((1, bh, ps, nb), kvmap),
-        ]
+        in_specs += [pl.BlockSpec((1, bh, ps, nb), kvmap)] * 2
         inputs += [k_scales, v_scales]
 
     scalars = [page_table.astype(jnp.int32), lengths.astype(jnp.int32)]
@@ -437,23 +578,21 @@ def _decode_pallas(q, q_rot, cos, sin, k_pages, v_pages, k_scales,
         scalars.append(first.astype(jnp.int32))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(scalars),
-        grid=(b, n_hb, cfg.num_pages),
+        grid=(b, h // bh, -(-cfg.num_pages // P)),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, bh, sq, d), qmap),
-        scratch_shapes=[
-            pltpu.VMEM((bh * sq, d), jnp.float32),
-            pltpu.VMEM((bh * sq, _LANES), jnp.float32),
-            pltpu.VMEM((bh * sq, _LANES), jnp.float32),
-        ],
+        out_specs=pl.BlockSpec((1, bh, rows, d), qmap),
+        scratch_shapes=scratch,
     )
     return pl.pallas_call(
         functools.partial(_decode_kernel, cfg=cfg),
         grid_spec=grid_spec,
-        out_shape=shape_struct((b, h, sq, d), q.dtype, q, k_pages,
+        out_shape=shape_struct((b, h, rows, d), q.dtype, q, k_pages,
                                v_pages),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")
-        ),
+        # a step's copies are started by the step before it, whichever
+        # program that was in: that grid runs in order
+        compiler_params=pltpu.CompilerParams(dimension_semantics=(
+            ("arbitrary",) * 3 if cfg.copies
+            else ("parallel", "parallel", "arbitrary"))),
         interpret=_interpret(),
         name=kernel_name("paged_decode"),
     )(*scalars, *inputs)
@@ -498,6 +637,30 @@ def _pick_block_h(h: int, sq: int = 1) -> int:
     while h % bh:
         bh -= 1
     return bh
+
+
+def _kernel_copies(d: int, has_scales: bool) -> bool:
+    """Whether the kernel copies a step's pages out of the pools itself
+    (or the pipeline hands it one page's block a step, as it always did):
+    Mosaic cuts a copy's source out of an HBM array only along whole
+    128-lane tiles, so a narrower head (gpt2's 64) and an int8 pool's
+    scale planes (``ceil(d / kv_block)`` wide) stay with the pipeline."""
+    return not has_scales and d % _LANES == 0
+
+
+def _pages_per_step(page_size: int, d: int, block_h: int, itemsize: int,
+                    num_pages: int, has_scales: bool) -> int:
+    """Logical pages one grid step of the walk covers, from the shapes:
+    up to ``FMHA_DECODE_STEP_KEYS`` keys, at least
+    ``FMHA_DECODE_MIN_STEPS`` steps over the table, within
+    ``FMHA_DECODE_TILE_BYTES`` of VMEM; one where the pipeline brings the
+    pages (:func:`_kernel_copies`)."""
+    if not _kernel_copies(d, has_scales):
+        return 1
+    page_bytes = block_h * page_size * d * itemsize
+    return max(1, min(FMHA_DECODE_STEP_KEYS // page_size,
+                      -(-num_pages // FMHA_DECODE_MIN_STEPS),
+                      FMHA_DECODE_TILE_BYTES // (4 * page_bytes)))
 
 
 def fmha_decode(
@@ -679,15 +842,19 @@ def fmha_decode(
                 f"{FMHA_DECODE_MAX_ROWS}); chunk the query (sq <= "
                 f"{FMHA_DECODE_MAX_ROWS}) or use implementation='xla'")
         width = page_table.shape[1]
+        num_pages = (width if first is None or max_pages is None
+                     else min(width, int(max_pages)))
         cfg = _DecodeConfig(
             sm_scale=scale, causal=causal, sq=sq, block_h=bh,
-            page_size=k_pages.shape[2],
-            num_pages=(width if first is None or max_pages is None
-                       else min(width, int(max_pages))),
+            page_size=k_pages.shape[2], num_pages=num_pages,
             kv_block=int(kv_block), has_scales=k_scales is not None,
             has_rope=rope is not None, ancestor=ancestor, group=group,
             has_first=first is not None,
             table_pages=width if first is not None else 0,
+            pages=_pages_per_step(
+                k_pages.shape[2], d, bh, k_pages.dtype.itemsize,
+                num_pages, k_scales is not None),
+            copies=_kernel_copies(d, k_scales is not None),
         )
         planes = [q]
         if rope is not None:

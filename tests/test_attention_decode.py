@@ -447,3 +447,107 @@ class TestChunkedPrefill:
                                implementation="pallas")
         np.testing.assert_array_equal(np.asarray(auto),
                                       np.asarray(explicit))
+
+
+# ---------------------------------------------------------------------------
+# Several pages a grid step (PR 34)
+# ---------------------------------------------------------------------------
+
+import os
+import sys
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if REPO not in sys.path:
+    sys.path.insert(0, REPO)
+
+from apex_tpu.ops import attention_decode as ad
+from tools.paged_decode_ablation import parent_call
+
+TREE = ((1, 0, 0, 0), (1, 1, 0, 0), (1, 0, 1, 0), (1, 1, 0, 1))
+
+
+def _variant(name, d, npp, ps=16, b=3, h=2):
+    """A call of every kind ``fmha_decode`` serves beside plain decode:
+    (arguments, keywords), the table ``npp`` pages wide."""
+    key = jax.random.PRNGKey(len(name))
+    q, kp, vp, pt = make_cache(key, 1 + b * npp, h, ps, d, b, npp)
+    top = npp * ps
+    lengths = jnp.array([top - 3, 0, top // 2 + 1], jnp.int32)
+    kw = {}
+    if name in ("sq4", "tree", "rope_sq4"):
+        q = jax.random.normal(key, (b, h, 4, d), jnp.float32)
+        lengths = jnp.maximum(lengths, jnp.array([0, 4, 0]))
+    if name == "tree":
+        kw["ancestor"] = TREE
+    if name in ("rope", "rope_sq4"):
+        pos = lengths[:, None] - q.shape[2] + jnp.arange(q.shape[2])[None]
+        kw["rope"] = rope_cos_sin(jnp.maximum(pos, 0), d)
+    if name == "noncausal":
+        kw["causal"] = False
+    if name == "int8":
+        (kp, ks), (vp, vs) = quant_pages(kp, 32), quant_pages(vp, 32)
+        kw.update(k_scales=ks, v_scales=vs, kv_block=32)
+    return (q, kp, vp, pt, lengths), kw
+
+
+class TestPagesAStep:
+    """The rule's pages a step, and every kind of call at the pages it
+    gives them."""
+
+    @pytest.mark.parametrize("shape,want", [
+        # page, d, block_h, itemsize, table pages, scales -> pages a step
+        ((64, 128, 8, 2, 65, False), 8),     # trinity's window walk
+        ((64, 128, 8, 2, 200, False), 8),    # trinity's full walk
+        ((64, 64, 16, 2, 16, False), 1),     # gpt2: 64 lanes, the pipeline
+        ((64, 128, 16, 2, 16, False), 2),    # a short table, small steps
+        ((64, 128, 8, 2, 20, False), 3),
+        ((64, 128, 8, 1, 200, True), 1),     # int8: scale planes a page
+        ((128, 128, 8, 2, 200, False), 4),   # 512 keys a step
+        ((16, 128, 8, 4, 24, False), 3),
+        ((512, 256, 16, 4, 64, False), 1),   # VMEM: 8 MiB a page block
+        ((64, 256, 16, 4, 200, False), 2),   # VMEM before the key bound
+    ])
+    def test_rule(self, shape, want):
+        assert ad._pages_per_step(*shape) == want
+        page, d, bh, itemsize, _, scales = shape
+        if want > 1:
+            tiles = 2 * 2 * want * bh * page * d * itemsize
+            assert tiles <= ad.FMHA_DECODE_TILE_BYTES
+            assert want * page <= ad.FMHA_DECODE_STEP_KEYS
+
+    @pytest.mark.parametrize("npp", [8, 16, 24])        # 1, 2, 3 pages
+    @pytest.mark.parametrize("name", [
+        "sq4", "tree", "rope", "rope_sq4", "noncausal", "int8"])
+    def test_every_kind_at_the_rules_pages(self, name, npp):
+        args, kw = _variant(name, 128, npp)
+        want = 1 if name == "int8" else npp // 8
+        assert ad._pages_per_step(
+            16, 128, 2, args[1].dtype.itemsize, npp, name == "int8") == want
+        out = fmha_decode(*args, implementation="pallas", **kw)
+        ref = fmha_decode(*args, implementation="xla", **kw)
+        live = np.asarray(args[4]) > 0
+        np.testing.assert_allclose(np.asarray(out)[live],
+                                   np.asarray(ref)[live], atol=2e-5)
+        assert np.isfinite(np.asarray(out)).all()
+
+    @pytest.mark.parametrize("d", [32, 128])
+    @pytest.mark.parametrize("name", [
+        "plain", "sq4", "tree", "rope", "noncausal", "int8"])
+    def test_one_page_a_step_is_the_walk_through_pr33_to_the_bit(
+            self, name, d):
+        """At one page a step the kernel computes what the kernel it
+        replaced computed (kept in tools/paged_decode_ablation.py), bit
+        for bit, in interpret mode."""
+        args, kw = _variant(name, d, 4)
+        assert ad._pages_per_step(
+            16, d, 2, args[1].dtype.itemsize, 4, name == "int8") == 1
+        out = fmha_decode(*args, implementation="pallas", **kw)
+        was = parent_call(*args, **kw)
+        if name == "rope":
+            # the rotation is the same three products and one sum, over
+            # the head block at once and not a head at a time: XLA:CPU
+            # contracts a multiply-add or not by the shape it is handed
+            np.testing.assert_allclose(np.asarray(out), np.asarray(was),
+                                       rtol=0, atol=5e-7)
+        else:
+            np.testing.assert_array_equal(np.asarray(out), np.asarray(was))

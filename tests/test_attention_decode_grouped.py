@@ -140,3 +140,142 @@ def test_refusals():
         fmha_decode(q, kp, kp, jnp.zeros((1, 2), jnp.int32),
                     jnp.ones((1,), jnp.int32), num_kv_heads=2,
                     first=jnp.zeros((2,), jnp.int32))
+
+
+# ---------------------------------------------------------------------------
+# Several pages a grid step (PR 34)
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("pages", [1, 2, 3, 8])
+@pytest.mark.parametrize("ring", [False, True])
+@pytest.mark.parametrize("group", [1, 6])
+def test_pages_a_step_match_reference(group, ring, pages):
+    """One head or six a K/V head, from position 0 or from a first
+    position round a ring that has wrapped twice, at every count of
+    pages a step the rule gives a table of this shape (its width chooses
+    it): contexts that end one token into a step, on a step's last
+    token, at 0 (an idle slot) and inside the first step."""
+    from apex_tpu.ops import attention_decode as ad
+    from apex_tpu.ops.attention_decode import paged_attention_reference
+
+    ps, d, h_kv, width = 16, 128, 2, 8 * pages
+    step = pages * ps
+    if ring:
+        # the window is 7 steps of pages: a walk covers them and the
+        # page the newest token sits in
+        window = 7 * step
+        base = 2 * width * ps + 3 * ps
+        lengths = [base + 1, base, 0, ps + 3]
+        kw = dict(first=jnp.maximum(jnp.asarray(lengths) - window, 0),
+                  max_pages=window // ps + 1)
+        walked = min(width, window // ps + 1)
+    else:
+        lengths = [5 * step + 1, 3 * step, 0, 8 * step]
+        kw, walked = {}, width
+    assert ad._pages_per_step(
+        ps, d, ad._pick_block_h(h_kv, group), 4, walked, False) == pages
+    b = len(lengths)
+    keys = jax.random.split(jax.random.PRNGKey(pages), 4)
+    q = jax.random.normal(keys[0], (b, h_kv * group, 1, d), jnp.float32)
+    kp = jax.random.normal(keys[1], (1 + b * width, h_kv, ps, d))
+    vp = jax.random.normal(keys[2], (1 + b * width, h_kv, ps, d))
+    table = 1 + jax.random.permutation(keys[3], b * width).reshape(b, width)
+    ln = jnp.asarray(lengths, jnp.int32)
+    out = fmha_decode(q, kp, vp, table, ln, num_kv_heads=h_kv,
+                      implementation="pallas", **kw)
+    want = paged_attention_reference(q, kp, vp, table, ln,
+                                     first=kw.get("first"))
+    live = np.asarray(lengths) > 0
+    np.testing.assert_allclose(np.asarray(out)[live], np.asarray(want)[live],
+                               rtol=2e-5, atol=2e-5)
+    assert np.isfinite(np.asarray(out)).all()
+
+
+def _equations(jaxpr) -> int:
+    """Equations of a jaxpr, those of every jaxpr it holds included (a
+    Mosaic call's kernel and its block specs' index maps too)."""
+    jaxpr = getattr(jaxpr, "jaxpr", jaxpr)
+    n = len(jaxpr.eqns)
+    for eqn in jaxpr.eqns:
+        for v in eqn.params.values():
+            for sub in (v if isinstance(v, (list, tuple)) else [v]):
+                if hasattr(getattr(sub, "jaxpr", sub), "eqns"):
+                    n += _equations(sub)
+            for spec in getattr(v, "block_mappings", ()):
+                n += _equations(spec.index_map_jaxpr)
+    return n
+
+
+def test_the_kernels_size_does_not_grow_with_the_pages_a_step(monkeypatch):
+    """What refused PR 33: eight pages a step as eight copies of the
+    body a head, traced and lowered at five call sites in every process.
+    Trinity's window walk at the rule's pages a step is no more
+    equations than at one (and no more than the kernel through PR 33,
+    674 at this shape)."""
+    from apex_tpu.ops import attention_decode as ad
+
+    b, hq, h_kv, d, ps, width = 24, 48, 8, 128, 64, 81
+    sds = jax.ShapeDtypeStruct
+    pool = sds((4 * (1 + b * width), h_kv, ps, d), jnp.bfloat16)
+    rope = sds((b, 1, d // 2), jnp.float32)
+
+    def walk(q, k, v, table, ln, first, cos, sin):
+        return fmha_decode(q, k, v, table, ln, num_kv_heads=h_kv,
+                           first=first, max_pages=65, rope=(cos, sin),
+                           implementation="pallas")
+
+    count = lambda: _equations(jax.make_jaxpr(walk)(
+        sds((b, hq, 1, d), jnp.bfloat16), pool, pool,
+        sds((b, width), jnp.int32), sds((b,), jnp.int32),
+        sds((b,), jnp.int32), rope, rope))
+    assert ad._pages_per_step(ps, d, 8, 2, 65, False) == 8
+    at_the_rules = count()
+    monkeypatch.setattr(ad, "_pages_per_step", lambda *a: 1)
+    at_one = count()
+    assert at_the_rules <= 1.25 * at_one, (at_the_rules, at_one)
+    assert at_the_rules <= 674, at_the_rules
+
+
+def test_the_decode_programs_kernels_lower_to_a_bounded_text(monkeypatch):
+    """``AfmoeModel.decode_fns``'s ``jit__decode`` (a small model with
+    Trinity's head width and layer kinds), lowered for the TPU without
+    one: its five paged-decode calls are no more than 160 kB of module
+    text (135 kB when written; 77 kB through PR 33 at two K/V heads;
+    eight unrolled pages a head would be some 600)."""
+    from jax.sharding import Mesh
+
+    from apex_tpu.models.afmoe import FULL, SLIDING, AfmoeConfig, AfmoeModel
+    from apex_tpu.serving.kv_cache import KVCacheConfig, init_pools
+    from apex_tpu.serving.serve import init_carry
+    from apex_tpu.utils import platform
+
+    monkeypatch.setattr(platform, "_current_platform", lambda: "tpu")
+    model = AfmoeModel(AfmoeConfig.from_hf(dict(
+        vocab_size=96, hidden_size=64, num_hidden_layers=5,
+        num_dense_layers=1, num_attention_heads=12, num_key_value_heads=2,
+        head_dim=128, intermediate_size=128, moe_intermediate_size=32,
+        num_experts_per_tok=2, num_shared_experts=1, route_scale=2.448,
+        rms_norm_eps=1e-5, rope_theta=10000.0, mup_enabled=True,
+        sliding_window=64, layer_types=[SLIDING] * 4 + [FULL]),
+        num_experts=16, held_experts=(1, 4, 6, 11),
+        params_dtype=jnp.bfloat16))
+    slots = 3
+    ccfg = KVCacheConfig.of_classes(
+        model.cache_classes(slots=slots, pages_per_seq=64, page_size=16,
+                            prefill_chunk=32),
+        page_size=16, max_seqs=slots, dtype=jnp.bfloat16)
+    fns = model.decode_fns(
+        None, Mesh(np.array(jax.devices()[:1]), ("tp",)), ccfg,
+        max_prompt_len=256, prefill_chunk=32)
+    text = fns.decode_jit.trace(
+        jax.eval_shape(model.init, jax.random.PRNGKey(0)),
+        jax.eval_shape(lambda: init_pools(ccfg)),
+        jax.eval_shape(lambda: dict(init_carry(slots),
+                                    **fns.decode.carry_extras)),
+        jax.ShapeDtypeStruct((slots, ccfg.table_columns[-1][1]), jnp.int32),
+    ).lower(lowering_platforms=("tpu",)).as_text()
+    calls = [line for line in text.split("\n") if "tpu_custom_call" in line]
+    assert len(calls) == 5
+    assert all("paged_decode" in line for line in calls)
+    assert sum(map(len, calls)) <= 160_000, sum(map(len, calls))

@@ -85,42 +85,55 @@ def test_forward_and_backward_compile(one_chip, monkeypatch, call):
 
 # The paged decode kernel at the serving cells' sizes (in THIS file: one
 # process may hold the TPU's library).  (slots, query heads, K/V heads,
-# head_dim, table width, window or 0)
+# head_dim, table width, window or 0, s_q, int8 pool)
 DECODE_CALLS = {
-    "gpt2-345m-decode": (32, 16, 16, 64, 16, 0),
-    "trinity-full-layer": (24, 48, 8, 128, 200, 0),
-    "trinity-window-layer": (24, 48, 8, 128, 81, 4096),
+    "gpt2-345m-decode": (32, 16, 16, 64, 16, 0, 1, False),
+    "trinity-full-layer": (24, 48, 8, 128, 200, 0, 1, False),
+    "trinity-window-layer": (24, 48, 8, 128, 81, 4096, 1, False),
+    # the other calls the ONE kernel serves, at widths a chip would see:
+    # 16 heads of 128 over a long table (8 pages a step, widened to
+    # fp32 a head), a prefill chunk over pages, a verify step, int8
+    "mha-128-long-table": (8, 16, 16, 128, 128, 0, 1, False),
+    "gpt2-345m-chunk-64": (1, 16, 16, 64, 16, 0, 64, False),
+    "mha-128-verify-4": (8, 16, 16, 128, 32, 0, 4, False),
+    "gpt2-345m-int8": (32, 16, 16, 64, 16, 0, 1, True),
+    "mha-128-int8": (8, 16, 16, 128, 32, 0, 1, True),
 }
 
 
 @pytest.mark.parametrize("call", list(DECODE_CALLS))
 def test_paged_decode_compiles(one_chip, monkeypatch, call):
     """Grouped heads as further query rows, the walk from a per-slot
-    first position round a ring and the fused rotation lay out for
-    Mosaic at the cell's widths."""
+    first position round a ring, the fused rotation, and a step's pages
+    copied into one tile a head (or one page a step from the pipeline:
+    64-wide heads, int8) lay out for Mosaic at the cell's widths."""
     from apex_tpu.ops import attention_decode
 
-    b, hq, hkv, d, width, window = DECODE_CALLS[call]
+    b, hq, hkv, d, width, window, sq, int8 = DECODE_CALLS[call]
     page = 64
     monkeypatch.setattr(attention_decode, "_interpret", lambda: False)
 
     def sds(shape, dt):
         return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
 
-    pool = sds((1 + b * width, hkv, page, d), jnp.bfloat16)
-    rope = sds((b, 1, d // 2), jnp.float32)
+    pool = sds((1 + b * width, hkv, page, d),
+               jnp.int8 if int8 else jnp.bfloat16)
+    scales = sds((1 + b * width, hkv, page, 1), jnp.float32)
+    rope = sds((b, sq, d // 2), jnp.float32)
 
-    def step(q, k, v, table, lengths, cos, sin):
+    def step(q, k, v, table, lengths, cos, sin, ks, vs):
         return attention_decode.fmha_decode(
             q, k, v, table, lengths, rope=(cos, sin),
             num_kv_heads=hkv if hkv != hq else None,
             first=jnp.maximum(lengths - window, 0) if window else None,
             max_pages=window // page + 1 if window else None,
+            k_scales=ks if int8 else None, v_scales=vs if int8 else None,
             implementation="pallas")
 
     text = jax.jit(step).lower(
-        sds((b, hq, 1, d), jnp.bfloat16), pool, pool,
+        sds((b, hq, sq, d), jnp.bfloat16), pool, pool,
         sds((b, width), jnp.int32), sds((b,), jnp.int32), rope, rope,
+        scales, scales,
     ).compile().as_text()
     assert text.count("tpu_custom_call") == 1
     assert "tlm.kernel.paged_decode" in text
