@@ -309,20 +309,24 @@ def _walk(cfg, len_ref, first_ref, bb):
     return start, jnp.where(ln > 0, jnp.maximum(last - start + 1, 0), 0)
 
 
-def _stream_tiles(cfg, walk, at, grid, pt_ref, pools, tiles, sem, state):
+def _stream_tiles(cfg, walk, at, grid, pt_ref, pools, tiles, sem, state,
+                  cut=None):
     """The copies of a step's pages (``cfg.copies``) of program
     ``at = (b, hb, step)`` in ``grid``: wait for this step's tile, with
     the next live step's in flight under it.  Returns the tile to read.
     ``state`` (SMEM): [the tile the next live step reads, whether its
-    copies are already in flight]."""
+    copies are already in flight].  ``cut(pool, page, hb)`` is what a
+    place of a tile is copied from (default: pool page ``page``'s
+    ``block_h`` heads of block ``hb``)."""
     (b, hb, step), (n_b, n_hb) = at, grid[:2]
     P, bh = cfg.pages, cfg.block_h
+    if cut is None:
+        cut = lambda pool, page, hh: pool.at[page, pl.ds(hh * bh, bh)]
 
     def page_copies(hh, page, buf, j):
-        # pool page ``page``'s block_h heads -> place j of tile buf
+        # what ``cut`` takes of pool page ``page`` -> place j of tile buf
         return [pltpu.make_async_copy(
-            pool.at[page, pl.ds(hh * bh, bh)], tile.at[buf, j],
-            sem.at[i, buf])
+            cut(pool, page, hh), tile.at[buf, j], sem.at[i, buf])
             for i, (pool, tile) in enumerate(zip(pools, tiles))]
 
     def live_pages(bb, st):

@@ -16,6 +16,15 @@ tiles; whatever follows ``k_r`` is zero and is never read as a value).  ``W_kvb`
   on the cached rows themselves (576 and 512 wide), ``W_UV`` is applied
   to the result.  No per-head key or value is ever built, so a decode
   step reads each selected row once for all heads.
+- **absorbed, over the pages** (:func:`mla_paged`, decode without a
+  selection): the same form over the WHOLE context of every slot, read
+  where it lies.  A Mosaic kernel walks the slot's page-table row,
+  several pages a grid step (:mod:`apex_tpu.ops.attention_decode`'s
+  walk: the pool stays in HBM, a step's LIVE pages are copied into one
+  of two VMEM tiles with the next step's copies in flight), and every
+  row serves all heads from that one fetch: key = the whole row, value
+  = its first ``kv_lora_rank`` columns.  No gathered copy of the
+  context exists, and the pool is never sliced by layer.
 
 Both are the same mathematics (``tests/test_deepseek_v32.py`` holds
 them to each other).  Masked entries take a finite ``-1e30``, so a row
@@ -24,17 +33,24 @@ with nothing valid (an idle slot) gives garbage, not NaN.
 
 from __future__ import annotations
 
+import functools
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from apex_tpu.ops.attention import flash_attention
+from apex_tpu.ops.attention import _NEG_INF, _interpret, flash_attention
+from apex_tpu.ops.attention_decode import (
+    _DecodeConfig, _pages_per_step, _stream_tiles, _walk,
+)
+from apex_tpu.ops.common import run_kernel, shape_struct
 from apex_tpu.ops.sparse_index import NEG
-from apex_tpu.telemetry.spans import phase
+from apex_tpu.telemetry.spans import kernel_name, phase
 
-__all__ = ["mla_expanded", "mla_absorbed"]
+__all__ = ["mla_expanded", "mla_absorbed", "mla_paged"]
 
 
 def _masked_softmax(scores, mask):
@@ -93,17 +109,160 @@ def mla_expanded(q_nope, q_rope, rows, w_uk, w_uv, mask, scale: float,
         return jnp.moveaxis(out, 0, 1).reshape(n, H, -1)
 
 
+def _absorbed_query(q_nope, q_rope, w_uk, rows):
+    """``[q_nope W_UK^T | q_rope | 0...]`` (B, H, width of a cached
+    row), in the rows' dtype."""
+    q_abs = jnp.einsum("bhd,chd->bhc", q_nope, w_uk)
+    q = jnp.concatenate([q_abs.astype(rows.dtype), q_rope], axis=-1)
+    return jnp.pad(q, ((0, 0), (0, 0), (0, rows.shape[-1] - q.shape[-1])))
+
+
 def mla_absorbed(q_nope, q_rope, rows, chosen, w_uk, w_uv, scale: float):
     """``q_nope`` (B, H, dn), ``q_rope`` (B, H, dr); ``rows`` (B, K, >=
     dc + dr): each query's OWN gathered cache rows; ``chosen`` (B, K) bool
     (false on filler rows) -> (B, H, dv)."""
     dc = w_uk.shape[0]
-    q_abs = jnp.einsum("bhd,chd->bhc", q_nope, w_uk)
-    q = jnp.concatenate([q_abs.astype(rows.dtype), q_rope], axis=-1)
-    q = jnp.pad(q, ((0, 0), (0, 0), (0, rows.shape[-1] - q.shape[-1])))
+    q = _absorbed_query(q_nope, q_rope, w_uk, rows)
     with phase("attn.mla.core"):
         s = jnp.einsum("bhc,bkc->bhk", q, rows,
                        preferred_element_type=jnp.float32) * scale
         p = _masked_softmax(s, chosen[:, None, :]).astype(rows.dtype)
         o = jnp.einsum("bhk,bkc->bhc", p, rows[..., :dc])
     return jnp.einsum("bhc,chd->bhd", o, w_uv)
+
+
+# ---------------------------------------------------------------------------
+# The absorbed form over a slot's pages
+# ---------------------------------------------------------------------------
+
+
+def _latent_walk_kernel(pt_ref, len_ref, layer_ref, q_ref, pool_ref, o_ref,
+                        qs_ref, acc_ref, m_ref, l_ref, tile, sem, state,
+                        *, cfg: _DecodeConfig, dc: int):
+    """Program ``(slot, 0, step)``: ``cfg.pages`` logical pages of the
+    slot's context against all heads' absorbed queries.  The online
+    softmax is :func:`apex_tpu.ops.attention_decode._decode_kernel`'s;
+    keys and values are ONE tile."""
+    at = b, _, step = tuple(pl.program_id(i) for i in range(3))
+    grid = tuple(pl.num_programs(i) for i in range(3))
+    ps, P = cfg.page_size, cfg.pages
+    walk = functools.partial(_walk, cfg, len_ref, None)
+    _, live = walk(b)
+
+    @pl.when((b == 0) & (step == 0))
+    def _first_program():
+        # a dead place's scores are masked whatever it holds, but as a
+        # value it has to be finite for the masked weight's zero
+        state[0] = 0
+        state[1] = 0
+        tile[...] = jnp.zeros_like(tile)
+
+    @pl.when(step == 0)
+    def _init():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+        m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        qs_ref[...] = (q_ref[0].astype(jnp.float32) * cfg.sm_scale
+                       ).astype(qs_ref.dtype)
+
+    @pl.when(step * P < live)
+    def _body():
+        layer = layer_ref[0]
+        cur = _stream_tiles(
+            cfg, walk, at, grid, pt_ref, (pool_ref,), (tile,), sem, state,
+            cut=lambda pool, page, hb: pool.at[layer, page])
+        rows = tile[cur].reshape(P * ps, tile.shape[-1])
+        s = lax.dot_general(qs_ref[...], rows, (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32)
+        mask = step * P * ps + lax.broadcasted_iota(
+            jnp.int32, s.shape, 1) < len_ref[b]
+        s = jnp.where(mask, s, _NEG_INF)
+        m_prev = m_ref[...]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        pexp = jnp.where(mask, jnp.exp(s - m_new), 0.0)
+        corr = jnp.exp(m_prev - m_new)
+        l_ref[...] = l_ref[...] * corr + jnp.sum(pexp, -1, keepdims=True)
+        m_ref[...] = m_new
+        acc_ref[...] = acc_ref[...] * corr + jnp.dot(
+            pexp.astype(rows.dtype), rows[:, :dc],
+            preferred_element_type=jnp.float32)
+
+    @pl.when(step == grid[2] - 1)
+    def _finalize():
+        # an idle slot (length 0) walks nothing and writes zeros
+        o_ref[0] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
+                    ).astype(o_ref.dtype)
+
+
+def _latent_walk(q, pool, layer, page_table, lengths, scale: float, dc: int):
+    """``q`` (B, H, W) absorbed queries as wide as a pool row; ``pool``
+    (layers, pages, page_size, W) -> (B, H, dc)."""
+    B, H, W = q.shape
+    ps, width = pool.shape[2], page_table.shape[1]
+    cfg = _DecodeConfig(
+        sm_scale=float(scale), causal=False, sq=1, block_h=1, page_size=ps,
+        num_pages=width, kv_block=0, has_scales=False, has_rope=False,
+        pages=_pages_per_step(ps, W, 1, pool.dtype.itemsize, width, False),
+        copies=True)
+    per_slot = lambda b, h, p, *scalars: (b, 0, 0)
+    return pl.pallas_call(
+        functools.partial(_latent_walk_kernel, cfg=cfg, dc=dc),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(B, 1, -(-width // cfg.pages)),
+            in_specs=[pl.BlockSpec((1, H, W), per_slot),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec((1, H, dc), per_slot),
+            scratch_shapes=[
+                pltpu.VMEM((H, W), pool.dtype),
+                pltpu.VMEM((H, dc), jnp.float32),
+                pltpu.VMEM((H, 1), jnp.float32),
+                pltpu.VMEM((H, 1), jnp.float32),
+                pltpu.VMEM((2, cfg.pages, ps, W), pool.dtype),
+                pltpu.SemaphoreType.DMA((1, 2)),
+                pltpu.SMEM((2,), jnp.int32),
+            ]),
+        out_shape=shape_struct((B, H, dc), q.dtype, q, pool),
+        # a step's copies are started by the step before it, whichever
+        # slot that was in: the grid runs in order
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",) * 3),
+        interpret=_interpret(),
+        name=kernel_name("latent_walk"),
+    )(page_table.astype(jnp.int32), lengths.astype(jnp.int32),
+      jnp.asarray(layer, jnp.int32).reshape(1), q, pool)
+
+
+def mla_paged(q_nope, q_rope, pool, layer, page_table, lengths, w_uk, w_uv,
+              scale: float, *, implementation: Optional[str] = None):
+    """The absorbed form of one query a slot over the slot's WHOLE paged
+    context: ``q_nope`` (B, H, dn), ``q_rope`` (B, H, dr); ``pool``
+    (layers, pages, page_size, >= dc + dr) the stacked latent pool and
+    ``layer`` (a traced scalar is fine) the layer read; ``page_table``
+    (B, pages a slot) physical pages (unallocated entries hold a valid
+    page, the null page 0); ``lengths`` (B,) the rows a slot's query
+    sees, its own included (0: an idle slot, whose output is zeros on
+    the kernel's path) -> (B, H, dv).
+
+    ``implementation``: None = the Mosaic walk on a TPU and XLA
+    elsewhere (the rows gathered through the table, then
+    :func:`mla_absorbed`); ``"pallas"`` / ``"xla"`` strict."""
+    from apex_tpu.utils.platform import default_implementation
+
+    dc = w_uk.shape[0]
+    B = q_nope.shape[0]
+
+    def xla():
+        rows = pool[layer, page_table].reshape(B, -1, pool.shape[-1])
+        seen = jnp.arange(rows.shape[1], dtype=jnp.int32)[None] \
+            < lengths[:, None]
+        return mla_absorbed(q_nope, q_rope, rows, seen, w_uk, w_uv, scale)
+
+    def walk():
+        q = _absorbed_query(q_nope, q_rope, w_uk, pool)
+        with phase("attn.mla.core"):
+            o = _latent_walk(q, pool, layer, page_table, lengths, scale, dc)
+        return jnp.einsum("bhc,chd->bhd", o, w_uv)
+
+    return run_kernel("latent_walk", walk, xla,
+                      implementation or default_implementation())

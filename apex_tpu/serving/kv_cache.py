@@ -156,12 +156,21 @@ class KVCacheConfig:
     fp32 scales.
 
     ``kind="latent"`` is a pool whose per-token entry is NOT per head
-    (multi-head latent attention with a sparse indexer): ``latent_dim``
-    values shared by all heads (the normalised latent and the rotated
-    shared key side by side) plus an ``index_dim``-wide index key, per
-    layer.  ``num_heads`` / ``head_dim`` do not describe such an entry
-    and must be left at 1 / ``latent_dim``; pages, tables, the
-    allocator and admission are the same.  A latent row is stored
+    (multi-head latent attention): ``latent_dim`` values shared by all
+    heads (the normalised latent and the rotated shared key side by
+    side), per layer, plus an ``index_dim``-wide index key where the
+    model selects its context with a sparse indexer (DeepSeek-V3.2).
+    ``index_dim=0`` is the latent pool WITHOUT index keys, for a model
+    whose attention reads the whole context (Xing4.0): ``init_pools``
+    builds ``ckv`` alone, no ``kidx``.  ``num_heads`` / ``head_dim`` do
+    not describe such an entry and must be left at 1 / ``latent_dim``;
+    pages, tables, the allocator and admission are the same.  Nothing
+    refuses a latent cache by its kind: ``admit(prompt_tokens=)`` /
+    ``prefix_cache=True`` and ``export_request`` refuse a cache of
+    several page classes or with a window class, and a latent cache is
+    one whole-context class whose pages ``copy_pages`` /
+    ``export_pages`` move pool by pool, with or without ``kidx``.  A
+    latent row is stored
     ``latent_row_dim`` wide, ``latent_dim`` rounded up to whole 128-lane
     tiles (the tail is zero): the device tiles a row that way in any
     case, and a pool whose last axis is NOT a multiple of 128 (576) is
@@ -221,9 +230,13 @@ class KVCacheConfig:
             raise ValueError(
                 f"kind must be 'kv' or 'latent', got {self.kind!r}")
         if self.kind == "latent":
-            if self.latent_dim < 1 or self.index_dim < 1:
+            if self.latent_dim < 1 or self.index_dim < 0:
                 raise ValueError(
-                    "a latent pool needs latent_dim and index_dim >= 1")
+                    "a latent pool needs latent_dim and index_dim: "
+                    "latent_dim >= 1; index_dim >= 0 is the width of the "
+                    "sparse indexer's key a token (DeepSeek-V3.2), 0 for "
+                    "a model whose attention reads the whole context and "
+                    "keeps no index keys (no kidx pool is built)")
             if (self.num_heads, self.head_dim) != (1, self.latent_dim):
                 raise ValueError(
                     "a latent entry is shared by all heads: pass "
@@ -756,8 +769,9 @@ def init_pools(config: KVCacheConfig) -> Dict[str, jnp.ndarray]:
     slices), plus fp32 ``k_scales``/``v_scales`` when quantized.
 
     ``kind="latent"``: ``ckv`` of shape ``(num_layers, num_pages,
-    page_size, latent_row_dim)`` and ``kidx`` of ``(..., index_dim)`` — a
-    token's entry is one row, shared by all heads."""
+    page_size, latent_row_dim)`` and, where ``index_dim > 0``, ``kidx``
+    of ``(..., index_dim)`` — a token's entry is one row, shared by all
+    heads."""
     cfg = config
     if cfg.classes:
         # a class's pool under its own name: "<class>.k" / "<class>.v",
@@ -768,8 +782,10 @@ def init_pools(config: KVCacheConfig) -> Dict[str, jnp.ndarray]:
             for c in cfg.classes for kv in ("k", "v")}
     if cfg.kind == "latent":
         lead = (cfg.num_layers, cfg.num_pages, cfg.page_size)
-        return {"ckv": jnp.zeros(lead + (cfg.latent_row_dim,), cfg.dtype),
-                "kidx": jnp.zeros(lead + (cfg.index_dim,), cfg.dtype)}
+        pools = {"ckv": jnp.zeros(lead + (cfg.latent_row_dim,), cfg.dtype)}
+        if cfg.index_dim:
+            pools["kidx"] = jnp.zeros(lead + (cfg.index_dim,), cfg.dtype)
+        return pools
     shape = (cfg.num_layers, cfg.num_pages, cfg.num_heads,
              cfg.page_size, cfg.head_dim)
     dt = cfg.kv_dtype if cfg.quantized else cfg.dtype
@@ -1025,7 +1041,7 @@ def write_latent_tokens(
     pools: Dict[str, jnp.ndarray],
     layer,
     ckv_new: jnp.ndarray,
-    kidx_new: jnp.ndarray,
+    kidx_new: Optional[jnp.ndarray],
     pages: jnp.ndarray,
     offsets: jnp.ndarray,
 ) -> Dict[str, jnp.ndarray]:
@@ -1033,7 +1049,8 @@ def write_latent_tokens(
     is fine) of a WHOLE latent pool dict, leading layer axis included.
 
     ``ckv_new`` (n, latent_dim), zero-padded here to the pool's row
-    width, and ``kidx_new`` (n, index_dim) are the token rows, ``pages``/``offsets`` (n,) their physical targets
+    width, and ``kidx_new`` (n, index_dim; None for a pool without index
+    keys) are the token rows, ``pages``/``offsets`` (n,) their physical targets
     (:func:`write_targets`; idle or padded entries point at the null
     page).  Unlike :func:`write_tokens` this takes and returns the
     stacked pools: inside a layer scan that carries them, and a jit
@@ -1046,8 +1063,9 @@ def write_latent_tokens(
     pad = pools["ckv"].shape[-1] - ckv_new.shape[-1]
     out["ckv"] = pools["ckv"].at[layer, pages, offsets].set(
         jnp.pad(ckv_new, ((0, 0), (0, pad))).astype(pools["ckv"].dtype))
-    out["kidx"] = pools["kidx"].at[layer, pages, offsets].set(
-        kidx_new.astype(pools["kidx"].dtype))
+    if kidx_new is not None:
+        out["kidx"] = pools["kidx"].at[layer, pages, offsets].set(
+            kidx_new.astype(pools["kidx"].dtype))
     return out
 
 

@@ -137,3 +137,48 @@ def test_paged_decode_compiles(one_chip, monkeypatch, call):
     ).compile().as_text()
     assert text.count("tpu_custom_call") == 1
     assert "tlm.kernel.paged_decode" in text
+
+
+# Xing4.0's two kernels at the cell's widths (in THIS file too).
+@pytest.mark.parametrize("tokens", [4096, 16], ids=["chunk", "decode"])
+def test_hyper_connection_mapping_compiles(one_chip, monkeypatch, tokens):
+    """The projection of four float32 streams of 3584 in full precision,
+    the transpose that puts the tokens on the lanes and 20 Sinkhorn
+    iterations on 4 x 4 values a token: one Mosaic call, for a chunk's
+    tokens and for a decode step's sixteen (padded to whole lanes)."""
+    from apex_tpu.ops import hyper_connections as hc
+
+    monkeypatch.setattr(hc, "_interpret", lambda: False)
+    sds = lambda shape: jax.ShapeDtypeStruct(
+        shape, jnp.float32, sharding=one_chip)
+    text = jax.jit(lambda X, phi, alpha, bias: hc.hc_mapping(
+        X, phi, alpha, bias, sinkhorn_iters=20, eps=1e-6,
+        clamp=(-30.0, 30.0), rms_eps=1e-6, implementation="pallas")).lower(
+        sds((4, tokens, 3584)), sds((4, 24, 3584)), sds((3,)), sds((24,)),
+    ).compile().as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert "tlm.kernel.hc_map" in text
+
+
+def test_latent_walk_compiles_and_gathers_nothing(one_chip, monkeypatch):
+    """The absorbed form over the stacked latent pool of the Xing4 cell
+    (6 layers x 16 slots x 336 pages of 64 rows, 640 wide), the layer a
+    traced scalar: one Mosaic call and no temporary the size of a
+    layer's pool or of a slot's context."""
+    from apex_tpu.ops import attention_latent as al
+
+    monkeypatch.setattr(al, "_interpret", lambda: False)
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    bf = jnp.bfloat16
+    compiled = jax.jit(lambda qn, qr, pool, layer, table, lengths, uk, uv:
+                       al.mla_paged(qn, qr, pool, layer, table, lengths, uk,
+                                    uv, 0.1, implementation="pallas")).lower(
+        sds((16, 32, 128), bf), sds((16, 32, 64), bf),
+        sds((6, 1 + 16 * 336, 64, 640), bf), sds((), jnp.int32),
+        sds((16, 336), jnp.int32), sds((16,), jnp.int32),
+        sds((512, 32, 128), bf), sds((512, 32, 128), bf)).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert "tlm.kernel.latent_walk" in text
+    # one slot's gathered context would be 27.5 MB, a layer's pool 440
+    assert compiled.memory_analysis().temp_size_in_bytes < 4 * 2**20
