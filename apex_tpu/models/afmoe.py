@@ -35,9 +35,11 @@ ring of ``window + chunk`` tokens and one page a slot (class
   row (a window layer's modulo its ring), the context is read back in
   POSITION order (a full layer's pages ``[0, start + C)``, a window
   layer's ``[start + C - window - C, start + C)`` out of the ring) and
-  attention is :func:`apex_tpu.ops.attention.flash_attention` under a
-  mask built from the keys' own positions, a K/V head's query heads
-  riding as further query rows (K/V are not repeated);
+  attention is :func:`apex_tpu.ops.attention.flash_attention` told
+  where the chunk sits among those keys (``q_offset``, ``window``: no
+  mask is built, key blocks no row sees are not computed), a K/V head's
+  query heads riding as further query rows (``q_period``; K/V are not
+  repeated);
 - a DECODE step — :func:`apex_tpu.ops.attention_decode.fmha_decode` with
   ``num_kv_heads``: a full layer walks its slot's pages from 0, a window
   layer from the page holding ``length - window`` (``first``) round its
@@ -55,6 +57,7 @@ reference.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Dict, Optional, Tuple
 
 import jax
@@ -63,7 +66,7 @@ import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from apex_tpu.models.gpt import GPTDecodeFns
-from apex_tpu.ops.attention import flash_attention
+from apex_tpu.ops.attention import flash_attention, k_blocks_run
 from apex_tpu.ops.attention_decode import fmha_decode
 from apex_tpu.ops.layer_norm import fused_rms_norm_affine
 from apex_tpu.ops.rope import apply_rope_tables, rope_cos_sin, rope_table
@@ -86,8 +89,6 @@ COUNTER_NAMES = (
     "decode_experts_touched", "decode_load_max", "decode_window_rows",
     "decode_full_rows", "decode_context_rows", "decode_slot_layers",
 )
-
-_NEG = -1e30
 
 
 @dataclasses.dataclass(frozen=True)
@@ -266,27 +267,24 @@ class AfmoeModel:
             self.config.params_dtype)
         return jnp.matmul(o, ap["wo"], preferred_element_type=jnp.float32)
 
-    def _attend_rows(self, q, k, v, q_pos, k_pos, window: int):
-        """``q`` (n, Hq, d) at ``q_pos`` (n,) against ``k``/``v`` (S,
-        Hkv, d) at ``k_pos`` (S,): causal, within ``window`` where that
-        is not 0.  A K/V head's query heads ride as further query rows,
-        so K/V are not repeated -> (n, Hq * d)."""
+    def _attend_rows(self, q, k, v, offset, window: int):
+        """``q`` (n, Hq, d), one position after another, against
+        ``k``/``v`` (S, Hkv, d), likewise, the first query ``offset``
+        positions (a traced scalar is fine) after the first key: causal,
+        within ``window`` where that is not 0.  A K/V head's query heads
+        ride as further query rows, so K/V are not repeated, and no mask
+        is built: the kernel is told where its rows sit -> (n, Hq * d)."""
         c = self.config
         n, Hq, d = q.shape
         Hkv = k.shape[1]
         G = Hq // Hkv
-        seen = k_pos[None, :] <= q_pos[:, None]
-        if window:
-            seen &= q_pos[:, None] - k_pos[None, :] < window
-        bias = jnp.where(seen, 0.0, _NEG).astype(jnp.float32)
         # (n, Hkv, G, d) -> (1, Hkv, G * n, d): row g * n + i
         qg = jnp.moveaxis(q.reshape(n, Hkv, G, d), 0, 2).reshape(
             1, Hkv, G * n, d)
         out = flash_attention(
             qg, jnp.moveaxis(k, 0, 1)[None], jnp.moveaxis(v, 0, 1)[None],
-            causal=False, sm_scale=c.softmax_scale,
-            bias=jnp.tile(bias, (G, 1))[None, None],
-            bias_requires_grad=False)
+            causal=True, sm_scale=c.softmax_scale, q_offset=offset,
+            q_period=n, window=window)
         return jnp.moveaxis(out[0].reshape(Hkv, G, n, d), 2, 0).reshape(
             n, Hq * d)
 
@@ -355,8 +353,8 @@ class AfmoeModel:
         ``0 .. T - 1`` (a test shifts them: only the window layers may
         notice)."""
         T = tokens.shape[0]
-        order = jnp.arange(T, dtype=jnp.int32)
-        positions = order if positions is None else positions
+        if positions is None:
+            positions = jnp.arange(T, dtype=jnp.int32)
         cos, sin = rope_cos_sin(positions, self.config.head_dim,
                                 self.config.rope_theta)
 
@@ -364,8 +362,7 @@ class AfmoeModel:
             if self._rotates(layer):
                 q = apply_rope_tables(q, cos[:, None], sin[:, None])
                 k = apply_rope_tables(k, cos[:, None], sin[:, None])
-            return self._attend_rows(q, k, v, order, order,
-                                     self._window(layer)), pools
+            return self._attend_rows(q, k, v, 0, self._window(layer)), pools
 
         x = self._embed(params, tokens)
         x = self._walk(params, x, attend, None, jnp.ones((T,), bool))[0]
@@ -439,6 +436,18 @@ class AfmoeModel:
         return view
 
     @staticmethod
+    def _chunk_keys(window: int, end_page, C: int, ctx_len: int, page: int):
+        """The keys a chunk of ``C`` tokens that ends on page
+        ``end_page`` (traced or not) reads in a layer that sees
+        ``window`` back (0: everything): (their first page, how many
+        pages): a full layer's bucket from 0, a window layer's last
+        ``window + C``."""
+        if not window:
+            return 0, ctx_len // page
+        n_ctx = min(ctx_len // page, -(-(window + C) // page))
+        return jnp.maximum(end_page - n_ctx, 0), n_ctx
+
+    @staticmethod
     def _flat(pools, name):
         return tuple(pools[name + kv].reshape(
             (-1,) + pools[name + kv].shape[2:]) for kv in (".k", ".v"))
@@ -485,16 +494,14 @@ class AfmoeModel:
                     pools[name + kv], i * n_pages, new, pages)
                 for kv, new in ((".k", k), (".v", v))})
             with phase(f"attn.{name}.core"):
+                first_page, n_ctx = self._chunk_keys(
+                    window, end_page, C, ctx_len, page)
                 if ring:
-                    n_ctx = min(ctx_len // page, -(-(window + C) // page))
-                    first_page = jnp.maximum(end_page - n_ctx, 0)
                     logical = first_page + jnp.arange(n_ctx, dtype=jnp.int32)
                     ctx_pages = jnp.take(row, logical % ring)
                 else:
                     # a bucket may reach past the table: the null page
                     # there, at positions no query of the chunk sees
-                    n_ctx = ctx_len // page
-                    first_page = 0
                     ctx_pages = jnp.take(
                         row, jnp.arange(n_ctx, dtype=jnp.int32),
                         mode="fill", fill_value=0)
@@ -503,10 +510,8 @@ class AfmoeModel:
                 rows = lambda f: jnp.moveaxis(
                     f[i * n_pages + ctx_pages], 1, 2).reshape(
                         n_ctx * page, c.num_key_value_heads, c.head_dim)
-                k_pos = first_page * page + jnp.arange(
-                    n_ctx * page, dtype=jnp.int32)
-                o = self._attend_rows(q, rows(fk), rows(fv), positions,
-                                      k_pos, window)
+                o = self._attend_rows(q, rows(fk), rows(fv),
+                                      start - first_page * page, window)
             return o, pools
 
         x = self._embed(params, toks)
@@ -605,7 +610,7 @@ class AfmoeModel:
         vocab) and ``last_attn`` (2, slots, Hq * d).  ``chunk`` compiles
         once per context BUCKET (``prefill_chunk`` times a power of two,
         and the slot bound): a full layer reads the bucket's pages and
-        masks what lies past the chunk."""
+        skips what lies past the chunk."""
         from apex_tpu.serving.kv_cache import init_pools
         from apex_tpu.serving.sampling import advance_slots, sample
 
@@ -615,7 +620,7 @@ class AfmoeModel:
             raise ValueError(
                 f"max_prompt_len {max_prompt_len} exceeds the slot bound "
                 f"{cfg.max_len} (pages_per_seq * page_size)")
-        C, max_len = int(prefill_chunk), cfg.max_len
+        C, page, max_len = int(prefill_chunk), cfg.page_size, cfg.max_len
         table = self.rope_table(max_len)
         S = cfg.max_seqs
         # the longest context a chunk reads: the last chunk of the
@@ -657,13 +662,32 @@ class AfmoeModel:
         cj = jax.jit(_chunk, donate_argnums=(1,), static_argnames=("ctx_len",))
         dj = jax.jit(_decode, donate_argnums=(1,))
 
+        def bucket(start: int) -> int:
+            return next(b for b in buckets if b >= min(start + C, top))
+
         def chunk(pools, toks, start, plen, write_from, row, key):
             start = int(start)
-            ctx_len = next(b for b in buckets if b >= min(start + C, top))
             return cj(params, pools,
                       jnp.asarray(toks, jnp.int32).reshape(1, C),
                       jnp.int32(start), jnp.int32(plen),
-                      jnp.int32(write_from), row, key, ctx_len=ctx_len)
+                      jnp.int32(write_from), row, key, ctx_len=bucket(start))
+
+        @functools.lru_cache(maxsize=None)
+        def k_blocks(start: int):
+            """(key blocks the chunk at ``start`` computes, key blocks
+            of the contexts it reads), one K/V head's, summed over the
+            layers: what the chunk's positions leave of its attention
+            (``ops.attention.k_blocks_run``, the kernel's own bounds)."""
+            G = c.num_attention_heads // c.num_key_value_heads
+            counts = np.zeros((2,), np.int64)
+            for layer in range(c.num_hidden_layers):
+                window = self._window(layer)
+                first_page, n_ctx = self._chunk_keys(
+                    window, (start + C) // page, C, bucket(start), page)
+                counts += k_blocks_run(
+                    G * C, n_ctx * page, start - int(first_page) * page, C,
+                    window, dtype=c.params_dtype)
+            return int(counts[0]), int(counts[1])
 
         def prefill(pools, toks, length, page_row, key):
             toks = np.asarray(toks, np.int32).reshape(-1)
@@ -680,6 +704,7 @@ class AfmoeModel:
         decode = lambda pools, carry, pt: dj(params, pools, carry, pt)
         chunk.prefill_chunk = C
         chunk.ctx_buckets = tuple(buckets)
+        chunk.k_blocks = k_blocks
         decode.eos_id = eos_id
         carry_sharding = NamedSharding(mesh, P())
         decode.carry_sharding = carry_sharding

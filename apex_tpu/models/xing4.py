@@ -20,8 +20,8 @@ projections, YaRN, the expert layer (every routed expert held), the
 chunked ingestion, ``decode_fns`` and its carry are inherited.  What
 differs in the three walks of the attention:
 
-- ``apply`` and a prefill CHUNK run the expanded form under the causal
-  mask (no selection);
+- ``apply`` and a prefill CHUNK run the expanded form causally from
+  the first query's position (no selection, no mask built);
 - a DECODE step runs the absorbed form over every live row of the
   slot's pages, read where they lie (:func:`apex_tpu.ops.
   attention_latent.mla_paged`): no row is gathered, no index key is kept
@@ -47,6 +47,7 @@ from jax import lax
 from apex_tpu.models.deepseek_v32 import (
     COUNTER_NAMES, DeepSeekV32Config, DeepSeekV32Model,
 )
+from apex_tpu.ops.attention import k_blocks_run
 from apex_tpu.ops.attention_latent import mla_expanded, mla_paged
 from apex_tpu.ops.hyper_connections import hc_mapping, hc_mix, hc_read
 from apex_tpu.ops.rope import apply_rope_tables
@@ -206,14 +207,14 @@ class Xing4Model(DeepSeekV32Model):
         return carry
 
     def _attend_expanded(self, ap, q_nope, q_rope, rows, positions, real):
-        """Expanded attention of the queries at ``positions`` over
-        ``rows``, the cached rows of positions 0..S-1, causal."""
-        S = rows.shape[0]
-        causal = jnp.arange(S, dtype=jnp.int32)[None] <= positions[:, None]
+        """Expanded attention of the queries at ``positions`` (one after
+        another from ``positions[0]``) over ``rows``, the cached rows of
+        positions 0..S-1, causal."""
         w_uk, w_uv = self._w_kvb(ap)
         with phase("attn.mla"):
-            o = mla_expanded(q_nope, q_rope, rows, w_uk, w_uv, causal,
-                             self.config.softmax_scale)
+            o = mla_expanded(q_nope, q_rope, rows, w_uk, w_uv, None,
+                             self.config.softmax_scale,
+                             q_offset=positions[0])
         return self._out(ap, o), jnp.sum(
             jnp.where(real, positions + 1, 0)).astype(jnp.float32)
 
@@ -271,6 +272,27 @@ class Xing4Model(DeepSeekV32Model):
         last = jnp.sum(jnp.take(
             X, jnp.clip(plen - 1 - start, 0, C - 1), axis=1), axis=0)
         return self._logits(params, last[None])[0], pools
+
+    def decode_fns(self, params, mesh, cache_config, **kw):
+        """:meth:`DeepSeekV32Model.decode_fns`, and the chunk function
+        says what its positions leave of its attention:
+        ``chunk.k_blocks(start)`` -> (key blocks the chunk at ``start``
+        computes, key blocks of the extent it reads), one head's, summed
+        over the layers (``ops.attention.k_blocks_run``, the kernel's
+        own bounds)."""
+        fns = super().decode_fns(params, mesh, cache_config, **kw)
+        C, page = fns.prefill_chunk, cache_config.page_size
+        layers, dtype = self.config.num_hidden_layers, self.config.params_dtype
+
+        @functools.lru_cache(maxsize=None)
+        def k_blocks(start: int):
+            # the extent ``chunk`` picks its program by
+            ctx_len = min(-(-(start + C) // page) * page, cache_config.max_len)
+            run, extent = k_blocks_run(C, ctx_len, start, dtype=dtype)
+            return layers * run, layers * extent
+
+        fns.chunk.k_blocks = k_blocks
+        return fns
 
     def decode_step(self, params, pools, tokens, positions, active,
                     page_table, *, page_size: int, table):

@@ -40,6 +40,7 @@ and long sequences (32k+) compile:
 from __future__ import annotations
 
 import functools
+import math
 from typing import NamedTuple, Optional
 
 import jax
@@ -53,7 +54,8 @@ from apex_tpu.utils.platform import default_implementation, is_tpu
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-__all__ = ["flash_attention", "mha_reference"]
+__all__ = ["flash_attention", "mha_reference", "k_block_bounds",
+           "k_blocks_run"]
 
 _NEG_INF = -1e30
 _LANES = 128
@@ -112,6 +114,9 @@ def mha_reference(
     kv_segment_ids: Optional[jnp.ndarray] = None,
     dropout_rate: float = 0.0,
     dropout_seed=None,
+    q_offset=None,
+    q_period: Optional[int] = None,
+    window: int = 0,
 ) -> jnp.ndarray:
     """Plain XLA attention with fp32 softmax — the correctness reference,
     playing the role of the reference's pure-PyTorch ``impl='default'``
@@ -119,6 +124,8 @@ def mha_reference(
 
     Dropout uses the same counter-based hash as the Pallas kernel, so for
     a given ``dropout_seed`` both implementations drop the same entries.
+    ``q_offset`` / ``q_period`` / ``window``: :func:`flash_attention`'s
+    positions of a causal call; the mask they stand for is built here.
     """
     if (q_segment_ids is None) != (kv_segment_ids is None):
         raise ValueError("segment ids must be given for both q and kv")
@@ -134,7 +141,14 @@ def mha_reference(
     if causal:
         q_idx = jax.lax.broadcasted_iota(jnp.int32, (sq, sk), 0)
         k_idx = jax.lax.broadcasted_iota(jnp.int32, (sq, sk), 1)
-        mask = mask & (k_idx <= q_idx)[None, None]
+        if _positioned(q_offset, q_period, window):
+            # row r sits at key column q_offset + (r mod q_period)
+            q_idx = (0 if q_offset is None else q_offset) + (
+                q_idx if q_period is None else q_idx % q_period)
+        seen = k_idx <= q_idx
+        if window:
+            seen &= q_idx - k_idx < window
+        mask = mask & seen[None, None]
     if q_segment_ids is not None:
         mask = mask & (
             q_segment_ids[:, None, :, None] == kv_segment_ids[:, None, None, :]
@@ -186,6 +200,12 @@ class _FAConfig(NamedTuple):
     # inputs, where the default (single bf16 pass) loses ~3 decimal
     # digits vs the XLA path at long sequence lengths (KERNELS_TPU gate)
     hi_precision: bool = False
+    # the causal form that is told where its rows sit (``q_offset`` /
+    # ``q_period`` / ``window`` of :func:`flash_attention`): the key
+    # blocks of each q block come from :func:`k_block_bounds`, as
+    # prefetched scalars
+    positioned: bool = False
+    window: int = 0
 
 
 BIAS_PER_BATCH = -2
@@ -203,6 +223,61 @@ def _prec(cfg):
 
 
 # ---------------------------------------------------------------------------
+# A causal call that is told where its rows sit
+# ---------------------------------------------------------------------------
+
+
+def _positioned(q_offset, q_period, window) -> bool:
+    return q_offset is not None or q_period is not None or bool(window)
+
+
+def k_block_bounds(q_len: int, kv_len: int, q_offset, q_period: int,
+                   window: int, block_q: int, block_k: int):
+    """THE arithmetic of the position-bounded causal form, for every q
+    block at once: ``(first_kb, last_kb, lo)``, each ``(q blocks,)``
+    int32.  Query row ``r`` sits at key column ``q_offset + (r mod
+    q_period)`` (``q_offset >= 0``; ``block_q`` divides ``q_period`` or
+    one period holds every row, so a block's rows are consecutive
+    columns from ``lo``) and sees column ``c`` iff ``c <= t`` and, with
+    a ``window``, ``t - c < window``.  Key blocks outside ``first_kb ..
+    last_kb`` hold nothing any row of the q block sees: above its last
+    row's column, below its first row's window, or past the keys.
+
+    ``q_offset`` may be traced (then the three are ``jax.lax``
+    expressions, the kernel's prefetched scalars) or a Python int (then
+    numpy: :func:`k_blocks_run` counts with the same lines)."""
+    num_q, num_k = -(-q_len // block_q), -(-kv_len // block_k)
+    if isinstance(q_offset, jax.Array):
+        div, least, most = jax.lax.div, jax.lax.min, jax.lax.max
+    else:
+        div, least, most = np.floor_divide, np.minimum, np.maximum
+    lo = q_offset + (np.arange(num_q, dtype=np.int32) * block_q) % q_period
+    last = least(div(lo + (block_q - 1), block_k), num_k - 1)
+    if not window:
+        return np.zeros((num_q,), np.int32), last, lo
+    first = least(div(most(lo - (window - 1), 0), block_k), last)
+    return first, last, lo
+
+
+def k_blocks_run(q_len: int, kv_len: int, q_offset: int = 0,
+                 q_period: Optional[int] = None, window: int = 0,
+                 block_q: Optional[int] = None, block_k: Optional[int] = None,
+                 dtype=jnp.bfloat16):
+    """``(key blocks whose body runs, key blocks of the extent)`` of one
+    program (one head) of ``flash_attention(causal=True, q_offset=...,
+    q_period=..., window=...)`` at these lengths: how much of the
+    ``(q blocks, k blocks)`` grid the positions leave.  Host arithmetic
+    on Python ints; the blocks are clamped as the call clamps them."""
+    q_period = q_len if q_period is None else q_period
+    block_q, block_k = _block_sizes(dtype, q_len, kv_len, block_q, block_k,
+                                    True, q_period)
+    first, last, _ = k_block_bounds(q_len, kv_len, int(q_offset), q_period,
+                                    window, block_q, block_k)
+    return (int(np.sum(last - first + 1)),
+            -(-q_len // block_q) * -(-kv_len // block_k))
+
+
+# ---------------------------------------------------------------------------
 # Pallas forward
 # ---------------------------------------------------------------------------
 
@@ -210,6 +285,10 @@ def _prec(cfg):
 def _fa_fwd_kernel(
     *refs, cfg: _FAConfig, num_k: int, has_bias, has_segs, has_dropout,
 ):
+    if cfg.positioned:
+        # k_block_bounds' table, prefetched: (first_kb,) last_kb, lo
+        n = 3 if cfg.window else 2
+        bounds, refs = refs[:n], refs[n:]
     (q_ref, k_ref, v_ref), rest = refs[:3], refs[3:]
     bias_ref = qseg_ref = kseg_ref = seed_ref = None
     if has_bias:
@@ -218,11 +297,16 @@ def _fa_fwd_kernel(
         (qseg_ref, kseg_ref), rest = rest[:2], rest[2:]
     if has_dropout:
         seed_ref, rest = rest[0], rest[1:]
-    o_ref, lse_ref, acc_ref, m_ref, l_ref = rest
+    if cfg.positioned:
+        (o_ref, acc_ref, m_ref, l_ref), lse_ref = rest, None
+    else:
+        o_ref, lse_ref, acc_ref, m_ref, l_ref = rest
 
     i, j, kb = pl.program_id(0), pl.program_id(1), pl.program_id(2)
     block_q, block_k = cfg.block_q, cfg.block_k
-    if cfg.causal:
+    if cfg.positioned:
+        last_kb, lo = bounds[-2][j], bounds[-1][j]
+    elif cfg.causal:
         last_kb = jnp.minimum(
             num_k - 1, ((j + 1) * block_q - 1) // block_k
         )
@@ -247,9 +331,10 @@ def _fa_fwd_kernel(
         if has_bias:
             s = s + bias_ref[0].astype(jnp.float32)
         if masked or has_dropout:
-            q_global = j * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, s.shape, 0
-            )
+            # a row's key column: its own index, or where it was said
+            # to sit
+            q_global = (lo if cfg.positioned else j * block_q) + \
+                jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
             k_global = kb * block_k + jax.lax.broadcasted_iota(
                 jnp.int32, s.shape, 1
             )
@@ -257,6 +342,9 @@ def _fa_fwd_kernel(
             mask = k_global < cfg.kv_len
             if cfg.causal:
                 mask = jnp.logical_and(mask, k_global <= q_global)
+            if cfg.window:
+                mask = jnp.logical_and(
+                    mask, q_global - k_global < cfg.window)
             if has_segs:
                 mask = jnp.logical_and(
                     mask, qseg_ref[0, 0][:, None] == kseg_ref[0, 0][None, :]
@@ -286,18 +374,29 @@ def _fa_fwd_kernel(
         m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
         l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
 
+    # blocks the diagonal, the window's edge or the padded tail cuts
+    # take the masked body, the others the mask-free one
     conds = []
-    if cfg.causal:
+    if cfg.positioned:
+        k0 = kb * block_k
+        conds.append(k0 + (block_k - 1) > lo)
+        if cfg.window:
+            conds.append(k0 + cfg.window <= lo + (block_q - 1))
+    elif cfg.causal:
         conds.append(kb * block_k + (block_k - 1) > j * block_q)
     if cfg.kv_len < num_k * block_k:                        # kv padding
         conds.append(kb == num_k - 1)
-    _mask_specialized(kb <= last_kb, conds, has_segs, _body)
+    run = kb <= last_kb
+    if cfg.window:
+        run = jnp.logical_and(run, kb >= bounds[0][j])
+    _mask_specialized(run, conds, has_segs, _body)
 
     @pl.when(kb == last_kb)
     def _finalize():
         l = jnp.maximum(l_ref[:, 0:1], 1e-30)
         o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
-        lse_ref[0, 0] = m_ref[:, 0] + jnp.log(l[:, 0])
+        if lse_ref is not None:
+            lse_ref[0, 0] = m_ref[:, 0] + jnp.log(l[:, 0])
 
 
 def _fwd_in_specs(cfg, d, psq, psk, has_bias, has_segs, has_dropout,
@@ -315,13 +414,20 @@ def _fwd_in_specs(cfg, d, psq, psk, has_bias, has_segs, has_dropout,
             return f
         return lambda i, kb, jq: f(i, jq, kb)
 
+    def kv_map(i, j, kb, *bounds):
+        if bounds:
+            # a step that runs no body asks for the block it holds: the
+            # nearest one that is read, so nothing is fetched for it
+            kb = jax.lax.min(kb, bounds[-2][j])
+            if cfg.window:
+                kb = jax.lax.max(kb, bounds[0][j])
+        return (i, kb, 0)
+
     specs = [
-        pl.BlockSpec((1, block_q, d), w(lambda i, j, kb: (i, j, 0)),
+        pl.BlockSpec((1, block_q, d), w(lambda i, j, kb, *_: (i, j, 0)),
                      memory_space=pltpu.VMEM),
-        pl.BlockSpec((1, block_k, d), w(lambda i, j, kb: (i, kb, 0)),
-                     memory_space=pltpu.VMEM),
-        pl.BlockSpec((1, block_k, d), w(lambda i, j, kb: (i, kb, 0)),
-                     memory_space=pltpu.VMEM),
+        pl.BlockSpec((1, block_k, d), w(kv_map), memory_space=pltpu.VMEM),
+        pl.BlockSpec((1, block_k, d), w(kv_map), memory_space=pltpu.VMEM),
     ]
     if has_bias:
         if cfg.bias_batch == 1:
@@ -350,9 +456,18 @@ def _fwd_in_specs(cfg, d, psq, psk, has_bias, has_segs, has_dropout,
     return specs
 
 
-def _compiler_params():
+#: the limit handed to Mosaic for a call with positions: its default
+#: 1024 x 1024 blocks keep ~17 MB of float32 score-space temporaries at
+#: key width 256, over the default scoped limit of 16 MiB once the call
+#: sits in a whole chunk program (the same limit as ``fmha_mid``'s
+#: forward takes)
+FLASH_POSITIONED_VMEM_LIMIT = 32 * 1024 * 1024
+
+
+def _compiler_params(vmem_limit_bytes=None):
     return pltpu.CompilerParams(
-        dimension_semantics=("parallel", "parallel", "arbitrary")
+        dimension_semantics=("parallel", "parallel", "arbitrary"),
+        vmem_limit_bytes=vmem_limit_bytes,
     )
 
 
@@ -372,7 +487,10 @@ def _mask_specialized(run, conds, has_segs, body):
             lambda: body(masked=False))
 
 
-def _fa_fwd_pallas(q, k, v, bias, qseg, kseg, seed, cfg: _FAConfig):
+def _fa_fwd_pallas(q, k, v, bias, qseg, kseg, seed, cfg: _FAConfig,
+                   bounds=()):
+    """``bounds``: :func:`k_block_bounds`' table of a positioned call
+    (its prefetched scalars; such a call returns no ``lse``)."""
     bh, psq, d = q.shape
     psk = k.shape[1]
     num_q, num_k = psq // cfg.block_q, psk // cfg.block_k
@@ -388,32 +506,47 @@ def _fa_fwd_pallas(q, k, v, bias, qseg, kseg, seed, cfg: _FAConfig):
         inputs.extend([qseg, kseg])
     if has_dropout:
         inputs.append(seed)
-    out, lse = pl.pallas_call(
-        functools.partial(
-            _fa_fwd_kernel, cfg=cfg, num_k=num_k, has_bias=has_bias,
-            has_segs=has_segs, has_dropout=has_dropout,
-        ),
+    kernel = functools.partial(
+        _fa_fwd_kernel, cfg=cfg, num_k=num_k, has_bias=has_bias,
+        has_segs=has_segs, has_dropout=has_dropout,
+    )
+    grid = dict(
         grid=(bh, num_q, num_k),
         in_specs=_fwd_in_specs(cfg, d, psq, psk, has_bias, has_segs,
                                has_dropout),
-        out_specs=[
-            pl.BlockSpec((1, cfg.block_q, d), lambda i, j, kb: (i, j, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, 1, cfg.block_q), lambda i, j, kb: (i, 0, j)),
-        ],
-        out_shape=[
-            shape_struct((bh, psq, d), q.dtype, q, k, v),
-            shape_struct((bh, 1, psq), jnp.float32, q, k, v),
-        ],
         scratch_shapes=[
             pltpu.VMEM((cfg.block_q, d), jnp.float32),
             pltpu.VMEM((cfg.block_q, _LANES), jnp.float32),
             pltpu.VMEM((cfg.block_q, _LANES), jnp.float32),
         ],
-        compiler_params=_compiler_params(),
-        interpret=_interpret(),
-        name=kernel_name("fmha_flash.fwd"),
-    )(*inputs)
+    )
+    o_spec = pl.BlockSpec((1, cfg.block_q, d), lambda i, j, kb, *_: (i, j, 0),
+                          memory_space=pltpu.VMEM)
+    o_shape = shape_struct((bh, psq, d), q.dtype, q, k, v)
+    if cfg.positioned:
+        spec = dict(
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=len(bounds), out_specs=o_spec, **grid),
+            out_shape=o_shape,
+            compiler_params=_compiler_params(FLASH_POSITIONED_VMEM_LIMIT))
+    else:
+        spec = dict(
+            out_specs=[
+                o_spec,
+                pl.BlockSpec((1, 1, cfg.block_q), lambda i, j, kb: (i, 0, j)),
+            ],
+            out_shape=[
+                o_shape,
+                shape_struct((bh, 1, psq), jnp.float32, q, k, v),
+            ],
+            compiler_params=_compiler_params(), **grid)
+    res = pl.pallas_call(
+        kernel, interpret=_interpret(), name=kernel_name("fmha_flash.fwd"),
+        **spec,
+    )(*bounds, *inputs)
+    if cfg.positioned:
+        return res, None
+    out, lse = res
     return out, lse[:, 0]
 
 
@@ -787,6 +920,25 @@ def _flash_bwd(cfg, res, do):
 _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def _flash_positioned(q, k, v, bounds, cfg):
+    return _fa_fwd_pallas(q, k, v, None, None, None, None, cfg, bounds)[0]
+
+
+def _no_positioned_grad(*_):
+    raise NotImplementedError(
+        "flash_attention with q_offset / q_period / window is forward-only: "
+        "the backward kernels have not learned the positions (prefill "
+        "chunks do not differentiate; a training call gives none of them)")
+
+
+_flash_positioned.defvjp(_no_positioned_grad, _no_positioned_grad)
+# a program's layers make this call at the same shapes: as a jitted
+# function it is traced and lowered ONCE a program and called from each
+# (ROADMAP S10: a kernel costs set-up what is lowered for it)
+_flash_positioned_once = jax.jit(_flash_positioned, static_argnums=(4,))
+
+
 # ---------------------------------------------------------------------------
 # Public entry point
 # ---------------------------------------------------------------------------
@@ -812,9 +964,12 @@ def flash_attention(
     dropout_rate: float = 0.0,
     dropout_seed=None,
     bias_requires_grad: bool = True,
-    block_q: int = 512,
-    block_k: int = 1024,
+    block_q: Optional[int] = None,
+    block_k: Optional[int] = None,
     implementation: Optional[str] = None,
+    q_offset=None,
+    q_period: Optional[int] = None,
+    window: int = 0,
 ) -> jnp.ndarray:
     """Flash attention over ``(batch, heads, seq, head_dim)``.
 
@@ -852,15 +1007,54 @@ def flash_attention(
     backward pass replays exactly; the same seed on the XLA path draws
     the identical mask.
 
-    Default block sizes come from the on-chip sweep in KERNELS_TPU.json
-    (v5e: 1024x1024 is fastest, 512x1024 is within ~5% with more VMEM
-    headroom for the bias/dropout variants); both are clamped to the
-    sequence lengths.
+    Default block sizes (``block_q`` / ``block_k`` None) come from the
+    on-chip sweep in KERNELS_TPU.json (v5e: 1024x1024 is fastest,
+    512x1024 is within ~5% with more VMEM headroom for the bias/dropout
+    variants, so that is the default) — and 1024x1024 for a call with
+    positions, which has neither operand (6-16 % faster at the prefill
+    chunks' shapes, docs/attention.md); both are clamped to the sequence
+    lengths.
+
+    **Positions** (``causal=True`` only; forward only; no bias, segment
+    ids or dropout beside them).  Without them a causal call's row ``r``
+    sees key columns ``<= r``.  ``q_offset`` (an int ``>= 0``, or a
+    TRACED int32 scalar: one executable for every offset) says the rows
+    sit further along the keys, ``q_period`` (static, default the query
+    length; a multiple of 8) that they repeat, ``window`` (static, 0 =
+    none) that they see only so far back: row ``r`` sits at key column
+    ``t = q_offset + (r mod q_period)`` and sees column ``c`` iff ``c <=
+    t`` and ``t - c < window``.  That is what a prefill chunk at
+    ``start`` against its cached context is (``q_offset=start``), and
+    what a grouped-query chunk whose query heads ride as further rows of
+    their K/V head is (``q_period`` = the chunk).  The kernel runs no
+    body for a key block that no row of its q block sees, fetches
+    nothing for such a step and masks only the blocks an edge cuts
+    (:func:`k_block_bounds`; :func:`k_blocks_run` counts them); no
+    ``(sq, sk)`` mask exists anywhere.  A call with positions takes this
+    kernel (or XLA) at every length: the short and mid kernels have not
+    learned them.
     """
     if (q_segment_ids is None) != (kv_segment_ids is None):
         raise ValueError("segment ids must be given for both q and kv")
     if dropout_rate > 0.0 and dropout_seed is None:
         raise ValueError("dropout_rate > 0 requires dropout_seed")
+    positioned = _positioned(q_offset, q_period, window)
+    if positioned:
+        if not causal or bias is not None or q_segment_ids is not None \
+                or dropout_rate > 0.0:
+            raise ValueError(
+                "q_offset / q_period / window place the rows of a "
+                "causal=True call, and go with no bias, segment ids or "
+                "dropout")
+        if implementation in ("short", "mid", "decode"):
+            raise ValueError(
+                f"implementation={implementation!r} takes no q_offset / "
+                "q_period / window: only the flash kernel ('pallas') and "
+                "'xla' know a row's position")
+        if q_period is not None and q.shape[2] % q_period:
+            raise ValueError(
+                f"q_period {q_period} does not divide the {q.shape[2]} "
+                "query rows")
     if bias is not None and bias.ndim < 4:
         bias = bias.reshape((1,) * (4 - bias.ndim) + bias.shape)
     from apex_tpu.ops.common import run_kernel
@@ -923,7 +1117,7 @@ def flash_attention(
         # analog of the reference's kernel-availability windows
         # (apex/transformer/functional/fused_softmax.py:151-171)
         impl = "xla"
-    if implementation is None and impl == "pallas":
+    if implementation is None and impl == "pallas" and not positioned:
         from apex_tpu.ops.attention_short import short_seq_threshold
 
         thr = short_seq_threshold()
@@ -957,6 +1151,7 @@ def flash_attention(
             q, k, v, causal=causal, sm_scale=sm_scale, bias=bias,
             q_segment_ids=q_segment_ids, kv_segment_ids=kv_segment_ids,
             dropout_rate=dropout_rate, dropout_seed=dropout_seed,
+            q_offset=q_offset, q_period=q_period, window=window,
         )
 
     def _pallas_path():
@@ -964,6 +1159,7 @@ def flash_attention(
             q, k, v, causal, sm_scale, bias, q_segment_ids,
             kv_segment_ids, dropout_rate, dropout_seed,
             bias_requires_grad, block_q, block_k,
+            q_offset, q_period, window,
         )
 
     return run_kernel(
@@ -1000,16 +1196,36 @@ def _clamp_blocks(dtype, block_q: int, block_k: int):
     return block_q, block_k
 
 
+def _block_sizes(dtype, sq: int, sk: int, block_q: Optional[int],
+                 block_k: Optional[int], positioned: bool = False,
+                 q_period: Optional[int] = None):
+    """The blocks a call runs with: the caller's or the default for its
+    kind (a call with positions holds no bias block and takes the
+    larger q block), clamped to the dtype and the lengths, and a q
+    block inside one period of the rows."""
+    if block_q is None:
+        block_q = 1024 if positioned else 512
+    if block_k is None:
+        block_k = 1024
+    block_q, block_k = _clamp_blocks(dtype, block_q, block_k)
+    block_q = min(block_q, max(sq, 1))
+    block_k = min(block_k, max(sk, 1))
+    if q_period is not None and q_period < sq:
+        block_q = math.gcd(block_q, q_period)
+    return block_q, block_k
+
+
 def _flash_attention_pallas(
     q, k, v, causal, sm_scale, bias, q_segment_ids, kv_segment_ids,
     dropout_rate, dropout_seed, bias_requires_grad, block_q, block_k,
+    q_offset=None, q_period=None, window=0,
 ):
     b, h, sq, d = q.shape
     sk = k.shape[2]
     scale = (1.0 / d**0.5) if sm_scale is None else float(sm_scale)
-    block_q, block_k = _clamp_blocks(q.dtype, block_q, block_k)
-    block_q = min(block_q, max(sq, 1))
-    block_k = min(block_k, max(sk, 1))
+    positioned = _positioned(q_offset, q_period, window)
+    block_q, block_k = _block_sizes(q.dtype, sq, sk, block_q, block_k,
+                                    positioned, q_period)
     pad_q = (-sq) % block_q
     pad_k = (-sk) % block_k
     # pad head_dim to the 128-lane tile; zero columns do not change
@@ -1061,7 +1277,17 @@ def _flash_attention_pallas(
         bias_batch=bias_batch, bias_grad=bool(bias_requires_grad),
         hi_precision=(q.dtype == jnp.float32),
     )
-    out = _flash(qf, kf, vf, bias_flat, qseg, kseg, seed_arr, cfg)
+    if positioned:
+        first, last, lo = k_block_bounds(
+            sq, sk, jnp.asarray(0 if q_offset is None else q_offset,
+                                jnp.int32),
+            sq + pad_q if q_period is None else q_period, window,
+            block_q, block_k)
+        out = _flash_positioned_once(
+            qf, kf, vf, ((first,) if window else ()) + (last, lo),
+            cfg._replace(positioned=True, window=int(window)))
+    else:
+        out = _flash(qf, kf, vf, bias_flat, qseg, kseg, seed_arr, cfg)
     if pad_q:
         out = out[:, :sq]
     out = out.reshape(b, h, sq, d + pad_d)

@@ -62,15 +62,20 @@ def _masked_softmax(scores, mask):
 
 def mla_expanded(q_nope, q_rope, rows, w_uk, w_uv, mask, scale: float,
                  *, head_block: int = 32,
-                 implementation: Optional[str] = None):
+                 implementation: Optional[str] = None, q_offset=None):
     """``q_nope`` (n, H, dn), ``q_rope`` (n, H, dr); ``rows`` (S, >= dc
     + dr) cached entries ``[c_kv | k_r | 0...]``; ``w_uk`` (dc, H, dn),
-    ``w_uv`` (dc, H, dv); ``mask`` (n, S) bool -> (n, H, dv).
+    ``w_uv`` (dc, H, dv) -> (n, H, dv).  What a query sees is EITHER
+    ``mask`` (n, S) bool, any selection, OR (``mask`` None) ``q_offset``,
+    a traced int32 scalar is fine: query ``i`` sits at row ``q_offset +
+    i`` and sees the rows up to its own — the causal form, which builds
+    no mask.
 
     The attention itself is :func:`apex_tpu.ops.attention.
-    flash_attention` with the selection mask as an additive bias shared
-    by all heads (``implementation`` is handed through: None picks the
-    Mosaic kernels on a TPU and XLA elsewhere), so the (heads, n, S)
+    flash_attention`, a selection mask as an additive bias shared by all
+    heads, the causal form as the kernel's own positions
+    (``implementation`` is handed through: None picks the Mosaic kernels
+    on a TPU and XLA elsewhere), so the (heads, n, S)
     scores never reach HBM — as plain fusions their softmax was 85 % of
     a 2048-token chunk's time on the v5e.  The kernels take one width
     for q, k and v: keys are ``[k_nope | k_r]`` (dn + dr), values are
@@ -81,7 +86,13 @@ def mla_expanded(q_nope, q_rope, rows, w_uk, w_uv, mask, scale: float,
     c_kv, k_r = rows[:, :dc], rows[:, dc:dc + dr]
     G = head_block if H % head_block == 0 else H
     S = rows.shape[0]
-    bias = jnp.where(mask, 0.0, NEG).astype(jnp.float32)[None, None]
+    if (mask is None) == (q_offset is None):
+        raise ValueError("give a mask or a q_offset, one of them")
+    if mask is None:
+        seen = dict(causal=True, q_offset=q_offset)
+    else:
+        seen = dict(causal=False, bias_requires_grad=False, bias=jnp.where(
+            mask, 0.0, NEG).astype(jnp.float32)[None, None])
     pad = max(dn + dr - dv, 0)
 
     def block(args):
@@ -94,9 +105,8 @@ def mla_expanded(q_nope, q_rope, rows, w_uk, w_uv, mask, scale: float,
                     ((0, 0), (0, 0), (0, pad)))
         heads_first = lambda t: jnp.moveaxis(t, 1, 0)[None]
         out = flash_attention(
-            heads_first(q), heads_first(k), heads_first(v), causal=False,
-            sm_scale=scale, bias=bias, bias_requires_grad=False,
-            implementation=implementation)
+            heads_first(q), heads_first(k), heads_first(v), sm_scale=scale,
+            implementation=implementation, **seen)
         return jnp.moveaxis(out[0], 0, 1)[..., :dv]      # (n, G, dv)
 
     with phase("attn.mla.core"):

@@ -610,12 +610,19 @@ class ContinuousBatcher:
     def _prefill_span(self, req: Request, slot: int, chunk: int,
                       t_admit: float):
         """The ``dispatch_prefill`` span of one prefill / chunk call
-        (``chunk`` -1 on the monolithic path)."""
+        (``chunk`` -1 on the monolithic path).  Where the chunk function
+        knows what its positions leave of its attention
+        (``chunk_fn.k_blocks``), the span says so: key blocks computed
+        and key blocks of the context read."""
         span = host_span("serve.dispatch_prefill", uid=req.uid, slot=slot,
                          prompt_tokens=len(req.prompt), chunk=chunk)
         if req.arrival_s is not None:
             span.set_metadata(
                 queue_wait_us=int(1e6 * (t_admit - req.arrival_s)))
+        k_blocks = getattr(self.chunk_fn, "k_blocks", None)
+        if chunk >= 0 and k_blocks is not None:
+            run, extent = k_blocks(chunk * self.prefill_chunk)
+            span.set_metadata(k_blocks_run=run, k_blocks_extent=extent)
         return span
 
     def _slot_live(self, slot: int, first, req: Request, plen: int,

@@ -182,3 +182,38 @@ def test_latent_walk_compiles_and_gathers_nothing(one_chip, monkeypatch):
     assert "tlm.kernel.latent_walk" in text
     # one slot's gathered context would be 27.5 MB, a layer's pool 440
     assert compiled.memory_analysis().temp_size_in_bytes < 4 * 2**20
+
+
+# the flash forward told its positions, at the chunk shapes of the Xing4
+# and Trinity cells: (heads, q rows, keys, width, q_period, window)
+POSITIONED_CALLS = {
+    "xing4-chunk": (32, 4096, 20480, 192, None, 0),
+    "trinity-window-chunk": (8, 6144, 5120, 128, 1024, 4096),
+    "trinity-full-chunk": (8, 6144, 13312, 128, 1024, 0),
+}
+
+
+@pytest.mark.parametrize("call", list(POSITIONED_CALLS))
+def test_positioned_flash_forward_compiles_and_holds_no_mask(
+        one_chip, monkeypatch, call):
+    """``flash_attention(causal=True, q_offset=<traced>, ...)`` at its
+    default blocks: one Mosaic call whose bounds arrive as prefetched
+    scalars, and nothing the size of a ``(rows, keys)`` float32 mask in
+    the program (the bias variant it replaces held one)."""
+    from apex_tpu.ops import attention
+    from apex_tpu.utils import platform
+
+    monkeypatch.setattr(attention, "_interpret", lambda: False)
+    monkeypatch.setattr(platform, "_current_platform", lambda: "tpu")
+    h, sq, sk, d, period, window = POSITIONED_CALLS[call]
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    bf = jnp.bfloat16
+    compiled = jax.jit(lambda q, k, v, at: attention.flash_attention(
+        q, k, v, causal=True, sm_scale=0.1, q_offset=at, q_period=period,
+        window=window)).lower(
+            sds((1, h, sq, d), bf), sds((1, h, sk, d), bf),
+            sds((1, h, sk, d), bf), sds((), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert "tlm.kernel.fmha_flash.fwd" in text
+    assert f"f32[{sq},{sk}]" not in text and f"f32[1,{sq},{sk}]" not in text

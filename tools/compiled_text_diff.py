@@ -34,6 +34,10 @@ The programs:
 - ``train-1.3b-dp2tp2``   ``jit_train_step``, cerebras-gpt-1.3b, dp2 x tp2
 - ``serve-345m``          ``jit__prefill`` and ``jit__decode`` of
   ``GPTModel.decode_fns`` at the gpt2 serving cells' shapes
+- ``serve-dsv32``, ``serve-trinity``, ``serve-xing4``   ``jit__decode``
+  and every ``jit__chunk`` (one a context extent or bucket, written as
+  ``jit__chunk@<extent>``) of the three expert models' ``decode_fns`` at
+  their cells' shapes; a checkout without the model skips the program
 
 The train step is the trainer's own: ``main(--steps 0)`` builds the
 mesh, the model, the optimizer state and the jitted step, with the mesh
@@ -66,6 +70,10 @@ PROGRAMS = {
     "train-345m": ("gpt2-345m", "pretrain-s1024-b16"),
     "train-1.3b-dp2tp2": ("cerebras-gpt-1.3b", "pretrain-s2048-b8-dp2tp2"),
     "serve-345m": ("gpt2-345m", "backlog-short-in-long-out"),
+    "serve-dsv32": ("deepseek-v3.2-ep16-share", "backlog-longdoc-in-mid-out"),
+    "serve-trinity": ("trinity-large-ep8-share",
+                      "backlog-mixed-short-long-in-mid-out"),
+    "serve-xing4": ("xing4.0-29b-a4b-depth6", "backlog-16k-in-mid-out"),
 }
 
 # an instruction's metadata={op_name="jit(f)/..." stack_frame_id=7}, and
@@ -212,6 +220,83 @@ def _serve_texts(root, topo, cfg, tr):
     return texts
 
 
+def _expert_serve_texts(root, topo, cfg, tr):
+    """The model and the cache of ``benchmarks/runners/serve_latent_moe``
+    / ``serve_window_moe`` / ``serve_hyper_latent`` (the traffic file's
+    ``runner``), from shapes alone: the steps close over no weight."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from apex_tpu.serving.kv_cache import KVCacheConfig, init_pools
+    from apex_tpu.serving.serve import init_carry
+    from apex_tpu.transformer import parallel_state
+
+    bf16 = jnp.bfloat16
+    slots, page = int(tr["slots"]), int(tr["page_size"])
+    pages_per_seq, C = int(tr["pages_per_seq"]), int(tr["prefill_chunk"])
+    if tr["runner"] == "serve_window_moe":
+        from apex_tpu.models.afmoe import AfmoeConfig, AfmoeModel
+
+        model = AfmoeModel(AfmoeConfig.from_hf(
+            cfg, num_experts=cfg["published"]["num_experts"],
+            held_experts=tuple(cfg["held_experts"]), params_dtype=bf16))
+        ccfg = KVCacheConfig.of_classes(
+            model.cache_classes(slots=slots, pages_per_seq=pages_per_seq,
+                                page_size=page, prefill_chunk=C),
+            page_size=page, max_seqs=slots, dtype=bf16)
+    else:
+        if tr["runner"] == "serve_hyper_latent":
+            from apex_tpu.models.xing4 import Xing4Config, Xing4Model
+
+            mcfg = Xing4Config.from_hf(cfg, params_dtype=bf16)
+            model = Xing4Model(mcfg)
+        else:
+            from apex_tpu.models.deepseek_v32 import (
+                DeepSeekV32Config, DeepSeekV32Model,
+            )
+
+            mcfg = DeepSeekV32Config.from_hf(
+                cfg, n_routed_experts=cfg["published"]["n_routed_experts"],
+                held_experts=tuple(cfg["held_experts"]), params_dtype=bf16)
+            model = DeepSeekV32Model(mcfg)
+        ccfg = KVCacheConfig(
+            num_layers=mcfg.num_hidden_layers, num_heads=1,
+            head_dim=mcfg.latent_dim, num_pages=1 + slots * pages_per_seq,
+            page_size=page, max_seqs=slots, pages_per_seq=pages_per_seq,
+            dtype=bf16, kind="latent", latent_dim=mcfg.latent_dim,
+            index_dim=mcfg.index_head_dim)
+    if parallel_state.model_parallel_is_initialized():
+        parallel_state.destroy_model_parallel()
+    mesh = parallel_state.initialize_model_parallel(
+        tensor_model_parallel_size_=1, devices=topo.devices[:1])
+    fns = model.decode_fns(None, mesh, ccfg,
+                           max_prompt_len=int(tr["max_prompt_len"]),
+                           prefill_chunk=C)
+    on_mesh = lambda tree: jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=NamedSharding(mesh, P())), tree)
+    params = on_mesh(jax.eval_shape(model.init, jax.random.PRNGKey(0)))
+    pools = on_mesh(jax.eval_shape(lambda: init_pools(ccfg)))
+    carry = on_mesh(jax.eval_shape(lambda: dict(
+        init_carry(slots), **fns.decode.carry_extras)))
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)
+    key = jax.eval_shape(lambda: jax.random.PRNGKey(0))
+    table_width = ccfg.table_columns[-1][1]
+    texts = {"jit__decode": fns.decode_jit.lower(
+        params, pools, carry, i32(slots, table_width)).compile().as_text()}
+    # one chunk program a context extent: those the cell's prompts reach
+    top = -(-int(tr["max_prompt_len"]) // C) * C
+    extents = getattr(fns.chunk, "ctx_buckets", None) or sorted(
+        {min(start + C, ccfg.max_len) for start in range(0, top, C)})
+    for ctx_len in extents:
+        texts[f"jit__chunk@{ctx_len}"] = fns.chunk_jit.lower(
+            params, pools, i32(1, C), i32(), i32(), i32(), i32(table_width),
+            key, ctx_len=ctx_len).compile().as_text()
+    parallel_state.destroy_model_parallel()
+    return texts
+
+
 def emit(root: str, out_dir: str, programs) -> None:
     """Child: compile ``programs`` from the checkout at ``root`` and
     write their stripped texts into ``out_dir``."""
@@ -238,10 +323,18 @@ def emit(root: str, out_dir: str, programs) -> None:
         config, traffic = PROGRAMS[program]
         cfg = _read(root, "configs", config)
         tr = _read(root, "traffic", traffic)
-        build = _train_step_text if tr["kind"] == "train" else _serve_texts
-        for module, text in build(root, topo, cfg, tr).items():
+        build = (_train_step_text if tr["kind"] == "train" else
+                 _serve_texts if tr.get("runner", "serve") == "serve" else
+                 _expert_serve_texts)
+        try:
+            texts = build(root, topo, cfg, tr)
+        except ImportError as e:
+            print(f"skipped {program}: {root} has no such model ({e})",
+                  flush=True)
+            continue
+        for module, text in texts.items():
             head = text.split("\n", 1)[0]
-            if not head.startswith(f"HloModule {module}"):
+            if not head.startswith(f"HloModule {module.split('@')[0]}"):
                 raise SystemExit(f"{program}: expected a program named "
                                  f"{module}, the text opens with {head!r}")
             path = os.path.join(out_dir, f"{program}.{module}.txt")
