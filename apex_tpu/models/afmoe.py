@@ -70,6 +70,7 @@ from apex_tpu.ops.attention import flash_attention, k_blocks_run
 from apex_tpu.ops.attention_decode import fmha_decode
 from apex_tpu.ops.layer_norm import fused_rms_norm_affine
 from apex_tpu.ops.rope import apply_rope_tables, rope_cos_sin, rope_table
+from apex_tpu.telemetry import programs as _programs
 from apex_tpu.telemetry.spans import phase
 from apex_tpu.transformer.moe import HeldExpertsMLP
 
@@ -659,6 +660,8 @@ class AfmoeModel:
                 "counters": carry["counters"] + counted,
                 "last_logits": logits, "last_attn": shown}
 
+        _programs.own(_chunk.__name__, _decode.__name__,
+                      layer="serving steps")
         cj = jax.jit(_chunk, donate_argnums=(1,), static_argnames=("ctx_len",))
         dj = jax.jit(_decode, donate_argnums=(1,))
 

@@ -71,6 +71,7 @@ from apex_tpu.ops.rope import (
     apply_rope_tables, yarn_inv_freq, yarn_mscale, yarn_table,
 )
 from apex_tpu.ops.sparse_index import index_scores, topk_indices, topk_mask
+from apex_tpu.telemetry import programs as _programs
 from apex_tpu.telemetry.spans import phase
 from apex_tpu.transformer.moe import HeldExpertsMLP
 
@@ -643,6 +644,8 @@ class DeepSeekV32Model:
                 "last_logits": logits, "last_selected": idx,
                 "last_selected_valid": chosen}
 
+        _programs.own(_chunk.__name__, _decode.__name__,
+                      layer="serving steps")
         cj = jax.jit(_chunk, donate_argnums=(1,), static_argnames=("ctx_len",))
         dj = jax.jit(_decode, donate_argnums=(1,))
 

@@ -40,6 +40,7 @@ from apex_tpu.ops.layer_norm import (
     fused_layer_norm_affine,
     fused_rms_norm_affine,
 )
+from apex_tpu.telemetry import programs as _programs
 from apex_tpu.telemetry.spans import phase
 from apex_tpu.transformer.parallel_state import (
     DATA_PARALLEL_AXIS,
@@ -1785,6 +1786,10 @@ class GPTModel:
         from apex_tpu.serving.serve import init_carry
 
         carry_tmpl = init_carry(cfg.max_seqs)
+        # the names jax gives the executables made below
+        _programs.own(*(f.__name__ for f in (
+            _prefill, _chunk, _decode, _spec, _spec_tree)),
+            layer="serving steps")
         pf = jax.jit(jax.shard_map(
             _prefill, mesh=mesh,
             in_specs=(specs, pool_specs, P(), P(), P(), P()),

@@ -117,6 +117,7 @@ from apex_tpu.serving.kv_cache import (
     prompt_page_hashes,
     staged_nbytes,
 )
+from apex_tpu.telemetry import programs as _programs
 from apex_tpu.telemetry.spans import host_span
 
 __all__ = ["Request", "Completion", "HandoffPacket",
@@ -155,6 +156,12 @@ def _import_state(pools, carry, staged, pages, slot, last, written,
 
 
 _import_state_jit = jax.jit(_import_state, donate_argnums=(0, 1))
+
+# the three programs above are this module's; a scheduler turn reads
+# the process's ledger of executables to say when it recompiled
+_programs.own(copy_pages.__name__, import_pages.__name__,
+              _import_state.__name__, layer="serving entry")
+_ledger = _programs.ledger
 
 #: the harvest-resolve seam: both windows pull device results through
 #: this module alias, so the resilience tier can inject a hanging
@@ -523,6 +530,12 @@ class ContinuousBatcher:
         self.steps = 0
         self.windows = 0
         self.turns = 0          # scheduler turns (``pump`` calls)
+        #: turns in which jax obtained an executable (a shape that was
+        #: not warmed: every slot waited for it), and the last such
+        #: turn's number; the process's ledger says what it obtained
+        #: (``apex_tpu.telemetry.programs``)
+        self.compiled_turns = 0
+        self.last_compiled_turn: Optional[int] = None
         self.prefill_chunks = 0
         #: prefill wall time spent while >= 1 decoding slot was live
         #: (total, and the worst single stall) — meaningful when
@@ -1440,6 +1453,7 @@ class ContinuousBatcher:
         admissions).  ``queue`` is a ``collections.deque`` of
         :class:`Request`; admitted entries are popped, backpressured
         ones stay."""
+        obtained, obtain_s = _ledger.count, _ledger.obtain_s_total
         with host_span("serve.pump", turn=self.turns, queued=len(queue),
                        live_slots=self.live_slots) as span:
             self.turns += 1
@@ -1460,6 +1474,18 @@ class ContinuousBatcher:
                        self.cache.pages_in_use().items()},
                     **{f"pages_overwritten_{k}": v for k, v in
                        self.cache.overwritten_pages.items()})
+            if _ledger.count != obtained:
+                # this turn met a shape nobody warmed: say so where the
+                # stall stands, on the trace's clock and on the host
+                self.compiled_turns += 1
+                self.last_compiled_turn = self.turns - 1
+                names = dict.fromkeys(
+                    r.name for r in _ledger.records_from(obtained))
+                span.set_metadata(
+                    executables=_ledger.count - obtained,
+                    obtain_us=int(1e6 * (_ledger.obtain_s_total
+                                         - obtain_s)),
+                    obtained=",".join(names))
         return bool(self._meta or self._prefilling or queue)
 
     # --------------------------------------------------------------- run

@@ -1,7 +1,7 @@
 """apex_tpu.telemetry — runtime metrics, events and phase traces.
 
 The runtime half of the observability story (:mod:`apex_tpu.pyprof` is
-the offline half: trace capture + XLA cost analysis).  Three modules:
+the offline half: trace capture + XLA cost analysis).  Four modules:
 
 - :mod:`~apex_tpu.telemetry.metrics` — :class:`MetricsLogger`
   (counters/gauges/timings/step scalars, process-0 JSONL sink with
@@ -21,6 +21,12 @@ the offline half: trace capture + XLA cost analysis).  Three modules:
   ``tlm.<name>`` host spans on the profiler's clock, and
   :class:`TraceTrigger` (touch-file / env armed mid-run xplane capture
   of K steps).
+- :mod:`~apex_tpu.telemetry.programs` — the ledger of every executable
+  jax traces, lowers and obtains in this process, by name and stage
+  (:data:`~apex_tpu.telemetry.programs.ledger`, installed on import;
+  :func:`~apex_tpu.telemetry.programs.own` claims the main path's
+  names): what set-up is made of, and how a scheduler turn says that it
+  recompiled.
 
 ``tools/metrics_report.py`` turns the JSONL stream into a run summary;
 the workflow is documented in docs/observability.md.
@@ -35,6 +41,7 @@ from apex_tpu.telemetry import events  # noqa: F401  (stdlib-only)
 _LAZY_ATTRS = {
     "metrics": "apex_tpu.telemetry.metrics",
     "spans": "apex_tpu.telemetry.spans",
+    "programs": "apex_tpu.telemetry.programs",
     "MetricsLogger": "apex_tpu.telemetry.metrics",
     "StepStats": "apex_tpu.telemetry.metrics",
     "transformer_flops_per_token": "apex_tpu.telemetry.metrics",
@@ -58,7 +65,8 @@ def __getattr__(name):
         import importlib
 
         mod = importlib.import_module(_LAZY_ATTRS[name])
-        val = mod if name in ("metrics", "spans") else getattr(mod, name)
+        val = mod if name in ("metrics", "spans", "programs") \
+            else getattr(mod, name)
         globals()[name] = val
         return val
     raise AttributeError(

@@ -43,7 +43,6 @@ import time
 import numpy as np
 
 REPO = os.path.dirname(os.path.abspath(__file__))
-BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -92,19 +91,27 @@ def check(ok: bool, what: str) -> None:
 class CompileClock:
     """Seconds jax spent obtaining executables (XLA compilation on a
     cold cache, a cache read on a warm one) and how many it obtained
-    per jitted function, from jax's own monitoring events."""
+    per jitted function, since this clock was made: a view of the
+    program's own ledger (``apex_tpu.telemetry.programs``), which is
+    the one listener to jax's compile events."""
 
     def __init__(self):
-        import jax
+        from apex_tpu.telemetry import programs
 
-        self.total = 0.0
-        self.times = collections.Counter()  # "jit(name)" -> executables
-        jax.monitoring.register_event_duration_secs_listener(self._on)
+        self._ledger = programs.ledger
+        self._count0 = self._ledger.count
+        self._total0 = self._ledger.obtain_s_total
 
-    def _on(self, event, duration, fun_name=None, **_):
-        if event == BACKEND_COMPILE_EVENT:
-            self.total += duration
-            self.times[fun_name] += 1
+    @property
+    def total(self) -> float:
+        return self._ledger.obtain_s_total - self._total0
+
+    @property
+    def times(self) -> collections.Counter:
+        """"jit(name)" -> executables."""
+        return collections.Counter(
+            f"jit({r.name})"
+            for r in self._ledger.records_from(self._count0))
 
 
 def layout(n_devices: int, heads: int) -> dict:

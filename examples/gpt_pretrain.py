@@ -42,6 +42,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from apex_tpu import amp
 from apex_tpu.models import GPTConfig, GPTModel
 from apex_tpu.optimizers import FusedAdam
+from apex_tpu.telemetry import programs as _programs
 from apex_tpu.telemetry.metrics import (
     MetricsLogger,
     StepStats,
@@ -534,6 +535,7 @@ def main(argv=None):
     # the threaded "params" are the flat shard under --zero3 — the
     # replicated tree never exists between steps
     store_spec = shard_spec if args.zero3 else specs
+    _programs.own(train_step.__name__, layer="train step")
     step = jax.jit(
         jax.shard_map(
             train_step, mesh=mesh,
