@@ -5,8 +5,9 @@ from __future__ import annotations
 import jax
 from jax.ad_checkpoint import checkpoint_name
 
-__all__ = ["shape_struct", "run_kernel", "KernelLoweringError",
-           "ATTENTION_RESIDUAL_NAMES", "name_attention_residuals"]
+__all__ = ["shape_struct", "largest_tile", "run_kernel",
+           "KernelLoweringError", "ATTENTION_RESIDUAL_NAMES",
+           "name_attention_residuals"]
 
 #: ``checkpoint_name`` tags of the two residuals that only the forward
 #: kernel can produce.  A remat policy that saves these names
@@ -58,3 +59,10 @@ def shape_struct(shape, dtype, *varying_like) -> jax.ShapeDtypeStruct:
     runs on dp-sharded activations inside a tensor-parallel region."""
     vma = frozenset().union(*(jax.typeof(x).vma for x in varying_like))
     return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
+
+
+def largest_tile(size: int, most: int) -> int:
+    """The largest multiple of 128 (a lane's width) that divides ``size``
+    (a multiple of 128) and is at most ``most`` (at least 128)."""
+    return max(t for t in range(128, max(most, 128) + 1, 128)
+               if size % t == 0)

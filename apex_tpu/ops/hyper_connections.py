@@ -46,7 +46,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from apex_tpu.ops.attention import _interpret
-from apex_tpu.ops.common import run_kernel, shape_struct
+from apex_tpu.ops.common import largest_tile, run_kernel, shape_struct
 from apex_tpu.telemetry.spans import kernel_name
 
 __all__ = ["hc_mapping", "hc_read", "hc_mix", "sinkhorn"]
@@ -123,13 +123,6 @@ def _mapping_kernel(x_ref, phi_ref, alpha_ref, bias_ref, pre_ref, post_ref,
             res_ref[i * n:(i + 1) * n] = r
 
 
-def _tile(size: int, most: int) -> int:
-    """The largest multiple of 128 that divides ``size`` (a multiple of
-    128) and is at most ``most`` (at least 128)."""
-    return max(t for t in range(_LANES, max(most, _LANES) + 1, _LANES)
-               if size % t == 0)
-
-
 def _mapping_pallas(streams, phi, alpha, bias, rms_eps, kw):
     n, T, C = streams.shape
     K = phi.shape[1]
@@ -140,8 +133,8 @@ def _mapping_pallas(streams, phi, alpha, bias, rms_eps, kw):
         streams = jnp.pad(streams, ((0, 0), (0, pad_t), (0, pad_c)))
         phi = jnp.pad(phi, ((0, 0), (0, 0), (0, pad_c)))
     Tp, Cp, Kp = T + pad_t, C + pad_c, -(-K // _LANES) * _LANES
-    tt = _tile(Tp, HC_MAP_TOKENS)
-    tc = _tile(Cp, HC_MAP_BLOCK_BYTES // (4 * n * tt))
+    tt = largest_tile(Tp, HC_MAP_TOKENS)
+    tc = largest_tile(Cp, HC_MAP_BLOCK_BYTES // (4 * n * tt))
     tokens = lambda t, c: (0, t)
     fixed = lambda t, c: (0, 0)
     out = lambda rows: shape_struct((rows, Tp), jnp.float32, streams)

@@ -37,13 +37,23 @@ import numpy as np
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
+from apex_tpu.ops.moe_grouped import grouped_swiglu
 from apex_tpu.telemetry.spans import phase
 from apex_tpu.transformer.parallel_state import (
     DATA_PARALLEL_AXIS,
     TENSOR_PARALLEL_AXIS,
 )
+from apex_tpu.utils.platform import default_implementation
 
 __all__ = ["MoEMLP", "HeldExpertsMLP"]
+
+#: rows an expert can expect (``n * top_k // num_experts``) from which
+#: :meth:`HeldExpertsMLP.apply` multiplies its tiles in one grouped
+#: Mosaic product, not in a loop: a whole 128-row tile.  From there the
+#: grouped form won every measurement (PERF.md section 6, PR 38: by 41-45
+#: % at Xing4's widths with every expert held, by 4-10 % at
+#: DeepSeek-V3.2's with 16 of 256); at 64 it depends on the share held
+GROUPED_MIN_ROWS = 128
 
 
 class MoEMLP:
@@ -280,11 +290,38 @@ class HeldExpertsMLP:
 
     **Grouped computation.**  The (token, choice) pairs that landed on
     held experts are sorted by expert and laid out in tiles of
-    ``tile_rows`` rows, each tile one expert's; a loop over the tiles
-    IN USE (a dynamic count) multiplies each by its expert's three
-    matrices, and every token then gathers and weights its own rows.  Work and
+    ``tile_rows`` rows, each tile one expert's; the tiles IN USE (a
+    dynamic count) are multiplied by their experts' three matrices, and
+    every token then gathers and weights its own rows.  Work and
     weight traffic follow the pairs that exist: an expert nobody chose
     is not read, and there is no (n, E, capacity) dispatch mask.
+
+    One layout, two forms of the products, told apart by a shape: the
+    rows an expert can expect, ``n * top_k // num_experts``.
+
+    - Under 128 (``GROUPED_MIN_ROWS``) — every decode step (tiles of 16
+      rows, under 32) and a chunk whose experts expect less than a
+      128-row tile each: an XLA loop over the tiles that slices each
+      tile's expert out of the stack.  It reads exactly the experts
+      touched, once each while an expert owns one tile, and that read
+      is what bounds a step.
+    - 128 or more (a long prefill chunk over few experts: experts own
+      two tiles and more, the hot ones of a skewed router many).  The
+      loop would read an expert's weights again for EVERY tile, and a
+      128-row tile does 128 FLOPs a weight byte where a v5e's ridge is
+      240: each tile is bound by the re-read.  On a TPU, for weights
+      narrower than float32, the tiles go through
+      :mod:`apex_tpu.ops.moe_grouped` instead: one Mosaic product that
+      copies an expert's weights once however many tiles it owns (FLOPs
+      a weight byte then grow with the expert's rows), the same
+      arithmetic in the same precisions.  Off the TPU and for float32
+      (the references) the 128-row loop stays.
+
+    The grouped form gathers every row of the static bound of tiles,
+    live or not, so where few of the experts are held (the bound is for
+    all pairs landing here) its layout costs more than its product
+    saves at small loads: at 64 rows an expert it gains 21 % with all 64
+    of 64 held and loses 21 % with 16 of 256.
     """
 
     #: what :meth:`apply` counts, in this order; the load of each held
@@ -405,10 +442,12 @@ class HeldExpertsMLP:
                           preferred_element_type=jnp.float32)
 
     def _experts(self, experts, x, chosen, g, held, token_valid,
-                 tile_rows: int, layer=None):
+                 tile_rows: int, layer=None, grouped: bool = False):
         """Gathers and dynamic slices only, no scatter: rows find their
         tokens through the sorted order, tokens find their rows through
-        a running count per expert."""
+        a running count per expert.  ``grouped``: the tiles go to
+        :func:`~apex_tpu.ops.moe_grouped.grouped_swiglu` in one call
+        and not through the loop one by one."""
         n, h = x.shape
         k, nh = self.top_k, len(held)
         T = tile_rows
@@ -449,8 +488,25 @@ class HeldExpertsMLP:
             return lax.dynamic_update_slice(
                 buf, out.astype(buf.dtype), (t * T, 0))
 
-        buf = lax.fori_loop(0, jnp.sum(tiles), tile,
-                            jnp.zeros((n_tiles * T, h), x.dtype))
+        if grouped:
+            # every tile's rows in ONE gather, every expert's weights
+            # fetched once.  Rows past an expert's pairs are not zeroed
+            # as ``tile`` zeroes them (a pass over all the rows, 0.5 ms
+            # a layer at Xing4's chunk): they hold some token's row, so
+            # what is computed for them is finite, and no pair reads it.
+            # Tile 0 always runs, so that row 0, which the pairs not
+            # held here read (weighted by 0), is written.
+            e = jnp.repeat(tile_expert, T)
+            offset = jnp.arange(n_tiles * T, dtype=jnp.int32) - row_start[e]
+            pair = order[jnp.clip(src_start[e] + offset, 0, n * k - 1)]
+            buf = grouped_swiglu(
+                x[pair // k],
+                *(experts[name] for name in ("w_gate", "w_up", "w_down")),
+                tile_expert, jnp.maximum(jnp.sum(tiles), 1),
+                0 if layer is None else layer)
+        else:
+            buf = lax.fori_loop(0, jnp.sum(tiles), tile,
+                                jnp.zeros((n_tiles * T, h), x.dtype))
         # a pair's row: its expert's first row plus how many earlier
         # pairs chose the same expert (the stable sort's order)
         within = jnp.sum(jnp.where(
@@ -490,7 +546,9 @@ class HeldExpertsMLP:
         stacks, in that order.  ``token_valid`` (n,) marks padding rows
         of a prefill chunk and idle decode slots: they route nothing and
         count nothing.  ``tile_rows`` defaults to 128 where an expert
-        can expect that many rows, else 16.  With ``expert_layer`` (a traced scalar is fine) the leaves of
+        can expect 32 rows or more, else 16; which form multiplies the
+        tiles is decided here and is no argument (class docstring,
+        "Grouped computation").  With ``expert_layer`` (a traced scalar is fine) the leaves of
         ``params['experts']`` keep a leading layer axis and that layer's
         experts are used: a model that scans over its layers hands the
         whole stack in, so that no layer's experts are sliced out of it.
@@ -507,15 +565,18 @@ class HeldExpertsMLP:
                 f"{stacked}")
         if token_valid is None:
             token_valid = jnp.ones((n,), bool)
+        dtype = params["experts"]["w_gate"].dtype
+        expected = n * self.top_k // self.num_experts
+        grouped = (expected >= GROUPED_MIN_ROWS and dtype != jnp.float32
+                   and default_implementation() == "pallas")
         if tile_rows is None:
-            expected = n * self.top_k // self.num_experts
             tile_rows = 128 if expected >= 32 else 16
         chosen, g = self.route(params, x)
-        x = x.astype(params["experts"]["w_gate"].dtype)
+        x = x.astype(dtype)
         with phase("moe.experts"):
             y, counters = self._experts(
                 params["experts"], x, chosen, g, held, token_valid,
-                tile_rows, expert_layer)
+                tile_rows, expert_layer, grouped)
         with phase("moe.shared"):
             sh = params["shared"]
             y = y + self._swiglu(x, sh["w_gate"], sh["w_up"], sh["w_down"])

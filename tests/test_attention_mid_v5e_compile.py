@@ -195,7 +195,7 @@ POSITIONED_CALLS = {
 
 @pytest.mark.parametrize("call", list(POSITIONED_CALLS))
 def test_positioned_flash_forward_compiles_and_holds_no_mask(
-        one_chip, monkeypatch, call):
+        one_chip, monkeypatch, request, call):
     """``flash_attention(causal=True, q_offset=<traced>, ...)`` at its
     default blocks: one Mosaic call whose bounds arrive as prefetched
     scalars, and nothing the size of a ``(rows, keys)`` float32 mask in
@@ -205,6 +205,11 @@ def test_positioned_flash_forward_compiles_and_holds_no_mask(
 
     monkeypatch.setattr(attention, "_interpret", lambda: False)
     monkeypatch.setattr(platform, "_current_platform", lambda: "tpu")
+    # the positioned call is a jitted function: a trace an earlier test
+    # of this process made at these shapes was built for the
+    # interpreter, and this one must not be found by a later test
+    attention._flash_positioned_once.clear_cache()
+    request.addfinalizer(attention._flash_positioned_once.clear_cache)
     h, sq, sk, d, period, window = POSITIONED_CALLS[call]
     sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
     bf = jnp.bfloat16
@@ -217,3 +222,35 @@ def test_positioned_flash_forward_compiles_and_holds_no_mask(
     assert text.count("tpu_custom_call") == 1
     assert "tlm.kernel.fmha_flash.fwd" in text
     assert f"f32[{sq},{sk}]" not in text and f"f32[1,{sq},{sk}]" not in text
+
+
+def test_grouped_expert_product_compiles_and_copies_no_layer(
+        one_chip, monkeypatch):
+    """``HeldExpertsMLP.apply`` at Xing4's chunk (4,096 tokens, 4 of 64
+    experts each, all held) over the layer-stacked experts with a traced
+    layer, as the chunk program's scan calls it: the two ``moe_grouped``
+    Mosaic calls (their weight blocks within the VMEM limit they ask
+    for), no loop over tiles, and no temporary the size of a layer's
+    experts (1.4 GB): the kernel indexes the stack, nothing slices it."""
+    from apex_tpu.ops import moe_grouped
+    from apex_tpu.transformer.moe import HeldExpertsMLP
+    from apex_tpu.utils import platform
+
+    monkeypatch.setattr(moe_grouped, "_interpret", lambda: False)
+    monkeypatch.setattr(platform, "_current_platform", lambda: "tpu")
+    h, f, experts, k, n, layers = 3584, 1024, 64, 4, 4096, 5
+    layer = HeldExpertsMLP(h, f, experts, top_k=k)
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    params = jax.tree.map(
+        lambda leaf: sds(leaf.shape, leaf.dtype),
+        jax.eval_shape(lambda: layer.init(jax.random.PRNGKey(0), experts)))
+    params["experts"] = {name: sds((layers,) + leaf.shape, leaf.dtype)
+                         for name, leaf in params["experts"].items()}
+    compiled = jax.jit(lambda p, x, j: layer.apply(
+        p, x, tuple(range(experts)), expert_layer=j)).lower(
+            params, sds((n, h), jnp.bfloat16), sds((), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 2
+    assert "tlm.kernel.moe_grouped.gate_up" in text
+    assert "tlm.kernel.moe_grouped.down" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 2**30

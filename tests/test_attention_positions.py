@@ -234,7 +234,11 @@ def test_a_programs_layers_lower_the_positioned_kernel_once():
             i32(), i32(), i32(ccfg.table_columns[-1][1]),
             jax.eval_shape(lambda: jax.random.PRNGKey(0)), ctx_len=8192,
         ).lower(lowering_platforms=("tpu",)).as_text()
-    assert text.count("tpu_custom_call") == 2
+    assert text.count('kernel_name = "tlm.kernel.fmha_flash.fwd"') == 2
+    # the toy's experts expect 128 rows a chunk (Trinity's 16), so since
+    # PR 38 its four expert layers take the grouped product, a jitted
+    # call as well: each of its two kernels lowered once
+    assert text.count("tpu_custom_call") == 4
 
 
 # every variant that takes no positions, at the count it had before

@@ -550,7 +550,9 @@ def recompiling_server(tmp_path_factory):
     inside a profiler session, a two-chunk prompt (extent 16 is new) and
     the same once more (warm)."""
     stack = _dsv32_stack()
+    n_warm = ledger.count
     warmed = _serve(stack, 6, "warm")
+    warm_obtained = ledger.records_from(n_warm)
     directory = str(tmp_path_factory.mktemp("trace"))
     opts = jax.profiler.ProfileOptions()
     opts.python_tracer_level = 0
@@ -567,6 +569,7 @@ def recompiling_server(tmp_path_factory):
     spans = _pump_spans(directory)
     assert len(spans) == cold.turns + again.turns
     return {"warmed": warmed, "cold": cold, "again": again,
+            "warm_obtained": warm_obtained,
             "cold_spans": spans[:cold.turns],
             "again_spans": spans[cold.turns:],
             "obtained": ledger.records_from(n0)[:n1 - n0],
@@ -590,7 +593,11 @@ def test_a_turn_that_meets_a_new_shape_says_so(recompiling_server):
         assert set(s["obtained"].split(",")) <= {
             r.name for r in run["obtained"]}
     (rec,) = _named(run["obtained"], "_chunk")
-    assert rec.seq == 2 and programs.layer_of("_chunk") == "serving steps"
+    # the executable after the warm-up's (the process's second where
+    # this file runs first: the ledger counts a name's over the process)
+    (warm,) = _named(run["warm_obtained"], "_chunk")
+    assert rec.seq == warm.seq + 1
+    assert programs.layer_of("_chunk") == "serving steps"
     # the turns that obtained nothing carry none of the three
     for s in spans:
         if s not in said:
