@@ -22,9 +22,9 @@ attention, three walks of it:
   gathering 2048 rows for each of 2048 queries would move 4.8 GB a
   layer);
 - a DECODE step — one query a slot: the index keys of the slot's pages
-  are scored, the chosen positions are turned into physical rows through
-  the page table, and attention runs in the ABSORBED form over those at
-  most ``index_topk`` gathered rows, never over the whole context.
+  are scored and attention runs in the ABSORBED form over the exact
+  ``index_topk`` best: the latent page walk reads every live row of the
+  slot where it lies and masks out the rows not chosen.
 
 **FFN**: ``first_k_dense`` leading SwiGLU layers, then
 :class:`apex_tpu.transformer.moe.HeldExpertsMLP` layers.  The router
@@ -63,14 +63,16 @@ from jax import lax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from apex_tpu.models.gpt import GPTDecodeFns
-from apex_tpu.ops.attention_latent import mla_absorbed, mla_expanded
+from apex_tpu.ops.attention_latent import mla_expanded, mla_paged
 from apex_tpu.ops.layer_norm import (
     fused_layer_norm_affine, fused_rms_norm_affine,
 )
 from apex_tpu.ops.rope import (
     apply_rope_tables, yarn_inv_freq, yarn_mscale, yarn_table,
 )
-from apex_tpu.ops.sparse_index import index_scores, topk_indices, topk_mask
+from apex_tpu.ops.sparse_index import (
+    index_scores, mask_at, topk_indices, topk_mask,
+)
 from apex_tpu.telemetry import programs as _programs
 from apex_tpu.telemetry.spans import phase
 from apex_tpu.transformer.moe import HeldExpertsMLP
@@ -511,8 +513,10 @@ class DeepSeekV32Model:
         """One token for every slot: ``tokens`` (B,) at ``positions``
         (B,) (the slot's context length), ``active`` (B,) bool.  Each
         layer writes the new row, scores the slot's cached index keys,
-        takes the exact top ``index_topk`` positions, gathers THOSE rows
-        through the page table and attends in the absorbed form.
+        takes the exact top ``index_topk`` positions and attends over
+        them in the absorbed form, walking the slot's pages with every
+        row not chosen masked out (the mask is the same set as the
+        positions, from the K-th score: ``sparse_index.mask_at``).
         Returns (fp32 logits (B, vocab), pools, counters (6,), the
         chosen positions (layers, B, K) int32 and which of them are real
         (layers, B, K) bool)."""
@@ -528,6 +532,7 @@ class DeepSeekV32Model:
         in_ctx = (jnp.arange(max_len, dtype=jnp.int32)[None]
                   <= positions[:, None]) & active[:, None]
         K = min(c.index_topk, max_len)
+        lengths = jnp.where(active, positions + 1, 0)
 
         def attend(ap, h, layer, pools):
             proj = self._project(ap, h, cos, sin)
@@ -539,23 +544,17 @@ class DeepSeekV32Model:
                     h.shape[0], max_len, -1)
                 scores = index_scores(proj.q_idx[:, None],
                                       proj.w_idx[:, None], kidx)[:, 0]
-            idx, chosen = topk_indices(scores, K, in_ctx)
+            idx, chosen, kth = topk_indices(scores, K, in_ctx)
             w_uk, w_uv = self._w_kvb(ap)
             with phase("attn.mla"):
-                # the gather IS the attention's HBM traffic: each chosen
-                # row read once, through the page table
                 with phase("attn.mla.core"):
-                    rows = pools["ckv"][
-                        layer,
-                        jnp.take_along_axis(page_table, idx // page_size,
-                                            axis=1),
-                        idx % page_size]
-                o = mla_absorbed(proj.q_nope, proj.q_rope, rows, chosen,
-                                 w_uk, w_uv, c.softmax_scale)
-            sel = jnp.stack([
-                jnp.sum(chosen).astype(jnp.float32),
-                jnp.sum(jnp.where(active, positions + 1, 0)
-                        ).astype(jnp.float32)])
+                    selected = mask_at(jnp.where(in_ctx, scores, -jnp.inf),
+                                       kth, K, in_ctx)
+                o = mla_paged(proj.q_nope, proj.q_rope, pools["ckv"], layer,
+                              page_table, lengths, w_uk, w_uv,
+                              c.softmax_scale, selected=selected)
+            sel = jnp.stack([jnp.sum(chosen).astype(jnp.float32),
+                             jnp.sum(lengths).astype(jnp.float32)])
             return self._out(ap, o), pools, sel, (idx, chosen)
 
         x = self._embed(params, tokens)

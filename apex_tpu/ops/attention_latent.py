@@ -16,15 +16,19 @@ tiles; whatever follows ``k_r`` is zero and is never read as a value).  ``W_kvb`
   on the cached rows themselves (576 and 512 wide), ``W_UV`` is applied
   to the result.  No per-head key or value is ever built, so a decode
   step reads each selected row once for all heads.
-- **absorbed, over the pages** (:func:`mla_paged`, decode without a
-  selection): the same form over the WHOLE context of every slot, read
-  where it lies.  A Mosaic kernel walks the slot's page-table row,
-  several pages a grid step (:mod:`apex_tpu.ops.attention_decode`'s
-  walk: the pool stays in HBM, a step's LIVE pages are copied into one
-  of two VMEM tiles with the next step's copies in flight), and every
-  row serves all heads from that one fetch: key = the whole row, value
-  = its first ``kv_lora_rank`` columns.  No gathered copy of the
-  context exists, and the pool is never sliced by layer.
+- **absorbed, over the pages** (:func:`mla_paged`, decode): the same
+  form over the context of every slot, read where it lies.  A Mosaic
+  kernel walks the slot's page-table row, several pages a grid step
+  (:mod:`apex_tpu.ops.attention_decode`'s walk: the pool stays in HBM, a
+  step's LIVE pages are copied into one of two VMEM tiles with the next
+  step's copies in flight), and every row serves all heads from that one
+  fetch: key = the whole row, value = its first ``kv_lora_rank``
+  columns.  No gathered copy of the context exists, and the pool is
+  never sliced by layer.  Without a selection a query sees its slot's
+  whole context; with one (``selected``, a sparse selection as a mask)
+  the walk still reads every live row and masks out those not chosen —
+  cheaper than gathering the chosen rows while they are a large share
+  of the context (``models/deepseek_v32.py`` says where it turns).
 
 Both are the same mathematics (``tests/test_deepseek_v32.py`` holds
 them to each other).  Masked entries take a finite ``-1e30``, so a row
@@ -146,13 +150,18 @@ def mla_absorbed(q_nope, q_rope, rows, chosen, w_uk, w_uv, scale: float):
 # ---------------------------------------------------------------------------
 
 
-def _latent_walk_kernel(pt_ref, len_ref, layer_ref, q_ref, pool_ref, o_ref,
-                        qs_ref, acc_ref, m_ref, l_ref, tile, sem, state,
-                        *, cfg: _DecodeConfig, dc: int):
+def _latent_walk_kernel(pt_ref, len_ref, layer_ref, q_ref, *refs,
+                        cfg: _DecodeConfig, dc: int, selected: bool):
     """Program ``(slot, 0, step)``: ``cfg.pages`` logical pages of the
     slot's context against all heads' absorbed queries.  The online
     softmax is :func:`apex_tpu.ops.attention_decode._decode_kernel`'s;
-    keys and values are ONE tile."""
+    keys and values are ONE tile.  With ``selected`` the step's block of
+    the slot's selection (1, 1, 1, pages x page_size) int32 comes before
+    the pool, and a row it holds 0 for is masked like a row past the
+    slot's length."""
+    sel_ref = refs[0] if selected else None
+    (pool_ref, o_ref, qs_ref, acc_ref, m_ref, l_ref, tile, sem,
+     state) = refs[1:] if selected else refs
     at = b, _, step = tuple(pl.program_id(i) for i in range(3))
     grid = tuple(pl.num_programs(i) for i in range(3))
     ps, P = cfg.page_size, cfg.pages
@@ -186,6 +195,8 @@ def _latent_walk_kernel(pt_ref, len_ref, layer_ref, q_ref, pool_ref, o_ref,
                             preferred_element_type=jnp.float32)
         mask = step * P * ps + lax.broadcasted_iota(
             jnp.int32, s.shape, 1) < len_ref[b]
+        if selected:
+            mask = mask & (sel_ref[0, 0] != 0)
         s = jnp.where(mask, s, _NEG_INF)
         m_prev = m_ref[...]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
@@ -204,9 +215,11 @@ def _latent_walk_kernel(pt_ref, len_ref, layer_ref, q_ref, pool_ref, o_ref,
                     ).astype(o_ref.dtype)
 
 
-def _latent_walk(q, pool, layer, page_table, lengths, scale: float, dc: int):
+def _latent_walk(q, pool, layer, page_table, lengths, scale: float, dc: int,
+                 selected=None):
     """``q`` (B, H, W) absorbed queries as wide as a pool row; ``pool``
-    (layers, pages, page_size, W) -> (B, H, dc)."""
+    (layers, pages, page_size, W); ``selected`` None or (B, pages a slot
+    x page_size) bool -> (B, H, dc)."""
     B, H, W = q.shape
     ps, width = pool.shape[2], page_table.shape[1]
     cfg = _DecodeConfig(
@@ -214,13 +227,25 @@ def _latent_walk(q, pool, layer, page_table, lengths, scale: float, dc: int):
         num_pages=width, kv_block=0, has_scales=False, has_rope=False,
         pages=_pages_per_step(ps, W, 1, pool.dtype.itemsize, width, False),
         copies=True)
+    steps = -(-width // cfg.pages)
     per_slot = lambda b, h, p, *scalars: (b, 0, 0)
+    sel_spec, sel = [], ()
+    if selected is not None:
+        # int32 rows a slot and step, each a whole lane-dense block: a
+        # step's selection arrives with the step, as its pages do
+        n = cfg.pages * ps
+        sel = (jnp.pad(selected.astype(jnp.int32),
+                       ((0, 0), (0, steps * n - selected.shape[1]))
+                       ).reshape(B, steps, 1, n),)
+        sel_spec = [pl.BlockSpec((1, 1, 1, n),
+                                 lambda b, h, p, *scalars: (b, p, 0, 0))]
     return pl.pallas_call(
-        functools.partial(_latent_walk_kernel, cfg=cfg, dc=dc),
+        functools.partial(_latent_walk_kernel, cfg=cfg, dc=dc,
+                          selected=selected is not None),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
-            grid=(B, 1, -(-width // cfg.pages)),
-            in_specs=[pl.BlockSpec((1, H, W), per_slot),
+            grid=(B, 1, steps),
+            in_specs=[pl.BlockSpec((1, H, W), per_slot), *sel_spec,
                       pl.BlockSpec(memory_space=pl.ANY)],
             out_specs=pl.BlockSpec((1, H, dc), per_slot),
             scratch_shapes=[
@@ -240,19 +265,22 @@ def _latent_walk(q, pool, layer, page_table, lengths, scale: float, dc: int):
         interpret=_interpret(),
         name=kernel_name("latent_walk"),
     )(page_table.astype(jnp.int32), lengths.astype(jnp.int32),
-      jnp.asarray(layer, jnp.int32).reshape(1), q, pool)
+      jnp.asarray(layer, jnp.int32).reshape(1), q, *sel, pool)
 
 
 def mla_paged(q_nope, q_rope, pool, layer, page_table, lengths, w_uk, w_uv,
-              scale: float, *, implementation: Optional[str] = None):
-    """The absorbed form of one query a slot over the slot's WHOLE paged
+              scale: float, *, selected=None,
+              implementation: Optional[str] = None):
+    """The absorbed form of one query a slot over the slot's paged
     context: ``q_nope`` (B, H, dn), ``q_rope`` (B, H, dr); ``pool``
     (layers, pages, page_size, >= dc + dr) the stacked latent pool and
     ``layer`` (a traced scalar is fine) the layer read; ``page_table``
     (B, pages a slot) physical pages (unallocated entries hold a valid
     page, the null page 0); ``lengths`` (B,) the rows a slot's query
     sees, its own included (0: an idle slot, whose output is zeros on
-    the kernel's path) -> (B, H, dv).
+    the kernel's path) -> (B, H, dv).  ``selected`` (B, pages a slot x
+    page_size) bool, or None for all: of those rows, the ones the query
+    sees (a sparse selection; every live row is still read).
 
     ``implementation``: None = the Mosaic walk on a TPU and XLA
     elsewhere (the rows gathered through the table, then
@@ -266,12 +294,15 @@ def mla_paged(q_nope, q_rope, pool, layer, page_table, lengths, w_uk, w_uv,
         rows = pool[layer, page_table].reshape(B, -1, pool.shape[-1])
         seen = jnp.arange(rows.shape[1], dtype=jnp.int32)[None] \
             < lengths[:, None]
+        if selected is not None:
+            seen = seen & selected
         return mla_absorbed(q_nope, q_rope, rows, seen, w_uk, w_uv, scale)
 
     def walk():
         q = _absorbed_query(q_nope, q_rope, w_uk, pool)
         with phase("attn.mla.core"):
-            o = _latent_walk(q, pool, layer, page_table, lengths, scale, dc)
+            o = _latent_walk(q, pool, layer, page_table, lengths, scale, dc,
+                             selected)
         return jnp.einsum("bhc,chd->bhd", o, w_uv)
 
     return run_kernel("latent_walk", walk, xla,

@@ -19,9 +19,11 @@ set a stable descending sort gives.  Two forms of one selection:
   the k-th largest score of each row by bisection on the score's bits —
   32 counting passes, no sort — and breaks ties at the threshold by a
   running count;
-- :func:`topk_indices` (decode: attention runs over gathered rows)
-  returns the positions themselves through ``lax.top_k``, which orders
-  equal values by index.
+- :func:`topk_indices` (decode) returns the positions themselves
+  through ``lax.top_k``, which orders equal values by index, and the
+  k-th largest score: :func:`mask_at` turns that threshold into the
+  same set as a mask, elementwise and one running count (a decode step
+  whose attention walks the pages under a mask).
 
 ``lax.approx_max_k`` would be a different model (recall < 1).
 """
@@ -36,7 +38,7 @@ from jax import lax
 
 from apex_tpu.telemetry.spans import phase
 
-__all__ = ["index_scores", "topk_mask", "topk_indices"]
+__all__ = ["index_scores", "topk_mask", "topk_indices", "mask_at"]
 
 #: the finite stand-in for minus infinity in a masked softmax
 NEG = -1e30
@@ -96,18 +98,31 @@ def topk_mask(scores: jnp.ndarray, k: int, valid: jnp.ndarray
         # key (0 where the row has fewer than k valid entries)
         tau = lax.fori_loop(0, 32, bit,
                             jnp.zeros(u.shape[:-1], jnp.uint32))[:, None]
-        above, at = u > tau, u == tau
-        room = k - jnp.sum(above, axis=-1, keepdims=True)
-        first = jnp.cumsum(at, axis=-1, dtype=jnp.int32) <= room
-        return (above | (at & first)) & valid
+        return mask_at(u, tau, k, valid)
+
+
+def mask_at(keys: jnp.ndarray, tau: jnp.ndarray, k: int, valid: jnp.ndarray
+            ) -> jnp.ndarray:
+    """Boolean (rows, S): every ``valid`` key above the row's ``tau``
+    (rows, 1), then keys EQUAL to it, lower positions first, until ``k``
+    are marked.  With ``tau`` the k-th largest key of the row this is the
+    row's top ``k``, ties to the lower position."""
+    above, at = keys > tau, keys == tau
+    room = k - jnp.sum(above, axis=-1, keepdims=True)
+    first = jnp.cumsum(at, axis=-1, dtype=jnp.int32) <= room
+    return (above | (at & first)) & valid
 
 
 def topk_indices(scores: jnp.ndarray, k: int, valid: jnp.ndarray
-                 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """(positions (rows, k) int32, chosen (rows, k) bool): the same set
+                 ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+    """(positions (rows, k) int32, chosen (rows, k) bool, the k-th
+    largest of ``where(valid, scores, -inf)`` (rows, 1)): the same set
     :func:`topk_mask` marks, as positions; ``chosen`` is false on the
-    filler entries of a row with fewer than ``k`` valid tokens."""
+    filler entries of a row with fewer than ``k`` valid tokens, whose
+    k-th largest is -inf.  ``mask_at(where(valid, scores, -inf), <the
+    third>, k, valid)`` marks the positions the first two give."""
     with phase("attn.select"):
         k = min(k, scores.shape[-1])
-        _, idx = lax.top_k(jnp.where(valid, scores, -jnp.inf), k)
-        return idx.astype(jnp.int32), jnp.take_along_axis(valid, idx, axis=-1)
+        top, idx = lax.top_k(jnp.where(valid, scores, -jnp.inf), k)
+        return (idx.astype(jnp.int32),
+                jnp.take_along_axis(valid, idx, axis=-1), top[:, k - 1:])

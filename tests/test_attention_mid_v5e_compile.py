@@ -160,23 +160,30 @@ def test_hyper_connection_mapping_compiles(one_chip, monkeypatch, tokens):
     assert "tlm.kernel.hc_map" in text
 
 
-def test_latent_walk_compiles_and_gathers_nothing(one_chip, monkeypatch):
-    """The absorbed form over the stacked latent pool of the Xing4 cell
-    (6 layers x 16 slots x 336 pages of 64 rows, 640 wide), the layer a
-    traced scalar: one Mosaic call and no temporary the size of a
-    layer's pool or of a slot's context."""
+def _latent_walk_xing4(one_chip, monkeypatch):
+    """``mla_paged`` compiled for the v5e at the Xing4 cell's widths (6
+    layers x 16 slots x 336 pages of 64 rows, 640 wide, 32 heads), the
+    layer a traced scalar, no selection."""
     from apex_tpu.ops import attention_latent as al
 
     monkeypatch.setattr(al, "_interpret", lambda: False)
     sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
     bf = jnp.bfloat16
-    compiled = jax.jit(lambda qn, qr, pool, layer, table, lengths, uk, uv:
-                       al.mla_paged(qn, qr, pool, layer, table, lengths, uk,
-                                    uv, 0.1, implementation="pallas")).lower(
+    return jax.jit(lambda qn, qr, pool, layer, table, lengths, uk, uv:
+                   al.mla_paged(qn, qr, pool, layer, table, lengths, uk, uv,
+                                0.1, implementation="pallas")).lower(
         sds((16, 32, 128), bf), sds((16, 32, 64), bf),
         sds((6, 1 + 16 * 336, 64, 640), bf), sds((), jnp.int32),
         sds((16, 336), jnp.int32), sds((16,), jnp.int32),
         sds((512, 32, 128), bf), sds((512, 32, 128), bf)).compile()
+
+
+def test_latent_walk_compiles_and_gathers_nothing(one_chip, monkeypatch):
+    """The absorbed form over the stacked latent pool of the Xing4 cell
+    (6 layers x 16 slots x 336 pages of 64 rows, 640 wide), the layer a
+    traced scalar: one Mosaic call and no temporary the size of a
+    layer's pool or of a slot's context."""
+    compiled = _latent_walk_xing4(one_chip, monkeypatch)
     text = compiled.as_text()
     assert text.count("tpu_custom_call") == 1
     assert "tlm.kernel.latent_walk" in text
@@ -254,3 +261,61 @@ def test_grouped_expert_product_compiles_and_copies_no_layer(
     assert "tlm.kernel.moe_grouped.gate_up" in text
     assert "tlm.kernel.moe_grouped.down" in text
     assert compiled.memory_analysis().temp_size_in_bytes < 2**30
+
+
+def test_latent_walk_without_a_selection_compiles_as_it_did(
+        one_chip, monkeypatch):
+    """The walk Xing4's decode step runs (no selection) compiles to the
+    text it had before the walk took an optional selection: the sha256
+    of the compiled program with names, source locations and the
+    locations inside the Mosaic body taken out
+    (``tools/compiled_text_diff.strip_metadata``), taken with jax and
+    jaxlib 0.9.0 and libtpu 0.0.34.  A change that means to move this
+    program changes the digest with it, and says so.
+
+    A new jax, jaxlib or libtpu may change the text of an unchanged
+    kernel.  Then compare the programs of the two commits under the new
+    toolchain: ``python tools/compiled_text_diff.py --parent <a checkout
+    of the commit before the selection> --change . --programs
+    serve-xing4`` must say EQUAL for ``jit__decode``.  Only then is the
+    digest this test prints the one to keep."""
+    import hashlib
+    import os
+
+    monkeypatch.syspath_prepend(os.path.join(os.path.dirname(
+        os.path.dirname(os.path.abspath(__file__))), "tools"))
+    from compiled_text_diff import strip_metadata
+
+    text = strip_metadata(_latent_walk_xing4(one_chip, monkeypatch).as_text())
+    digest = hashlib.sha256(text.encode()).hexdigest()
+    assert digest == (
+        "668bc07f98bf58c579d783aca9cac944688aa19d98c92a844e31b4f3e2341258"), (
+        f"digest {digest} under jax {jax.__version__}: see the docstring "
+        f"for how to tell a toolchain's change from the kernel's")
+
+
+def test_latent_walk_under_a_selection_compiles_at_the_dsv32_cell(
+        one_chip, monkeypatch):
+    """DeepSeek-V3.2's decode attention over the latent pool of its cell
+    (5 layers x 32 slots x 112 pages of 64 rows, 128 heads) under a
+    (32, 7168) selection: one Mosaic call, the selection its step-sized
+    blocks, and no temporary the size of a slot's chosen rows (the
+    gathered form's (32, 2048, 640) is 84 MB)."""
+    from apex_tpu.ops import attention_latent as al
+
+    monkeypatch.setattr(al, "_interpret", lambda: False)
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    bf = jnp.bfloat16
+    compiled = jax.jit(
+        lambda qn, qr, pool, layer, table, lengths, uk, uv, sel:
+        al.mla_paged(qn, qr, pool, layer, table, lengths, uk, uv, 0.1,
+                     selected=sel, implementation="pallas")).lower(
+        sds((32, 128, 128), bf), sds((32, 128, 64), bf),
+        sds((5, 1 + 32 * 112, 64, 640), bf), sds((), jnp.int32),
+        sds((32, 112), jnp.int32), sds((32,), jnp.int32),
+        sds((512, 128, 128), bf), sds((512, 128, 128), bf),
+        sds((32, 7168), jnp.bool_)).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert "tlm.kernel.latent_walk" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 8 * 2**20

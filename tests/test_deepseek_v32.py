@@ -215,6 +215,58 @@ def test_the_decode_program_shows_what_it_computed(built):
             assert (mine == np.asarray(selected)[-1]).all()
 
 
+def test_decode_step_walks_and_gathers_alike(built, monkeypatch):
+    """The decode step's page walk under the selection mask against the
+    same step with the chosen rows gathered through the page table and
+    attended alone: the same logits on every live slot and the same
+    selected sets, with slots past, at and below ``index_topk`` and one
+    idle."""
+    from apex_tpu.models import deepseek_v32
+
+    model, params, ccfg, fns, fresh = built
+    cache = PagedKVCache(ccfg)
+    pools = fresh()
+    lengths = (23, 8, 5, 0)
+    for slot, n in enumerate(lengths):
+        if not n:
+            continue
+        cache.admit(slot, n + 1)
+        toks = np.zeros((-(-n // CHUNK) * CHUNK,), np.int32)
+        toks[:n] = _tokens(500 + slot, n)
+        for c0 in range(0, n, CHUNK):
+            pools, _, _ = fns.chunk(pools, toks[c0:c0 + CHUNK], c0, n, 0,
+                                    jnp.asarray(cache.page_table[slot]),
+                                    jax.random.PRNGKey(0))
+    table = model.rope_table(ccfg.max_len)
+    args = (params, pools, jnp.asarray(_tokens(9, SLOTS)),
+            jnp.asarray(lengths, jnp.int32),
+            jnp.asarray([n > 0 for n in lengths]),
+            jnp.asarray(cache.page_table))
+
+    def gathered(q_nope, q_rope, pool, layer, page_table, lengths, w_uk,
+                 w_uv, scale, *, selected):
+        # the marked positions, lower first, and the rows they name
+        marked, idx = jax.lax.top_k(selected.astype(jnp.int32),
+                                    HF["index_topk"])
+        rows = pool[layer, jnp.take_along_axis(page_table, idx // PAGE,
+                                               axis=1), idx % PAGE]
+        return mla_absorbed(q_nope, q_rope, rows, marked > 0, w_uk, w_uv,
+                            scale)
+
+    out = []
+    for attend in (deepseek_v32.mla_paged, gathered):
+        monkeypatch.setattr(deepseek_v32, "mla_paged", attend)
+        step = jax.jit(lambda *a: model.decode_step(
+            *a, page_size=PAGE, table=table))
+        logits, _, stats, (idx, chosen) = step(*args)
+        out.append([np.asarray(v) for v in (logits, stats, idx, chosen)])
+    live = np.asarray(lengths) > 0
+    np.testing.assert_allclose(out[0][0][live], out[1][0][live],
+                               atol=2e-4, rtol=0)
+    for walked, gathered_ in zip(out[0][1:], out[1][1:]):
+        np.testing.assert_array_equal(walked, gathered_)
+
+
 def test_monolithic_prefill_signature_runs_the_chunks(built):
     model, params, ccfg, fns, fresh = built
     tokens = _tokens(7, 13)

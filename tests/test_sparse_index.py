@@ -47,7 +47,7 @@ def test_both_forms_select_the_reference_set(seed, n, width, k):
     want = _reference_mask(scores, k, valid)
     assert (np.asarray(jax.jit(topk_mask, static_argnums=1)(
         scores, k, valid)) == want).all()
-    idx, chosen = jax.jit(topk_indices, static_argnums=1)(scores, k, valid)
+    idx, chosen, _ = jax.jit(topk_indices, static_argnums=1)(scores, k, valid)
     assert (_as_mask(idx, chosen, width) == want).all()
     assert want.sum(1).tolist() == [min(k, int(v)) for v in valid.sum(1)]
 
@@ -59,7 +59,7 @@ def test_ties_go_to_the_lower_position():
     want = _reference_mask(scores, 20, valid)
     got = np.asarray(topk_mask(scores, 20, valid))
     assert (got == want).all()
-    idx, chosen = topk_indices(scores, 20, valid)
+    idx, chosen, _ = topk_indices(scores, 20, valid)
     assert (_as_mask(idx, chosen, 96) == want).all()
     # the rule itself, by hand: among equal scores the first ones win
     row = jnp.asarray([[1.0, 3.0, 1.0, 1.0, 2.0, 1.0]])
@@ -83,10 +83,19 @@ def test_a_query_with_fewer_than_k_predecessors_attends_to_all(predecessors):
     valid = jnp.arange(30)[None] < predecessors
     assert (np.asarray(topk_mask(scores, 8, valid))
             == np.asarray(valid)).all()
-    idx, chosen = topk_indices(scores, 8, valid)
+    idx, chosen, _ = topk_indices(scores, 8, valid)
     assert int(chosen.sum()) == predecessors
     assert sorted(np.asarray(idx)[0][np.asarray(chosen)[0]].tolist()) == \
         list(range(predecessors))
+
+
+def test_topk_indices_gives_the_kth_largest_valid_score():
+    """The third value is the k-th largest valid score of each row, -inf
+    in a row with fewer than k valid entries."""
+    scores = jnp.asarray([[5., 1., 4., 4., 2.], [3., 9., 8., 7., 6.]])
+    valid = jnp.asarray([[True] * 5, [True, False, False, True, False]])
+    _, _, kth = topk_indices(scores, 3, valid)
+    np.testing.assert_array_equal(np.asarray(kth), [[4.], [-np.inf]])
 
 
 def test_nothing_valid_selects_nothing():
