@@ -57,6 +57,7 @@ import numpy as np
 __all__ = [
     "KVCacheConfig",
     "PageClass",
+    "SlotState",
     "CacheOutOfPages",
     "AdmitResult",
     "PageAllocator",
@@ -137,6 +138,25 @@ class PageClass:
 
 
 @dataclasses.dataclass(frozen=True)
+class SlotState:
+    """A state of FIXED size that every slot keeps per layer beside its
+    pages (a state-space layer's recurrent state, its convolution
+    window): ``init_pools`` builds ``name`` as ``(layers, max_seqs) +
+    shape`` in ``dtype``, zeroed, in the same donated pools dict as the
+    pages.  Nothing about it is paged: a slot owns its row from
+    admission to retirement, and the model's first prompt chunk (start
+    0) starts from zeros whatever the row holds, so admission costs no
+    device work.  A cache with such a state refuses everything that
+    would move a slot's pages without it: the prefix index, a page
+    export and import."""
+
+    name: str
+    layers: int
+    shape: Tuple[int, ...]
+    dtype: Any = jnp.float32
+
+
+@dataclasses.dataclass(frozen=True)
 class KVCacheConfig:
     """Shape and dtype of one paged cache.
 
@@ -191,6 +211,9 @@ class KVCacheConfig:
     latent_dim: int = 0
     index_dim: int = 0
     classes: Tuple[PageClass, ...] = ()
+    #: per-slot states of fixed size beside the pages
+    #: (:class:`SlotState`, e.g. a state-space layer's); () for none
+    slot_states: Tuple[SlotState, ...] = ()
 
     @classmethod
     def of_classes(cls, classes, *, page_size: int, max_seqs: int,
@@ -216,6 +239,8 @@ class KVCacheConfig:
     def __post_init__(self):
         if self.classes:
             self._check_classes()
+        if self.slot_states:
+            self._check_states()
         if self.num_pages < 2:
             raise ValueError(
                 "num_pages must be >= 2 (page 0 is the reserved null "
@@ -243,6 +268,16 @@ class KVCacheConfig:
                     "num_heads=1, head_dim=latent_dim")
             if self.quantized:
                 raise ValueError("latent pools are not quantized")
+
+    def _check_states(self):
+        names = [st.name for st in self.slot_states]
+        pages = ("k", "v", "k_scales", "v_scales", "ckv", "kidx")
+        if len(set(names)) != len(names) or any(
+                n in pages or n.endswith((".k", ".v")) for n in names):
+            raise ValueError(f"slot states need distinct names that no "
+                             f"page pool has: {names}")
+        if any(st.layers < 1 for st in self.slot_states):
+            raise ValueError("a slot state needs >= 1 layer")
 
     def _check_classes(self):
         names = [c.name for c in self.classes]
@@ -299,6 +334,12 @@ class KVCacheConfig:
     @property
     def quantized(self) -> bool:
         return self.kv_dtype is not None
+
+    @property
+    def has_state(self) -> bool:
+        """Whether a slot keeps state beside its pages (``slot_states``):
+        its pages alone do not resume it."""
+        return bool(self.slot_states)
 
     @property
     def latent_row_dim(self) -> int:
@@ -634,6 +675,12 @@ class PagedKVCache:
             raise ValueError(
                 f"sequence of {total_tokens} tokens exceeds the slot "
                 f"bound {cfg.max_len} (pages_per_seq * page_size)")
+        if prompt_tokens is not None and cfg.has_state:
+            raise ValueError(
+                "the prefix index shares pages, and this cache keeps a "
+                "per-slot state beside them (slot_states) that a shared "
+                "prefix would also need: prefix caching over a cache with "
+                "state is not built (ROADMAP, R queue)")
         if prompt_tokens is not None and (
                 len(classes) > 1 or classes[0].window):
             raise ValueError(
@@ -749,6 +796,9 @@ class PagedKVCache:
             # its entry, and for a ring its size (a position's column)
             key += ((c.name, c.layers, c.num_heads, c.head_dim, c.window,
                      c.pages_per_seq if c.window else 0),)
+        for st in cfg.slot_states:
+            key += (("state", st.name, st.layers, tuple(st.shape),
+                     str(jnp.dtype(st.dtype))),)
         return key
 
     def device_tables(self) -> Tuple[jnp.ndarray, jnp.ndarray]:
@@ -771,8 +821,17 @@ def init_pools(config: KVCacheConfig) -> Dict[str, jnp.ndarray]:
     ``kind="latent"``: ``ckv`` of shape ``(num_layers, num_pages,
     page_size, latent_row_dim)`` and, where ``index_dim > 0``, ``kidx``
     of ``(..., index_dim)`` — a token's entry is one row, shared by all
-    heads."""
+    heads.
+
+    Each of ``slot_states`` (:class:`SlotState`) adds its own zeroed
+    ``(layers, max_seqs) + shape`` entry under its name."""
     cfg = config
+    states = {st.name: jnp.zeros((st.layers, cfg.max_seqs) + tuple(st.shape),
+                                 st.dtype) for st in cfg.slot_states}
+    return dict(_page_pools(cfg), **states)
+
+
+def _page_pools(cfg: KVCacheConfig) -> Dict[str, jnp.ndarray]:
     if cfg.classes:
         # a class's pool under its own name: "<class>.k" / "<class>.v",
         # (its layers, ITS pages, heads, page_size, head_dim)

@@ -296,7 +296,11 @@ class ContinuousBatcher:
     ``prefill_chunk``-token ingestion step (chunked mode only); the
     first token / logits are meaningful on the chunk containing the
     last prompt token.  :func:`apex_tpu.models.gpt.GPTModel.decode_fns`
-    builds the canonical set.
+    builds the canonical set.  Where the cache keeps a state a slot
+    beside its pages (``KVCacheConfig.slot_states``), ``chunk_fn`` is
+    also given ``slot=`` (the row of the state it reads and writes), and
+    ``decode_fn`` must leave the state of a slot that is not decoding
+    (``done``: empty, or still between its prompt's chunks) as it was.
 
     All are expected to be jitted ONCE outside; the driver never
     changes a shape.  ``logger`` is an optional
@@ -386,6 +390,17 @@ class ContinuousBatcher:
                 "prefix_cache requires chunked prefill (the monolithic "
                 "prefill recomputes every position and cannot skip "
                 "matched chunks)")
+        if cache.config.has_state and prefill_chunk is None:
+            raise ValueError(
+                "a cache with per-slot state (slot_states) is filled "
+                "chunk by chunk: the chunk step is told its slot, the "
+                "monolithic prefill is not (decode_fns(prefill_chunk=C))")
+        if prefix_cache and cache.config.has_state:
+            raise ValueError(
+                "prefix_cache over a cache with per-slot state is not "
+                "built: a hit would also need the state after the shared "
+                "prefix, which the prefix index does not keep (ROADMAP, R "
+                "queue)")
         if prefix_cache and any(c.window
                                 for c in cache.config.page_classes):
             raise ValueError(
@@ -632,6 +647,10 @@ class ContinuousBatcher:
         if req.arrival_s is not None:
             span.set_metadata(
                 queue_wait_us=int(1e6 * (t_admit - req.arrival_s)))
+        if chunk >= 0 and self.cache.config.has_state:
+            # where the chunk's state came from: zeros at the prompt's
+            # start, else the slot's row
+            span.set_metadata(ssm_state_in="carried" if chunk else "zero")
         k_blocks = getattr(self.chunk_fn, "k_blocks", None)
         if chunk >= 0 and k_blocks is not None:
             run, extent = k_blocks(chunk * self.prefill_chunk)
@@ -796,7 +815,8 @@ class ContinuousBatcher:
             t0 = time.perf_counter()
             self.pools, tok, logits = self.chunk_fn(
                 self.pools, st["toks"][c0:c0 + C], c0, st["plen"],
-                st["write_from"], st["page_row"], st["key"])
+                st["write_from"], st["page_row"], st["key"],
+                **({"slot": slot} if self.cache.config.has_state else {}))
             if self.measure_stall:
                 jax.block_until_ready(tok)
             dur = time.perf_counter() - t0
@@ -1281,6 +1301,13 @@ class ContinuousBatcher:
                 if m["finished"] is None and m["tokens"]
                 and s not in self._first_tok]
 
+    def _refuse_stateful_handoff(self, what: str) -> None:
+        if self.cache.config.has_state:
+            raise ValueError(
+                f"cannot {what} a request of a cache with per-slot state "
+                "(slot_states): a handoff moves pages, and the slot's "
+                "state would stay behind (ROADMAP, R queue)")
+
     def export_request(self, uid: Any) -> Optional[HandoffPacket]:
         """Package an in-flight request's decode state for another
         replica: stage every KV page written so far to host and
@@ -1289,6 +1316,7 @@ class ContinuousBatcher:
         is not exportable (:meth:`handoff_ready`).  The caller owns
         durability: journal the transfer BEFORE calling this — after
         it, the pages live only in the returned packet."""
+        self._refuse_stateful_handoff("export")
         slot = next((s for s, m in self._meta.items()
                      if m["req"].uid == uid), None)
         if slot is None:
@@ -1343,6 +1371,7 @@ class ContinuousBatcher:
         continuation by the key-schedule argument.  Returns ``False``
         on backpressure (no free slot / no pages) — the packet stays
         valid and the caller retries later."""
+        self._refuse_stateful_handoff("import")
         if packet.compat_key != self.cache.compat_key():
             raise ValueError(
                 f"handoff across incompatible cache families: packet "

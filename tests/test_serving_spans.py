@@ -406,8 +406,9 @@ def test_every_mosaic_kernel_call_is_named():
             text = f.read()
         calls += len(re.findall(r"^[^#\n]*pl\.pallas_call\(", text, re.M))
         names += re.findall(r'name=kernel_name\("([\w.]+)"\)', text)
-    assert calls == len(names) == 16
+    assert calls == len(names) == 17
     assert len(set(names)) == len(names)
     assert {"fmha_mid.fwd", "fmha_mid.bwd", "paged_decode", "latent_walk",
-            "hc_map", "moe_grouped.gate_up", "moe_grouped.down"} <= set(names)
+            "hc_map", "moe_grouped.gate_up", "moe_grouped.down",
+            "ssm_state_update"} <= set(names)
     assert kernel_name("paged_decode") == KERNEL_PREFIX + "paged_decode"
