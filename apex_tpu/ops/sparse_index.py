@@ -23,7 +23,11 @@ set a stable descending sort gives.  Two forms of one selection:
   through ``lax.top_k``, which orders equal values by index, and the
   k-th largest score: :func:`mask_at` turns that threshold into the
   same set as a mask, elementwise and one running count (a decode step
-  whose attention walks the pages under a mask).
+  whose attention walks the pages under a mask).  Which of the k
+  positions are real is counted from the row's valid count, not
+  gathered from ``valid``: ``lax.top_k`` puts every valid position
+  ahead of every filler, provided each valid score is above -inf (as
+  :func:`index_scores` gives for finite inputs).
 
 ``lax.approx_max_k`` would be a different model (recall < 1).
 """
@@ -120,9 +124,13 @@ def topk_indices(scores: jnp.ndarray, k: int, valid: jnp.ndarray
     :func:`topk_mask` marks, as positions; ``chosen`` is false on the
     filler entries of a row with fewer than ``k`` valid tokens, whose
     k-th largest is -inf.  ``mask_at(where(valid, scores, -inf), <the
-    third>, k, valid)`` marks the positions the first two give."""
+    third>, k, valid)`` marks the positions the first two give.
+
+    Every valid score must be above -inf.  The valid positions then
+    come first in ``lax.top_k``'s order, so ``chosen`` is counted — the
+    first ``sum(valid)`` entries of the row — not gathered."""
     with phase("attn.select"):
         k = min(k, scores.shape[-1])
         top, idx = lax.top_k(jnp.where(valid, scores, -jnp.inf), k)
-        return (idx.astype(jnp.int32),
-                jnp.take_along_axis(valid, idx, axis=-1), top[:, k - 1:])
+        chosen = jnp.arange(k)[None] < jnp.sum(valid, -1, keepdims=True)
+        return idx.astype(jnp.int32), chosen, top[:, k - 1:]

@@ -89,6 +89,53 @@ def test_a_query_with_fewer_than_k_predecessors_attends_to_all(predecessors):
         list(range(predecessors))
 
 
+K_CHOSEN, WIDTH_CHOSEN = 16, 48
+
+
+def _chosen_case_scores(kind, rng):
+    if kind == "normal":
+        return rng.standard_normal((4, WIDTH_CHOSEN)).astype(np.float32)
+    if kind == "ties":  # few levels: equal scores straddle the k-th place
+        return np.round(rng.standard_normal((4, WIDTH_CHOSEN))).astype(
+            np.float32)
+    return rng.choice(np.asarray([0.0, -0.0, 1.0, -1.0], np.float32),
+                      (4, WIDTH_CHOSEN))
+
+
+@pytest.mark.parametrize("kind", ["normal", "ties", "zeros"])
+@pytest.mark.parametrize("layout", ["contiguous", "scattered"])
+@pytest.mark.parametrize("count", [0, 1, K_CHOSEN - 1, K_CHOSEN,
+                                   K_CHOSEN + 1, WIDTH_CHOSEN])
+def test_chosen_is_the_gathered_validity_of_the_positions(count, layout,
+                                                          kind):
+    """``chosen``, counted from the row's valid count, equals the
+    validity of each selected position gathered from ``valid``."""
+    rng = np.random.default_rng(
+        [count, ["contiguous", "scattered"].index(layout),
+         ["normal", "ties", "zeros"].index(kind)])
+    scores = _chosen_case_scores(kind, rng)
+    valid = np.zeros((4, WIDTH_CHOSEN), bool)
+    for r in range(4):
+        at = (np.arange(count) if layout == "contiguous"
+              else rng.permutation(WIDTH_CHOSEN)[:count])
+        valid[r, at] = True
+    idx, chosen, _ = jax.jit(topk_indices, static_argnums=1)(
+        jnp.asarray(scores), K_CHOSEN, jnp.asarray(valid))
+    gathered = np.take_along_axis(valid, np.asarray(idx), axis=-1)
+    np.testing.assert_array_equal(np.asarray(chosen), gathered)
+    assert np.asarray(chosen).sum(1).tolist() == [min(K_CHOSEN, count)] * 4
+
+
+def test_topk_indices_gathers_nothing_at_the_decode_shape():
+    """At the DeepSeek-V3.2 cell's decode shape (32 slots x 7,168
+    positions, K = 2,048) the lowered selection holds no gather."""
+    text = jax.jit(topk_indices, static_argnums=1).lower(
+        jax.ShapeDtypeStruct((32, 7168), jnp.float32), 2048,
+        jax.ShapeDtypeStruct((32, 7168), jnp.bool_)).as_text()
+    assert "top_k" in text
+    assert "gather" not in text
+
+
 def test_topk_indices_gives_the_kth_largest_valid_score():
     """The third value is the k-th largest valid score of each row, -inf
     in a row with fewer than k valid entries."""
