@@ -90,13 +90,17 @@ class Tracer:
     starting takes some 40 ms) falls outside the measured window.  In an
     open loop it falls into the drain and stretches the last requests'
     latencies: a traced run's end-to-end numbers are not the benchmark's,
-    its per-layer numbers are."""
+    its per-layer numbers are.  ``stop_s`` is how long the stop blocked
+    the host, so that the drain's deadline does not count it: the drain
+    gets its grace in the server's own time.  ``t_stopped`` is the moment
+    the stop was called, the end of the traced stretch."""
 
     def __init__(self, enabled: bool, directory: str):
         self.enabled, self.directory = enabled, directory
         self.start_at = self.stop_at = float("inf")
         self.t_started: Optional[float] = None
         self.t_stopped: Optional[float] = None
+        self.stop_s = 0.0
 
     def arm(self, start_at: float, stop_at: float) -> None:
         self.start_at, self.stop_at = start_at, stop_at
@@ -127,6 +131,7 @@ class Tracer:
 
             self.t_stopped = time.perf_counter()
             jax.profiler.stop_trace()
+            self.stop_s = time.perf_counter() - self.t_stopped
 
     def xplane(self) -> Optional[str]:
         found = glob.glob(os.path.join(

@@ -19,8 +19,9 @@ harvest boundary, the only moment at which tokens reach the host, so:
 ``backlog`` keeps every slot full from a pre-aged first generation (see
 ``traffic.Backlog``); ``openloop`` runs its arrival process as a ramp
 before the window, measures the requests that fall DUE inside the
-window wherever they finish, and drains them up to a stated grace, after
-which an unfinished one counts as failed.
+window wherever they finish, and drains them up to a stated grace of the
+server's own time (the seconds the tracer's stop blocks the host are not
+counted), after which an unfinished one counts as failed.
 """
 
 from __future__ import annotations
@@ -364,7 +365,9 @@ def run(run) -> dict:
             run.tracer.poll(now)
             if now >= t_close and all(p.uid in drv.t_last for p in measured):
                 break
-            if now >= t_close + grace:
+            # the drain's grace is the server's own time: the seconds
+            # the tracer's stop blocked the host are not counted
+            if now >= t_close + grace + run.tracer.stop_s:
                 break
         run.tracer.stop()
         compiled = run.clock.snapshot() - (before or run.clock.snapshot())
@@ -385,6 +388,11 @@ def run(run) -> dict:
             release_lateness_p50_s=timing.percentile(late, 50),
             drained_after_close_s=t_end - t_close,
             unfinished=len(unfinished))
+        run.note(f"drain: last boundary {t_end - t_close:.3f} s after the "
+                 f"close (drained_after_close_s, the stop included); the "
+                 f"tracer's stop blocked the host {run.tracer.stop_s:.3f} s, "
+                 f"which the deadline of close + {grace} s does not count; "
+                 f"{len(unfinished)} measured request(s) unfinished")
         end_to_end = {"ttft_p90_s": timing.percentile(ttft, 90),
                       "tpot_p50_ms": timing.percentile(tpot, 50)}
         attempted = len(measured)
